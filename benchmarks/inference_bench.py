@@ -14,9 +14,16 @@ Predictor with shape-cached compiled executables → timed run loop with a
 true host-transfer sync per batch (serving semantics: the caller needs
 the output back).
 
-Run:  python benchmarks/inference_bench.py [resnet50|bert|all]
+Run:  python benchmarks/inference_bench.py [resnet50|bert|gpt2|all]
 Prints one JSON line per (config, batch): {"config", "infer": true,
-"batch", "latency_ms", "throughput", "unit"}.
+"batch", "latency_ms", "throughput", "unit", "platform", "device_kind"},
+and exits non-zero when any config failed.
+
+Off-TPU the configs run at toy shapes as a FUNCTIONAL gate
+(tools/precommit_gate.sh checks the rows' contract gates: compile-once,
+prefix reuse, int8 parity). Such a row says so itself — `platform` is the
+CPU and its `unit` reads "... on cpu (toy shapes; not a device number)" —
+so it cannot be filed as a chip measurement.
 
 Reference analogue: paddle/fluid/inference/tests/api benchmarks.
 """
@@ -28,6 +35,7 @@ import os
 import sys
 import tempfile
 import time
+import traceback
 
 import numpy as np
 
@@ -56,8 +64,8 @@ _TMPDIRS = []
 def _export(build_fn, feed_specs, tag):
     """Build under static graph, export via save_inference_model (fusion
     passes fold conv+bn etc.), return (path, feed_names). The artifact
-    dir is cleaned up at process exit — the watcher re-runs this script
-    every window and must not accumulate weight files in /tmp."""
+    dir is cleaned up at process exit, so repeated runs do not accumulate
+    weight files in /tmp."""
     import paddle_tpu as paddle
     from paddle_tpu import static
 
@@ -717,11 +725,13 @@ def bench_gpt2_overload(on_tpu):
 
 def main():
     import jax
-    on_tpu = jax.default_backend() == "tpu"
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
-    print(json.dumps({"backend": jax.default_backend(),
-                      "device_kind": jax.devices()[0].device_kind}),
-          flush=True)
+    device = {"platform": dev.platform, "device_kind": dev.device_kind,
+              "device_count": len(jax.devices())}
+    print(json.dumps(device), flush=True)
+    failed = []
     for name, cfg, fn in (("resnet50", "resnet50_infer", bench_resnet50),
                           ("bert", "bert_infer", bench_bert),
                           ("gpt2", "gpt2_generate", bench_gpt2_generate),
@@ -733,11 +743,21 @@ def main():
             continue
         try:
             for row in fn(on_tpu):
+                row.update(device)
+                if not on_tpu:
+                    row["unit"] = "%s on %s (toy shapes; not a device " \
+                        "number)" % (row["unit"].replace("/chip", ""),
+                                     dev.platform)
                 print(json.dumps(row), flush=True)
         except Exception as e:
-            print(json.dumps({"config": cfg,
+            traceback.print_exc()
+            failed.append(cfg)
+            print(json.dumps({"config": cfg, **device,
                               "error": f"{type(e).__name__}: {e}"}),
                   flush=True)
+    if failed:
+        raise SystemExit("inference_bench.py: failed configs: %s"
+                         % ", ".join(failed))
 
 
 if __name__ == "__main__":

@@ -39,9 +39,9 @@ __all__ = [
 ]
 
 #: primitives that move data across devices (jax lax.parallel lowerings;
-#: psum2 is the check_rep=True shard_map spelling of psum)
+#: psum_invariant is what psum traces to under shard_map's check_vma=True)
 COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "psum2", "pmax", "pmin", "all_gather", "all_to_all",
+    "psum", "psum_invariant", "pmax", "pmin", "all_gather", "all_to_all",
     "ppermute", "reduce_scatter", "psum_scatter",
 })
 

@@ -229,8 +229,8 @@ class CompiledPipeline1F1B:
         # the loss accumulator rides the scan carry as shape (1,), not a
         # scalar: under value_and_grad, shard_map forwards scan residuals
         # with a mesh-axis name attached, and a 0-d residual has no axis
-        # to carry it (jax 0.4.x _check_names rejects the program). The
-        # reshape back to () happens after the psum, outside the carry.
+        # to carry it. The reshape back to () happens after the psum,
+        # outside the carry.
         init = (init_act, jnp.zeros((1,), jnp.float32))
         (_, loss_acc), _ = jax.lax.scan(
             tick, init, jnp.arange(n_micro + pp - 1))

@@ -65,7 +65,7 @@ def fused_bias_dropout_residual_ln_pair(
     GEOMETRY only (not FLAGS_use_fused_dropout_ln — the caller's
     FLAGS_fused_block is the opt-in); rejected shapes/backends take the
     composed ops, which are also the parity oracle."""
-    if not pk.fused_ln_geometry_ok(pk.raw(x), dropout_rate, training):
+    if not pk.fused_ln_geometry_ok(pk.raw(x)):
         h = x if bias is None else m.add(x, bias)
         h = F.dropout(h, dropout_rate, training=training, mode=mode)
         z = m.add(residual, h)
@@ -89,7 +89,7 @@ def fused_bias_dropout_residual(x, residual, bias=None, dropout_rate=0.5,
                                 name=None):
     """residual + dropout(x + bias), fused (falls back to composed ops when
     the gate rejects the shape/backend)."""
-    if not pk.fused_ln_shapes_ok(pk.raw(x), dropout_rate, training):
+    if not pk.fused_ln_shapes_ok(pk.raw(x)):
         h = x if bias is None else m.add(x, bias)
         h = F.dropout(h, dropout_rate, training=training, mode=mode)
         return m.add(residual, h)
@@ -108,7 +108,7 @@ def fused_bias_dropout_residual_layer_norm(
     used inside fused_attention_op.cu). The dropout mask is generated by the
     on-chip PRNG and never materialized in HBM; the backward recomputes LN
     statistics from the saved pre-norm activation."""
-    if not pk.fused_ln_shapes_ok(pk.raw(x), dropout_rate, training):
+    if not pk.fused_ln_shapes_ok(pk.raw(x)):
         h = x if bias is None else m.add(x, bias)
         h = F.dropout(h, dropout_rate, training=training, mode=mode)
         z = m.add(residual, h)

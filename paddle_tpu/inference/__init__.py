@@ -120,10 +120,10 @@ class Predictor:
     def _compiled(self, sig):
         if sig in self._exec_cache:
             return self._exec_cache[sig]
-        from ..ops.pallas_kernels import preprobe_pallas_health
+        from ..ops.pallas_kernels import pallas_selfcheck
         from ..jit import compile_cache
         compile_cache.configure()
-        preprobe_pallas_health(needs_prng=False)  # eval: no dropout PRNG
+        pallas_selfcheck(needs_prng=False)  # eval: no dropout PRNG
         prog = self._program
         bf16 = self._config._bf16
         cap_names = sorted(self._captures)

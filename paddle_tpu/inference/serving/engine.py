@@ -98,12 +98,10 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
         from ...jit import compile_cache
-        from ...ops.pallas_kernels import preprobe_pallas_health
+        from ...ops.pallas_kernels import pallas_selfcheck
         compile_cache.configure()
-        # needs_paged: probe the paged-decode megakernel tier now so the
-        # decode trace's gate reads a cached verdict (mid-trace probing
-        # would add a hidden compile to the decode-compiles-once budget)
-        preprobe_pallas_health(needs_prng=False, needs_paged=True)
+        # needs_paged: the decode step runs the paged-decode kernel
+        pallas_selfcheck(needs_prng=False, needs_paged=True)
 
         gpt = getattr(model, "gpt", model)
         if not hasattr(gpt, "layers") or not hasattr(gpt, "embeddings"):

@@ -439,20 +439,31 @@ class DataLoader:
         # C++ blocking queue (native/src/queue.cc — the reference's
         # operators/reader/blocking_queue.h) when built, else queue.Queue.
         from .. import native as _native
-        use_native = _native.available()
-        if use_native:
+        if _native.available():
             q = _native.NativeQueue(capacity=self.prefetch_factor)
-            put, get = q.push, q.pop
+            put, get, close = q.push, q.pop, q.close   # push: False once closed
         else:
             pyq: "queue.Queue" = queue.Queue(maxsize=self.prefetch_factor)
-            put, get = pyq.put, pyq.get
+            gone = threading.Event()
+
+            def put(item):
+                while not gone.is_set():
+                    try:
+                        pyq.put(item, timeout=0.1)
+                        return True
+                    except queue.Full:
+                        pass
+                return False
+
+            get, close = pyq.get, gone.set
         sentinel = object()
         err = []
 
         def producer():
             try:
                 for item in self._raw_iter():
-                    put(item)
+                    if not put(item):
+                        return      # the consumer closed the queue
             except BaseException as e:  # noqa: BLE001
                 err.append(e)
             finally:
@@ -460,10 +471,17 @@ class DataLoader:
 
         t = threading.Thread(target=producer, daemon=True)
         t.start()
-        while True:
-            item = get()
-            if item is sentinel:
-                if err:
-                    raise err[0]
-                return
-            yield item
+        try:
+            while True:
+                item = get()
+                if item is sentinel:
+                    if err:
+                        raise err[0]
+                    return
+                yield item
+        finally:
+            # an iterator abandoned mid-epoch must not strand its producer
+            # blocked on a full queue for the life of the process (and
+            # inside native code when the interpreter shuts down)
+            close()
+            t.join(timeout=5.0)

@@ -1,29 +1,17 @@
-"""Persistent XLA compilation cache: enablement + hit/miss accounting.
+"""Persistent XLA compilation cache: hit/miss accounting.
 
-Every gang restart (distributed/launch.py watch loop) used to recompile
-the world from scratch: a fresh process pays the full trace+XLA-compile
-tax for executables that are byte-identical to what the previous
-incarnation already built. jax ships a persistent compilation cache
-(keyed on serialized HLO + compile options + jaxlib version) that turns
-that tax into a disk read — this module manages it behind one knob:
+A fresh process used to pay the full trace+XLA-compile tax for executables
+byte-identical to what the previous one already built. jax ships a
+persistent compilation cache (keyed on serialized HLO + compile options +
+jaxlib version) that turns that tax into a disk read. It is on by default:
 
-  PADDLE_TPU_COMPILE_CACHE_DIR=/path   enable, cache entries under /path
+  JAX_COMPILATION_CACHE_DIR=/path   set: jax reads it, no code sets a dir
+  (unset)                           <checkout>/.jax_cache, a fixed path
 
-The launcher exports it by default under ``--log_dir`` so all local
-ranks and every restart round share one cache (the cache is written
-atomically per entry; concurrent readers/writers are safe). Set it to
-the empty string to force-disable.
-
-Two subtleties this module exists to hide:
-
-  * jax only persists entries whose compile time exceeds
-    ``jax_persistent_cache_min_compile_time_secs`` (default 1s) — tiny
-    CPU-test executables would never be cached, so the CI contract
-    could not be proven. We zero it (and ``min_entry_size_bytes``).
-  * ``compilation_cache.is_cache_used`` latches its verdict at the
-    FIRST compile of the process; configuring the dir after any op has
-    run silently keeps the cache off. ``configure()`` resets the latch
-    when the dir changes.
+`paddle_tpu/__init__` applies that rule and zeroes jax's persistence
+thresholds before the first compile (``compilation_cache.is_cache_used``
+latches its verdict then). JAX_ENABLE_COMPILATION_CACHE=false is jax's own
+off switch.
 
 Accounting: jax emits monitoring events on every cache probe; we fold
 ``/jax/compilation_cache/cache_hits|cache_misses`` into the metrics
@@ -36,7 +24,6 @@ owns the jax side of the handshake.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 from typing import Optional, Tuple
 
@@ -45,7 +32,6 @@ __all__ = ["configure", "enabled", "cache_dir", "totals"]
 log = logging.getLogger("paddle_tpu.compile_cache")
 
 _lock = threading.Lock()
-_configured_dir: Optional[str] = None
 _listener_installed = False
 _hits = 0
 _misses = 0
@@ -60,11 +46,14 @@ def totals() -> Tuple[int, int]:
 
 
 def enabled() -> bool:
-    return bool(_configured_dir)
+    import jax
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)
 
 
 def cache_dir() -> Optional[str]:
-    return _configured_dir
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 def _on_event(event: str, **kw):
@@ -77,69 +66,23 @@ def _on_event(event: str, **kw):
         _metric_misses.inc()
 
 
-def _install_listener():
+def configure() -> bool:
+    """Install the hit/miss listener and the tracing probe (once; cheap
+    afterwards). Returns True when the cache is live. Called from every
+    compile entry point (jit engine, static Executor, inference Predictor,
+    serving engine) so the accounting is in place whichever front-end
+    compiles first. The directory itself is set at package import."""
     global _listener_installed
-    if _listener_installed:
-        return
-    from jax._src import monitoring
-    monitoring.register_event_listener(
-        lambda event, **kw: _on_event(event, **kw))
-    _listener_installed = True
-
-
-def configure(directory: Optional[str] = None) -> bool:
-    """Point jax's persistent compilation cache at `directory` (default:
-    $PADDLE_TPU_COMPILE_CACHE_DIR). Idempotent and cheap once configured;
-    returns True when the cache is live. Called from every compile entry
-    point (jit engine, static Executor, inference Predictor) so the env
-    var works no matter which front-end compiles first."""
-    global _configured_dir
-    if directory is None:
-        directory = os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR", "")
-    if not directory:
-        return enabled()
     with _lock:
-        if directory == _configured_dir:
-            return True
-        try:
-            import jax
-            from jax._src import compilation_cache
+        if not _listener_installed:
+            from jax._src import monitoring
 
-            os.makedirs(directory, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", directory)
-            # cache everything: CI proves the warm-cache contract on
-            # sub-second CPU compiles that the defaults would skip
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1)
-            except Exception:
-                pass  # knob landed in 0.4.26; belt-and-braces
-            # un-latch is_cache_used so compiles that already happened
-            # (e.g. import-time constant folding) don't pin the cache off
-            try:
-                compilation_cache.reset_cache()
-            except Exception:
-                pass
-            _install_listener()
-            _push_tracing_probe()
-            _configured_dir = directory
-            log.info("persistent compilation cache at %s", directory)
-            return True
-        except Exception as exc:  # never break training over a cache
-            log.warning("compile cache disabled: %s", exc)
-            return False
-
-
-def _push_tracing_probe():
-    """Let StepTelemetry distinguish warm-cache reloads from retraces
-    without observability importing jax (tracing is stdlib-pure)."""
-    try:
-        from ..observability import tracing
-        tracing.set_compile_cache_probe(totals)
-    except Exception:
-        pass
+            from ..observability import tracing
+            monitoring.register_event_listener(_on_event)
+            tracing.set_compile_cache_probe(totals)
+            _listener_installed = True
+            log.info("persistent compilation cache at %s", cache_dir())
+    return enabled()
 
 
 def _counter(name, help_):

@@ -124,10 +124,10 @@ def make_train_step(network, loss_fn, optimizer, mesh=None):
     data axes — XLA inserts grad all-reduces and TP collectives over ICI
     (the compiled replacement for the reference's Reducer
     imperative/reducer.h:130 and mp_layers' hand-inserted c_* ops)."""
-    from ..ops.pallas_kernels import preprobe_pallas_health
+    from ..ops.pallas_kernels import pallas_selfcheck
     from . import compile_cache
     compile_cache.configure()
-    preprobe_pallas_health()
+    pallas_selfcheck()
     if mesh is None:
         mesh = getattr(network, "_pt_mesh", None)
     # ZeRO stage over the "sharding" axis: 1 = optimizer state only,
@@ -263,6 +263,23 @@ def make_train_step(network, loss_fn, optimizer, mesh=None):
         _acc_sh = _grad_sh
         _host = jax.devices("cpu")[0] if offload else None
 
+    # jit keys an executable on WHICH arguments are committed to a device.
+    # Fresh parameters, accumulators and the rng key are not; a batch that
+    # went through device_put is, and then everything the step returns is
+    # too — which made step 2 lower and compile the whole program a second
+    # time. Commit the state to its device up front: one signature, one
+    # executable. (Under a mesh _place_state does it; the rng key follows
+    # the state either way.)
+    _key_sh = _repl_sh if mesh is not None else None
+    if mesh is None and params and len(params[0]._data.devices()) == 1:
+        _key_sh = jax.sharding.SingleDeviceSharding(
+            next(iter(params[0]._data.devices())))
+        for m in mutable:
+            m._data = jax.device_put(m._data, _key_sh)
+        for acc in accs:
+            for n in acc_names:
+                acc[n] = jax.device_put(acc[n], _key_sh)
+
     def _place_state():
         """Commit train state onto the mesh (idempotent)."""
         for p, sh in zip(params, _param_sh):
@@ -288,7 +305,8 @@ def make_train_step(network, loss_fn, optimizer, mesh=None):
         optimizer._step_count += 1
         t = np.int32(optimizer._step_count)
         lr = np.float32(optimizer.get_lr())
-        key = RNG.key
+        key = (RNG.key if _key_sh is None
+               else jax.device_put(RNG.key, _key_sh))
         in_arrs = [x._data for x in inputs]
         lab_arrs = [x._data for x in labels]
         wd_s = float(flag("step_watchdog_s") or 0.0)
@@ -455,10 +473,10 @@ def train_jaxpr(network, inputs):
 
 def make_eval_step(network, loss_fn=None, mesh=None):
     """Compile forward (+loss) for evaluation."""
-    from ..ops.pallas_kernels import preprobe_pallas_health
+    from ..ops.pallas_kernels import pallas_selfcheck
     from . import compile_cache
     compile_cache.configure()
-    preprobe_pallas_health(needs_prng=False)
+    pallas_selfcheck(needs_prng=False)
     if mesh is None:
         mesh = getattr(network, "_pt_mesh", None)
     params, frozen, buffers, _ = _collect_train_state(network, None)

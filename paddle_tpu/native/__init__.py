@@ -5,9 +5,12 @@ TPU-native C++ equivalents of the reference's C++ runtime layer (SURVEY.md
 auto_growth_best_fit_allocator.cc), blocking reader queue
 (operators/reader/blocking_queue.h), RecordEvent profiler
 (platform/profiler.cc), MultiSlot data feed (framework/data_feed.cc).
-The library is built lazily with `make -C native` on first use; every
-consumer degrades gracefully to a pure-python path when the toolchain is
-unavailable (`available() -> False`)."""
+The library is built lazily with `make -C native` on first use, from the
+sources git tracks (native/build/ is ignored). A build that FAILS raises:
+the DataLoader's prefetch queue is on the trainer's main path, and a quiet
+fall to the python queue would hide a broken toolchain for good. Only a
+checkout without native/Makefile (no sources to build from) answers
+`available() -> False`."""
 from __future__ import annotations
 
 import ctypes
@@ -33,30 +36,19 @@ def _load():
         _tried = True
         if not os.path.exists(_LIB_PATH):
             mk = os.path.join(_REPO, "native")
-            marker = os.path.join(mk, "build", ".build_failed")
-            if os.path.exists(marker):
-                return None  # earlier build failed; don't stall every run
-            if os.path.exists(os.path.join(mk, "Makefile")):
-                try:
-                    subprocess.run(["make", "-C", mk], check=True,
-                                   capture_output=True, timeout=120)
-                except Exception as e:
-                    import sys
-                    tail = getattr(e, "stderr", b"") or b""
-                    print("paddle_tpu: native build failed, using python "
-                          f"fallbacks ({tail[-300:].decode(errors='replace')})",
-                          file=sys.stderr)
-                    try:
-                        os.makedirs(os.path.dirname(marker), exist_ok=True)
-                        with open(marker, "w") as f:
-                            f.write("delete this file to retry the build\n")
-                    except OSError:
-                        pass
-                    return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
+            if not os.path.exists(os.path.join(mk, "Makefile")):
+                return None
+            try:
+                subprocess.run(["make", "-C", mk], check=True,
+                               capture_output=True, timeout=120)
+            except (OSError, subprocess.SubprocessError) as e:
+                _tried = False      # the next call builds (and raises) again
+                tail = getattr(e, "stderr", b"") or b""
+                raise RuntimeError(
+                    "paddle_tpu: building the native runtime failed "
+                    "(make -C %s): %s\n%s" % (
+                        mk, e, tail[-2000:].decode(errors="replace"))) from e
+        lib = ctypes.CDLL(_LIB_PATH)
         # signatures
         lib.pt_arena_create.restype = ctypes.c_void_p
         lib.pt_arena_create.argtypes = [ctypes.c_size_t, ctypes.c_size_t]

@@ -86,7 +86,7 @@ def _ring_attention_local(q, k, v, *, axis_name, causal, scale,
         if dropout_p <= 0.0 or key is None:
             return None, 1.0
         bkey = jax.random.fold_in(key, my_idx * size + kb)
-        return (jax.random.bernoulli(bkey, 1.0 - dropout_p,
+        return (jax.random.bernoulli(bkey, jnp.float32(1.0 - dropout_p),
                                      q.shape[:-1] + (t_local,)),
                 1.0 / (1.0 - dropout_p))
 
@@ -208,7 +208,8 @@ def _blockwise_attention(q, k, v, *, causal, scale, block_k=512,
         drop_keep, drop_scale = None, 1.0
         if dropout_p > 0.0 and dropout_key is not None:
             drop_keep = jax.random.bernoulli(
-                jax.random.fold_in(dropout_key, i), 1.0 - dropout_p,
+                jax.random.fold_in(dropout_key, i),
+                jnp.float32(1.0 - dropout_p),
                 q.shape[:-1] + (bk,))
             drop_scale = 1.0 / (1.0 - dropout_p)
         acc, l, m = _online_block(q, k_blk, v_blk, acc, l, m, scale=scale,

@@ -118,7 +118,8 @@ def rnn(x, h0, c0, seq_len, dropout_key, *weights, mode="LSTM",
             outs_dir, axis=-1)
         if dropout > 0.0 and key is not None and layer < num_layers - 1:
             key, sub = jax.random.split(key)
-            keep = jax.random.bernoulli(sub, 1.0 - dropout, layer_in.shape)
+            keep = jax.random.bernoulli(sub, jnp.float32(1.0 - dropout),
+                                        layer_in.shape)
             layer_in = jnp.where(keep, layer_in / (1.0 - dropout), 0.0)
     y = layer_in if time_major else jnp.swapaxes(layer_in, 0, 1)
     h_n = jnp.stack(h_finals)

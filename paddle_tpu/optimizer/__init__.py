@@ -441,11 +441,13 @@ class Adam(Optimizer):
     @staticmethod
     def _update_rule(static_args, param, grad, lr, t, m1, m2):
         b1, b2, eps = static_args
-        from ..ops.pallas_kernels import fused_adamw_or_none
+        from ..ops.pallas_kernels import (_note_update_path,
+                                          fused_adamw_or_none)
         fused = fused_adamw_or_none(param, grad, lr, t, m1, m2, beta1=b1,
                                     beta2=b2, epsilon=eps, coeff=0.0)
         if fused is not None:
             return fused
+        _note_update_path("xla_adamw")
         g = grad.astype(jnp.float32)
         p32 = param.astype(jnp.float32)
         m1n = b1 * m1 + (1 - b1) * g
@@ -496,11 +498,13 @@ class AdamW(Adam):
     @staticmethod
     def _update_rule(static_args, param, grad, lr, t, m1, m2):
         b1, b2, eps, coeff = static_args
-        from ..ops.pallas_kernels import fused_adamw_or_none
+        from ..ops.pallas_kernels import (_note_update_path,
+                                          fused_adamw_or_none)
         fused = fused_adamw_or_none(param, grad, lr, t, m1, m2, beta1=b1,
                                     beta2=b2, epsilon=eps, coeff=coeff)
         if fused is not None:
             return fused
+        _note_update_path("xla_adamw")
         g = grad.astype(jnp.float32)
         p32 = param.astype(jnp.float32)
         p32 = p32 * (1.0 - lr * coeff)

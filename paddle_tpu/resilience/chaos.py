@@ -3,12 +3,10 @@
 Every resilience path in this repo is testable on the CPU mesh because the
 faults it defends against can be INJECTED deterministically:
 
-    PADDLE_TPU_CHAOS="probe_timeout:3;sigterm_at_step:7;nan_at_step:3"
+    PADDLE_TPU_CHAOS="sigterm_at_step:7;nan_at_step:3"
 
 Spec grammar: `;`-separated `name[:int[:float]]` entries —
 
-    probe_timeout:N       first N TPU-probe calls report a timed-out probe
-                          (bench.py / benchmarks/tpu_capture.py)
     sigterm_at_step:K     deliver a real SIGTERM to this process at global
                           train step K (hapi Model.fit batch loop)
     nan_at_step:K         the compiled train step produces a NaN loss (and
@@ -59,9 +57,7 @@ Reference analogue: the fault-injection envs in the reference's elastic
 tests (test_fleet_elastic_manager.py fakes etcd faults) — here promoted to
 a first-class, grep-able harness.
 
-MUST stay pure-stdlib: bench.py's parent process loads this file standalone
-(importlib by path) precisely so probing chaos never imports jax or the
-paddle_tpu package.
+Pure stdlib: nothing here imports jax or the rest of the package.
 """
 from __future__ import annotations
 
@@ -122,18 +118,6 @@ def enabled() -> bool:
 
 def get(name: str) -> Optional[Tuple[float, ...]]:
     return _active().get(name)
-
-
-def probe_should_timeout() -> bool:
-    """Consume one injected probe failure (probe_timeout:N)."""
-    args = get("probe_timeout")
-    if not args:
-        return False
-    n = _counts.get("probe_timeout", 0)
-    if n >= int(args[0]):
-        return False
-    _counts["probe_timeout"] = n + 1
-    return True
 
 
 def nan_at_step() -> Optional[int]:

@@ -114,7 +114,8 @@ def test_dropout_parity_exact():
     cm = jnp.tril(jnp.ones((t, t), bool))
     w = jax.nn.softmax(jnp.where(cm, s, -jnp.inf), axis=-1)
     keep = jnp.concatenate(
-        [jax.random.bernoulli(jax.random.fold_in(key, i), 1.0 - p,
+        [jax.random.bernoulli(jax.random.fold_in(key, i),
+                              jnp.float32(1.0 - p),
                               (B, H, t, bk)) for i in range(2)],
         axis=-1)[..., :t]
     want = jnp.einsum("bhqk,bhkd->bhqd",

@@ -1,13 +1,16 @@
 """Persistent compilation cache (jit/compile_cache.py): the warm-restart
 contract (second process over the same cache dir reloads instead of
 recompiling), the retrace-vs-warm-reload reclassification inside
-StepTelemetry, and configure() plumbing.
+StepTelemetry, and where the cache lives.
 
 The contract test is the CI teeth of PR 9's tentpole: run the SAME tiny
-fit twice in fresh subprocesses sharing one PADDLE_TPU_COMPILE_CACHE_DIR;
+fit twice in fresh subprocesses sharing one JAX_COMPILATION_CACHE_DIR;
 the second run must see cache hits, zero retraces and strictly less
 compile wall time — and its journal must say `compile_cache`, not
-`retrace`."""
+`retrace`.
+
+tests/conftest.py switches the cache off for the test process (jax's own
+JAX_ENABLE_COMPILATION_CACHE=false); the children here switch it back on."""
 import glob
 import json
 import os
@@ -63,7 +66,8 @@ class TestWarmCacheContract:
         script.write_text(CHILD)
         tdir = str(tmp_path / ("telemetry_" + tag))
         env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
-                   PADDLE_TPU_COMPILE_CACHE_DIR=str(cache_dir))
+                   JAX_ENABLE_COMPILATION_CACHE="true",
+                   JAX_COMPILATION_CACHE_DIR=str(cache_dir))
         r = subprocess.run([sys.executable, str(script), tdir],
                            capture_output=True, text=True, timeout=240,
                            env=env, cwd=REPO)
@@ -121,8 +125,7 @@ class TestReclassification:
                 pass
             return run_journal.read_journal(j.path), tel.retraces - r0
         finally:
-            tracing.set_compile_cache_probe(
-                compile_cache.totals if compile_cache.enabled() else None)
+            tracing.set_compile_cache_probe(compile_cache.totals)
             run_journal.set_journal(prev_j)
 
     def test_warm_reload_is_not_a_retrace(self, tmp_path):
@@ -162,29 +165,74 @@ class TestReclassification:
         assert "cache_misses" not in evs[0]
 
 
-class TestConfigure:
-    def test_no_env_is_noop(self, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR", raising=False)
-        was = compile_cache.enabled()
-        assert compile_cache.configure() == was
+WHERE = """
+import json, os, sys
+import jax
+calls = []
+real_update = jax.config.update
+def spy(name, value):
+    calls.append(name)
+    return real_update(name, value)
+jax.config.update = spy
+import paddle_tpu
+from paddle_tpu.jit import compile_cache
+print(json.dumps({"dir": compile_cache.cache_dir(),
+                  "enabled": compile_cache.enabled(),
+                  "set_dir_calls": calls.count("jax_compilation_cache_dir"),
+                  "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+                  "min_bytes": jax.config.jax_persistent_cache_min_entry_size_bytes}))
+"""
 
-    def test_configure_points_jax_at_dir(self, tmp_path):
+
+class TestWhereTheCacheLives:
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it and no code sets a
+    directory. Unset: one fixed path inside the checkout, the same in
+    every process (a directory that moves never hits)."""
+
+    def _child(self, cwd, **env_over):
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_COMPILATION_CACHE_DIR",
+                            "JAX_ENABLE_COMPILATION_CACHE")}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO, **env_over)
+        r = subprocess.run([sys.executable, "-c", WHERE],
+                           capture_output=True, text=True, timeout=120,
+                           env=env, cwd=cwd)
+        assert r.returncode == 0, r.stdout + r.stderr
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    def test_env_var_set_means_code_sets_no_directory(self, tmp_path):
+        got = self._child(REPO, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        assert got["dir"] == str(tmp_path)
+        assert got["set_dir_calls"] == 0
+        assert got["enabled"]
+        # the thresholds are zeroed either way: sub-second CPU compiles
+        # must be cacheable or CI could not prove the warm contract
+        assert got["min_secs"] == 0 and got["min_bytes"] == -1
+
+    def test_unset_means_one_fixed_path_in_the_checkout(self, tmp_path):
+        a = self._child(REPO)
+        b = self._child(str(tmp_path))      # another cwd, another process
+        assert a["dir"] == b["dir"] == os.path.join(REPO, ".jax_cache")
+        assert a["set_dir_calls"] == b["set_dir_calls"] == 1
+        assert a["enabled"] and b["enabled"]    # on by default
+
+
+class TestConfigure:
+    def test_configure_installs_accounting_once(self, monkeypatch):
         import jax
 
-        prev_dir = compile_cache._configured_dir
-        prev_cfg = jax.config.jax_compilation_cache_dir
-        target = str(tmp_path / "cache")
-        try:
-            assert compile_cache.configure(target) is True
-            assert compile_cache.enabled()
-            assert compile_cache.cache_dir() == target
-            assert os.path.isdir(target)
-            assert jax.config.jax_compilation_cache_dir == target
-            # sub-second CPU compiles must be cacheable (CI contract)
-            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
-            assert compile_cache.configure(target) is True   # idempotent
-        finally:
-            compile_cache._configured_dir = prev_dir
-            jax.config.update("jax_compilation_cache_dir", prev_cfg)
-            tracing.set_compile_cache_probe(
-                compile_cache.totals if prev_dir else None)
+        registered = []
+        monkeypatch.setattr(compile_cache, "_listener_installed", False)
+        monkeypatch.setattr(
+            "jax._src.monitoring.register_event_listener",
+            registered.append)
+        before = jax.config.jax_compilation_cache_dir
+        compile_cache.configure()
+        compile_cache.configure()
+        assert len(registered) == 1                 # idempotent
+        assert jax.config.jax_compilation_cache_dir == before  # dir untouched
+        # the listener folds jax's events into totals()
+        h0, m0 = compile_cache.totals()
+        registered[0]("/jax/compilation_cache/cache_hits")
+        registered[0]("/jax/compilation_cache/cache_misses")
+        assert compile_cache.totals() == (h0 + 1, m0 + 1)

@@ -2,9 +2,9 @@
 
 Everything here runs the Pallas kernels in interpret mode (the emulator
 executes the SAME kernel bodies Mosaic compiles on TPU, minus the
-compiler), so tier-1 exercises the flash fwd/bwd math, the block
-autotuner's cache plumbing, and the probe-failure capture path without a
-TPU in the loop. Complements tests/test_pallas_fused.py (which covers
+compiler), so tier-1 exercises the flash fwd/bwd math and the block
+autotuner's cache plumbing without a TPU in the loop (the TPU self-checks
+are pinned by tests/test_pallas_health.py). Complements tests/test_pallas_fused.py (which covers
 the fused-dropout/LN chain and sdpa routing): this file is the parity
 matrix — causal x dtype, ragged/odd lengths, multi-block grids, dropout
 vs a dense oracle — plus the PR-6 diagnostics surface.
@@ -25,12 +25,7 @@ from paddle_tpu.ops.pallas_kernels import (
     attention_path_counts,
     attention_path_totals,
     flash_block_sizes,
-    pallas_health_reasons,
 )
-
-if not pk._HAS_PALLAS:  # pragma: no cover
-    pytest.skip("Pallas unavailable in this jax build",
-                allow_module_level=True)
 
 
 def _qkv(B, H, Tq, Tk, D, dtype=jnp.float32, seed=0):
@@ -154,7 +149,15 @@ class TestBlockAutotune:
 
     def _fake_tpu(self, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(pk, "pallas_tpu_healthy", lambda: True)
+        monkeypatch.setitem(pk.flag.__globals__["_FLAGS"],
+                            "flash_autotune_blocks", True)
+
+    def test_flag_defaults_off_and_pins_128(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pk, "_sweep_flash_blocks",
+                            lambda *a: pytest.fail("swept with flag off"))
+        assert flash_block_sizes(8, 512, 512, 64, jnp.float32, True) == \
+            (128, 128)
 
     def test_sweep_cached_in_process_and_persisted(self, monkeypatch,
                                                    tmp_path):
@@ -218,56 +221,47 @@ class TestBlockAutotune:
         assert pk._AUTOTUNE_CACHE == {}
 
 
-class TestProbeFailureCapture:
-    def _fail_counter(self, tier):
-        from paddle_tpu.observability import metrics
-        c = metrics.counter("pt_pallas_probe_failures_total",
-                            "Pallas Mosaic health-probe failures, by tier",
-                            labelnames=("tier",))
-        return sum(int(ch.value) for labels, ch in c._series()
-                   if labels.get("tier") == tier)
+class TestFlashOverMesh:
+    """Under a GSPMD mesh the kernel runs once per shard inside a
+    shard_map (XLA cannot partition a Mosaic kernel; on the TPU a bare
+    pallas_call in a sharded step is a compile error). Per-shard results
+    must equal the unsharded kernel's — with dropout too: the interpret
+    path slices the SAME bits slab, so the masks are identical."""
 
-    def test_failure_records_reason_event_and_metric(self, monkeypatch):
-        monkeypatch.setattr(pk, "_PROBE_FAILURES", {})
-        events = []
-        from paddle_tpu.observability import journal
-        monkeypatch.setattr(
-            journal, "emit",
-            lambda event, **kw: events.append((event, kw)) or True)
-        before = self._fail_counter("base")
-        with pytest.warns(UserWarning, match="Pallas TPU probe failed"):
-            pk._note_probe_failure(
-                "base", "MosaicError: lowering exploded at dot_general")
-        reasons = pallas_health_reasons()
-        assert "MosaicError" in reasons["base"]
-        assert events == [("pallas_probe_failed",
-                           {"tier": "base",
-                            "reason": "MosaicError: lowering exploded at "
-                                      "dot_general"})]
-        assert self._fail_counter("base") == before + 1
+    @staticmethod
+    def _mesh():
+        from jax.sharding import Mesh
+        return Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
 
-    def test_forced_override_records_reason_only(self, monkeypatch):
-        """Env-forced verdicts are operator decisions: reason captured
-        for bench JSON, but no journal event / failure metric."""
-        monkeypatch.setattr(pk, "_PROBE_FAILURES", {})
-        events = []
-        from paddle_tpu.observability import journal
-        monkeypatch.setattr(
-            journal, "emit",
-            lambda event, **kw: events.append((event, kw)) or True)
-        before = self._fail_counter("prng")
-        with pytest.warns(UserWarning, match="Pallas PRNG probe failed"):
-            pk._note_probe_failure("prng", "forced off via env",
-                                   forced=True)
-        assert pallas_health_reasons() == {"prng": "forced off via env"}
-        assert events == []
-        assert self._fail_counter("prng") == before
+    @pytest.mark.parametrize("dropout_p", [0.0, 0.3])
+    def test_matches_unsharded_fwd_and_grad(self, dropout_p):
+        q, k, v = _qkv(4, 4, 32, 32, 16)
+        rng = (jax.random.bits(jax.random.PRNGKey(1), (16, 32, 32),
+                               jnp.uint32)
+               if dropout_p else jnp.zeros((1,), jnp.int32))
+        static = (True, True, dropout_p, 16, 16)
 
-    def test_reasons_returns_a_copy(self, monkeypatch):
-        monkeypatch.setattr(pk, "_PROBE_FAILURES", {"base": "x"})
-        r = pallas_health_reasons()
-        r["base"] = "mutated"
-        assert pk._PROBE_FAILURES["base"] == "x"
+        def loss(fn):
+            def f(q, k, v):
+                out = fn(q, k, v)
+                return (out * jnp.cos(out)).sum(), out
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                              has_aux=True))(q, k, v)
+
+        (_, want), want_g = loss(lambda q, k, v: _flash(q, k, v, rng,
+                                                        *static))
+        (_, got), got_g = loss(lambda q, k, v: pk._flash_over_mesh(
+            self._mesh(), q, k, v, rng, *static))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        assert len(got.sharding.device_set) == 4
+
+    def test_indivisible_shapes_are_left_to_the_caller(self):
+        q, k, v = _qkv(3, 4, 16, 16, 16)      # batch 3 over dp=2
+        assert pk._flash_over_mesh(
+            self._mesh(), q, k, v, jnp.zeros((1,), jnp.int32),
+            True, True, 0.0, 16, 16) is None
 
 
 class TestPathCounters:

@@ -480,12 +480,6 @@ class TestPtdoctorCLI:
         assert r.returncode == 2
         assert "no span events" in r.stdout
 
-    def test_bench_on_repo_history(self):
-        r = self._run("bench", REPO)
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "gpt2_small_train" in r.stdout
-        assert "failed/unparsed" in r.stdout     # r01 (rc=1), r05 (rc=124)
-
     def test_bench_flags_regressions(self, tmp_path):
         rows = [
             ("BENCH_r01.json", {"n": 1, "rc": 0, "parsed": {
@@ -503,7 +497,7 @@ class TestPtdoctorCLI:
         assert r.returncode == 0, r.stdout + r.stderr
         assert "step_ms REGRESSED" in r.stdout
         assert "mfu REGRESSED" in r.stdout
-        assert "r03" in r.stdout                 # failed run listed
+        assert "failed/unparsed" in r.stdout and "r03" in r.stdout
 
     def test_bench_empty_dir_exits_2(self, tmp_path):
         assert self._run("bench", str(tmp_path)).returncode == 2

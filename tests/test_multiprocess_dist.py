@@ -29,28 +29,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "dist_worker.py")
 
 
-def _cpu_collectives_supported():
-    """This jaxlib's CPU client has no cross-process collective runtime
-    (XlaRuntimeError: Multiprocess computations aren't implemented on the
-    CPU backend) — TIER1_FAILURES.md bucket 2. Skip the cross-process
-    COLLECTIVE tests there instead of burning minutes spawning gangs
-    doomed to abort; the gang-restart/shrink drills below use
-    single-device workers + file barriers and always run."""
-    import importlib.metadata
-    try:
-        ver = tuple(int(x) for x in
-                    importlib.metadata.version("jaxlib").split(".")[:3])
-    except Exception:
-        return True
-    return ver >= (0, 5, 0)
-
-
-needs_cpu_collectives = pytest.mark.skipif(
-    not _cpu_collectives_supported(),
-    reason="multiprocess collectives unsupported on this jaxlib's CPU "
-           "backend (TIER1_FAILURES.md bucket 2)")
-
-
 def _clean_env(out_prefix):
     env = dict(os.environ)
     # children build their own (single-device) platform config
@@ -73,7 +51,6 @@ def _single_process_losses(tmp_path):
         return json.load(f)["losses"]
 
 
-@needs_cpu_collectives
 def test_launch_two_processes_collectives_and_dp_parity(tmp_path):
     out = os.path.join(str(tmp_path), "launch")
     cmd = [sys.executable, "-m", "paddle_tpu.distributed.launch",
@@ -107,7 +84,6 @@ def test_launch_two_processes_collectives_and_dp_parity(tmp_path):
     assert ranks[0]["losses"][1] < ranks[0]["losses"][0]
 
 
-@needs_cpu_collectives
 def test_launch_four_processes_full_collective_battery(tmp_path):
     """nproc=4 (r4 VERDICT item 5): reduce_scatter, alltoall, and ring
     send/recv cross real process boundaries, alongside the r4 trio."""
@@ -138,7 +114,6 @@ def test_launch_four_processes_full_collective_battery(tmp_path):
     np.testing.assert_allclose(losses, single, rtol=1e-5)
 
 
-@needs_cpu_collectives
 def test_hybrid_process_dp_times_inprocess_mp(tmp_path):
     """The multi-host pod shape (r4 VERDICT item 5): 2 processes x 4
     local devices each = one 2x4 (dp, mp) global mesh; GSPMD computes a
@@ -160,7 +135,6 @@ def test_hybrid_process_dp_times_inprocess_mp(tmp_path):
                                    res["hybrid_oracle"], rtol=1e-5)
 
 
-@needs_cpu_collectives
 def test_elastic_kill_relaunch_resume(tmp_path):
     """Elastic-restart drill (r4 VERDICT item 5): rank 1 dies abruptly at
     step 2; the relaunch resumes from the checkpoint and the stitched
@@ -386,7 +360,6 @@ def test_gang_shrink_after_dead_rank(tmp_path):
     assert "2 -> 1" in d.stdout
 
 
-@needs_cpu_collectives
 def test_spawn_two_processes(tmp_path):
     out = os.path.join(str(tmp_path), "spawn")
     r = subprocess.run([sys.executable, WORKER, "spawn"],

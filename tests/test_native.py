@@ -23,6 +23,23 @@ def test_version():
     assert "paddle_tpu_native" in native.version()
 
 
+def test_failed_build_raises_instead_of_python_fallback(monkeypatch,
+                                                        tmp_path):
+    """No .build_failed marker, no quiet queue.Queue: a checkout whose
+    native sources do not build says so at the first use."""
+    import subprocess
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "missing.so"))
+
+    def failing_make(cmd, **kw):
+        raise subprocess.CalledProcessError(2, cmd, stderr=b"g++: boom")
+    monkeypatch.setattr(native.subprocess, "run", failing_make)
+    for _ in range(2):                      # not latched off after one try
+        with pytest.raises(RuntimeError, match="g\\+\\+: boom"):
+            native.available()
+
+
 def test_arena_alloc_free_stats():
     a = native.HostArena(chunk_bytes=1 << 20)
     ptrs = [a.alloc(1000) for _ in range(100)]

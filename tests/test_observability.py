@@ -491,8 +491,8 @@ class TestResilienceJournalWiring:
             labelnames=("site",)).labels("wire_test").value == 2
 
     def test_retry_standalone_load_without_package(self):
-        """bench.py loads retry.py with no package parent; the telemetry
-        import inside must degrade silently."""
+        """retry.py is pure stdlib and loadable with no package parent;
+        the telemetry import inside must degrade silently."""
         import importlib.util
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         spec = importlib.util.spec_from_file_location(

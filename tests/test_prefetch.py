@@ -183,6 +183,27 @@ class TestShutdown:
 
 
 # ----------------------------------------------- DataLoader / fit wiring
+    @pytest.mark.parametrize("device_feed", [0, 2])
+    def test_abandoned_loader_iterator_stops_its_producer(self, device_feed):
+        """An iterator dropped mid-epoch (a train loop that breaks, a
+        smoke that takes six batches of a million) closes the host
+        prefetch queue: the producer thread exits instead of staying
+        blocked on a full queue until the interpreter shuts down."""
+        import threading
+        before = set(threading.enumerate())
+        loader = DataLoader(ArrDataset(256), batch_size=2, shuffle=False,
+                            prefetch_to_device=device_feed)
+        it = iter(loader)
+        next(it), next(it)
+        started = set(threading.enumerate()) - before
+        assert started                       # the producer (and feeder)
+        it.close()
+        deadline = time.time() + 10
+        while any(t.is_alive() for t in started) and time.time() < deadline:
+            time.sleep(0.05)
+        assert not [t.name for t in started if t.is_alive()]
+
+
 class TestDataLoaderIntegration:
     def test_parity_with_and_without_device_feed(self):
         ds = ArrDataset(16)

@@ -137,9 +137,6 @@ class TestAttention:
 
     def test_flash_vs_xla(self):
         from paddle_tpu.ops import pallas_kernels as pk
-        import jax
-        if not pk._HAS_PALLAS:
-            pytest.skip("no pallas")
         q = np.random.RandomState(0).randn(1, 2, 32, 16).astype(np.float32)
         k = np.random.RandomState(1).randn(1, 2, 32, 16).astype(np.float32)
         v = np.random.RandomState(2).randn(1, 2, 32, 16).astype(np.float32)
@@ -152,8 +149,6 @@ class TestAttention:
     def test_flash_causal_cross_length(self):
         # bottom-right alignment: Tq < Tk (cached decode) must match XLA
         from paddle_tpu.ops import pallas_kernels as pk
-        if not pk._HAS_PALLAS:
-            pytest.skip("no pallas")
         r = np.random.RandomState(3)
         q = r.randn(1, 1, 16, 8).astype(np.float32)
         k = r.randn(1, 1, 48, 8).astype(np.float32)

@@ -177,7 +177,8 @@ def test_ring_attention_dropout_parity():
         for s_blk in range(sep):
             for kb in range(sep):
                 m = jax.random.bernoulli(
-                    jax.random.fold_in(kd, s_blk * sep + kb), 1.0 - p,
+                    jax.random.fold_in(kd, s_blk * sep + kb),
+                    jnp.float32(1.0 - p),
                     (bl, H, tl, tl))
                 keep[di * bl:(di + 1) * bl, :,
                      s_blk * tl:(s_blk + 1) * tl,
@@ -208,7 +209,8 @@ def test_ulysses_attention_dropout_parity():
     for di in range(dp):
         for d in range(sep):
             kd = jax.random.fold_in(jax.random.fold_in(key, di), d)
-            m = jax.random.bernoulli(jax.random.fold_in(kd, 0), 1.0 - p,
+            m = jax.random.bernoulli(jax.random.fold_in(kd, 0),
+                                     jnp.float32(1.0 - p),
                                      (bl, hl, T, T))
             keep[di * bl:(di + 1) * bl, d * hl:(d + 1) * hl] = np.asarray(m)
     want = _dropped_dense(q, k, v, True, jnp.asarray(keep), p)

@@ -339,7 +339,7 @@ class TestExposedCollective:
             return jax.lax.psum(x, "x") + 1.0
 
         fn = jax.shard_map(body, mesh=self._mesh(), in_specs=(P("x"),),
-                           out_specs=P("x"), check_rep=False)
+                           out_specs=P("x"), check_vma=False)
         jx = jax.make_jaxpr(fn)(jnp.zeros((128, 256), jnp.float32))
         fs = exposed_collective_findings(jx, "pos")
         assert [f.rule for f in fs] == ["exposed-collective"]
@@ -359,7 +359,7 @@ class TestExposedCollective:
 
         fn = jax.shard_map(body, mesh=self._mesh(),
                            in_specs=(P("x"), P(), P("x")),
-                           out_specs=P("x"), check_rep=False)
+                           out_specs=P("x"), check_vma=False)
         jx = jax.make_jaxpr(fn)(
             jnp.zeros((128, 256), jnp.float32),
             jnp.zeros((256, 256), jnp.float32),
@@ -376,7 +376,7 @@ class TestExposedCollective:
             return jax.lax.psum(x, "x") + 1.0
 
         fn = jax.shard_map(body, mesh=self._mesh(), in_specs=(P("x"),),
-                           out_specs=P("x"), check_rep=False)
+                           out_specs=P("x"), check_vma=False)
         jx = jax.make_jaxpr(fn)(jnp.zeros((16, 16), jnp.float32))
         assert exposed_collective_findings(jx, "small") == []
 
@@ -393,7 +393,7 @@ class TestExposedCollective:
 
         fn = jax.shard_map(body, mesh=self._mesh(),
                            in_specs=(P("x"), P()), out_specs=P("x"),
-                           check_rep=False)
+                           check_vma=False)
         jx = jax.make_jaxpr(fn)(
             jnp.zeros((128, 256), jnp.float32),
             jnp.zeros((256, 64), jnp.float32))
@@ -431,7 +431,7 @@ class TestStepCard:
             return jax.lax.psum(x, "x")
 
         fn = jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
-                           out_specs=P("x"), check_rep=False)
+                           out_specs=P("x"), check_vma=False)
         jx = jax.make_jaxpr(fn)(jnp.zeros((64, 64), jnp.float32))
         card = step_card_from_jaxpr(jx, "col")
         assert card["collectives"]["count"] == 1

@@ -13,6 +13,13 @@ TARGET="${@:-tests/}"
 LOG="${PRECOMMIT_GATE_LOG:-/tmp/_t1.log}"
 rm -f "$LOG"
 
+# The persistent compilation cache is on by default and lives in the
+# checkout (.jax_cache). The smokes below count retraces and compiles, so
+# they must not depend on what an earlier run left there: switch it off
+# with jax's own variable. The compile-cache smoke turns it back on over
+# a directory of its own.
+export JAX_ENABLE_COMPILATION_CACHE=false
+
 # Static-analysis gate (docs/STATIC_ANALYSIS.md): ptlint over paddle_tpu/
 # must report zero unsuppressed findings. --train-step also traces the
 # reference train step and runs the jaxpr rules (donation, sharding,
@@ -73,16 +80,17 @@ fi
 
 # Compile-cache smoke (docs/PERFORMANCE.md "Compile cache & input
 # pipeline"): the SAME 2-step gpt-tiny fit twice, fresh process each
-# time, sharing one PADDLE_TPU_COMPILE_CACHE_DIR. The warm run must
+# time, sharing one JAX_COMPILATION_CACHE_DIR. The warm run must
 # reload executables from disk: journal says compile_cache (hits >= 1),
 # retraces == 0, and compile wall time drops vs the cold run. (The
 # observability smoke above keeps the no-cache contract honest:
-# retraces == 1 when no cache dir is set.)
+# retraces == 1 when the cache is off.)
 if [ "$rc" -eq 0 ]; then
     CC_DIR="$(mktemp -d /tmp/pt_cc_smoke_XXXXXX)"
     cc_smoke_run() {
         timeout -k 10 180 env JAX_PLATFORMS=cpu \
-            PADDLE_TPU_COMPILE_CACHE_DIR="$CC_DIR/cache" \
+            JAX_ENABLE_COMPILATION_CACHE=true \
+            JAX_COMPILATION_CACHE_DIR="$CC_DIR/cache" \
             PT_CC_SMOKE_DIR="$CC_DIR" \
             PT_CC_SMOKE_ROLE="$1" \
             python - <<'EOF'
@@ -607,7 +615,9 @@ fi
 # prefill_le_buckets, continuous_beats_static) and gpt2_prefix_int8
 # (prefix hit TTFT <= 0.6x miss, reuse tokens/s >= no-reuse, int8
 # greedy parity >= 64 tokens, int8 bytes <= 0.55x bf16, int8 decode
-# compiles once) — bench.py emits bench_gate_failed otherwise.
+# compiles once). On the CPU these rows are a FUNCTIONAL gate at toy
+# shapes: each row says so itself (platform, unit), so none can be filed
+# as a chip measurement.
 if [ "$rc" -eq 0 ]; then
     SERVE_LOG="$(mktemp /tmp/pt_serve_bench_XXXXXX.json)"
     timeout -k 10 480 env JAX_PLATFORMS=cpu \
@@ -616,7 +626,10 @@ if [ "$rc" -eq 0 ]; then
     if [ "$bench_rc" -eq 0 ]; then
         python - "$SERVE_LOG" <<'EOF'
 import json, sys
-rows = [json.loads(l) for l in open(sys.argv[1]) if l.strip()]
+rows = [json.loads(l) for l in open(sys.argv[1])
+        if l.strip().startswith("{")]
+assert all(r["platform"] == "cpu" and "cpu" in r.get("unit", "cpu")
+           for r in rows), rows
 row = next(r for r in rows if r.get("config") == "gpt2_generate")
 assert "error" not in row, row
 for k in ("tokens_per_s", "ttft_ms_p50", "ttft_ms_p95", "latency_ms_p50",

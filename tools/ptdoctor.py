@@ -380,23 +380,11 @@ def cmd_summary(agg, directory) -> int:
     if convp:
         print("  conv paths: " + "  ".join(
             "%s=%d" % (k, int(v)) for k, v in sorted(convp.items())))
-    # Pallas health: probe-failure counter + the per-tier reason strings
-    # captured in pallas_probe_failed / pallas_health events
-    probe_fail = _counter_by_label(agg, directory,
-                                   "pt_pallas_probe_failures_total", "tier")
-    reasons = {}
-    for e in events:
-        if e.get("event") == "pallas_probe_failed" and e.get("tier"):
-            reasons[e["tier"]] = e.get("reason", "?")
-        elif e.get("event") == "pallas_health":
-            for tier, reason in (e.get("reasons") or {}).items():
-                reasons.setdefault(tier, reason)
-    if probe_fail or reasons:
-        print("  pallas probe failures: " + ("  ".join(
-            "%s=%d" % (k, int(v)) for k, v in sorted(probe_fail.items()))
-            or "(reasons only)"))
-        for tier in sorted(reasons):
-            print("    %s: %s" % (tier, reasons[tier]))
+    upd = _counter_by_label(agg, directory,
+                            "pt_optimizer_update_path_total", "path")
+    if upd:
+        print("  optimizer update paths: " + "  ".join(
+            "%s=%d" % (k, int(v)) for k, v in sorted(upd.items())))
     # serving: request/token counters + the prefill bucket mix from the
     # generation engine's pt_serve_* series (docs/SERVING.md)
     admitted = _counter_total(agg, directory, "pt_serve_admitted_total")
@@ -705,15 +693,13 @@ def cmd_profile(agg, directory) -> int:
     return 0
 
 
-#: device_kind substring (lowercase, first match wins) ->
-#: (peak dense bf16 TFLOP/s, peak HBM GB/s) per chip — same table family
-#: as benchmarks/train_bench.py's _PEAK_FLOPS, extended with bandwidth.
-_ROOFLINE_PEAKS = (
-    ("v6", (918.0, 1640.0)),
-    ("v5p", (459.0, 2765.0)),
-    ("v5", (197.0, 819.0)),      # v5e / "v5 lite"
-    ("v4", (275.0, 1228.0)),
-)
+def _load_device_peaks():
+    path = os.path.join(_REPO, "paddle_tpu", "observability",
+                        "device_peaks.py")
+    spec = importlib.util.spec_from_file_location("_pt_device_peaks", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _roofline_peaks(kind):
@@ -733,13 +719,12 @@ def _roofline_peaks(kind):
         gb = None
     if tf is not None and gb is not None:
         return tf, gb, "env"
-    low = (kind or "").lower()
-    for sub, (t, g) in _ROOFLINE_PEAKS:
-        if sub in low:
-            return (tf if tf is not None else t,
-                    gb if gb is not None else g,
-                    "env+table" if (tf is not None or gb is not None)
-                    else "table")
+    row = _load_device_peaks().lookup(kind)
+    if row is not None:
+        return (tf if tf is not None else row[0],
+                gb if gb is not None else row[1],
+                "env+table" if (tf is not None or gb is not None)
+                else "table")
     if tf is not None or gb is not None:
         return tf, gb, "env"
     return None, None, None
