@@ -50,13 +50,14 @@ Cache buffers are donated — XLA updates the paged KV in place in HBM.
 from __future__ import annotations
 
 import threading
+import time
 
 import numpy as np
 
 from ...framework import state
 from ...framework.random import RNG
 from ...framework.tensor import Tensor
-from ...observability import memprof, metrics, tracing
+from ...observability import memprof, metrics, spans, tracing
 from . import cache as cache_mod
 
 __all__ = ["GenerationEngine"]
@@ -157,6 +158,9 @@ class GenerationEngine:
         self.admit_info = {"prefix_len": 0, "bucket": 0}
 
         self._traces = {"prefill": 0, "decode": 0, "suffix": 0}
+        # instant the previous program's result reached the host; None
+        # before the first program and across an idle wait
+        self._fetched_ts = None
         self._prefill_tel = tracing.StepTelemetry("serve_prefill")
         self._suffix_tel = tracing.StepTelemetry("serve_suffix")
         self._decode_tel = tracing.StepTelemetry("serve_decode")
@@ -190,32 +194,36 @@ class GenerationEngine:
         and set the slot's length to `tl`. Runs inside a trace."""
         import jax
         import jax.numpy as jnp
-        kc, vc, ksc, vsc, lens = self._split_cache(cache)
-        s, z = slot.astype(jnp.int32), jnp.int32(0)
-        o = jnp.int32(offset)
-        if self.kv.quantized:
-            ks, ks_sc = cache_mod.quantize_kv(ks)
-            vs, vs_sc = cache_mod.quantize_kv(vs)
+        # the scope travels in the HLO's `op_name` metadata (HLO text,
+        # xprof's op profile) whatever XLA fuses the insert into; the
+        # fusions' instruction names do not change
+        with jax.named_scope("insert_kv"):
+            kc, vc, ksc, vsc, lens = self._split_cache(cache)
+            s, z = slot.astype(jnp.int32), jnp.int32(0)
+            o = jnp.int32(offset)
+            if self.kv.quantized:
+                ks, ks_sc = cache_mod.quantize_kv(ks)
+                vs, vs_sc = cache_mod.quantize_kv(vs)
+                if prefix is not None:
+                    pk, pv, pks, pvs = prefix
+                    ksc = jax.lax.dynamic_update_slice(ksc, pks, (z, s, z, z))
+                    vsc = jax.lax.dynamic_update_slice(vsc, pvs, (z, s, z, z))
+                ksc = jax.lax.dynamic_update_slice(ksc, ks_sc, (z, s, z, o))
+                vsc = jax.lax.dynamic_update_slice(vsc, vs_sc, (z, s, z, o))
+            elif prefix is not None:
+                pk, pv = prefix
             if prefix is not None:
-                pk, pv, pks, pvs = prefix
-                ksc = jax.lax.dynamic_update_slice(ksc, pks, (z, s, z, z))
-                vsc = jax.lax.dynamic_update_slice(vsc, pvs, (z, s, z, z))
-            ksc = jax.lax.dynamic_update_slice(ksc, ks_sc, (z, s, z, o))
-            vsc = jax.lax.dynamic_update_slice(vsc, vs_sc, (z, s, z, o))
-        elif prefix is not None:
-            pk, pv = prefix
-        if prefix is not None:
+                kc = jax.lax.dynamic_update_slice(
+                    kc, pk.astype(kc.dtype), (z, s, z, z, z))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, pv.astype(vc.dtype), (z, s, z, z, z))
             kc = jax.lax.dynamic_update_slice(
-                kc, pk.astype(kc.dtype), (z, s, z, z, z))
+                kc, ks.astype(kc.dtype), (z, s, z, o, z))
             vc = jax.lax.dynamic_update_slice(
-                vc, pv.astype(vc.dtype), (z, s, z, z, z))
-        kc = jax.lax.dynamic_update_slice(
-            kc, ks.astype(kc.dtype), (z, s, z, o, z))
-        vc = jax.lax.dynamic_update_slice(
-            vc, vs.astype(vc.dtype), (z, s, z, o, z))
-        lens = jax.lax.dynamic_update_slice(
-            lens, jnp.reshape(tl, (1,)), (s,))
-        return self._join_cache(kc, vc, ksc, vsc, lens)
+                vc, vs.astype(vc.dtype), (z, s, z, o, z))
+            lens = jax.lax.dynamic_update_slice(
+                lens, jnp.reshape(tl, (1,)), (s,))
+            return self._join_cache(kc, vc, ksc, vsc, lens)
 
     # -- traced bodies ----------------------------------------------------
 
@@ -425,13 +433,14 @@ class GenerationEngine:
                 if memprof.is_oom(e):
                     memprof.on_oom("serve_prefill", e)
                 raise
+            self._enqueued("host_gap_prefill")
             RNG.key = key
             self.kv.set_state(kvstate)
             self._last = last
             if self.prefix_cache is not None:
                 self._store_prefix(prompt, n, slot)
         self.admit_info = {"prefix_len": 0, "bucket": b}
-        return int(np.asarray(tok)[0, 0])
+        return int(self._fetch(tok)[0, 0])
 
     def _suffix_prefill(self, slot, prompt, n, p, entry, sb) -> int:
         padded = np.full((1, sb), self.pad_id, np.int32)
@@ -450,10 +459,11 @@ class GenerationEngine:
                 if memprof.is_oom(e):
                     memprof.on_oom("serve_suffix", e)
                 raise
+            self._enqueued("host_gap_prefill")
             RNG.key = key
             self.kv.set_state(kvstate)
             self._last = last
-        return int(np.asarray(tok)[0, 0])
+        return int(self._fetch(tok)[0, 0])
 
     def _store_prefix(self, prompt, n: int, slot: int) -> None:
         """Harvest the slot's freshly-prefilled K/V head (largest bucket
@@ -488,10 +498,37 @@ class GenerationEngine:
                 if memprof.is_oom(e):
                     memprof.on_oom("serve_decode", e)
                 raise
+            self._enqueued("host_gap_decode")
             RNG.key = key
             self.kv.set_state(kvstate)
             self._last = tok
-        return np.asarray(tok).reshape(-1)
+        return self._fetch(tok).reshape(-1)
+
+    # -- the host's side of the gap between two programs -------------------
+
+    def _enqueued(self, gap: str) -> None:
+        """The next program's enqueue has returned: the host's Python
+        since the previous program's tokens were fetched is one `gap`
+        span. The device waits longer than this: its gap also holds the
+        launch after the enqueue and the tokens' way back, which lie
+        under `fetch`."""
+        t0 = self._fetched_ts
+        if t0 is not None:
+            spans.record(gap, (time.perf_counter() - t0) * 1e3, t0=t0)
+
+    def _fetch(self, tok) -> np.ndarray:
+        """Block for a program's tokens (the `fetch` span, a child of
+        `decode_step` or `prefill`); the next host gap starts here."""
+        with spans.span("fetch") as sp:
+            out = np.asarray(tok)
+            self._fetched_ts = now = time.perf_counter()
+            sp.close(now)
+        return out
+
+    def note_idle(self) -> None:
+        """The caller waited for a request: the gap up to the next
+        program is not host work and is not recorded."""
+        self._fetched_ts = None
 
     # -- compile-once contract accounting ---------------------------------
 

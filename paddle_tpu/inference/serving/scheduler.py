@@ -43,6 +43,15 @@ TTFT = metrics.histogram(
     "pt_serve_ttft_seconds", "Submit-to-first-token latency per request")
 REQ_SECONDS = metrics.histogram(
     "pt_serve_request_seconds", "Submit-to-completion latency per request")
+OCCUPANCY_PCT = metrics.histogram(
+    "pt_serve_occupancy_pct",
+    "Live slots / max_batch x 100, one observation per decode step",
+    buckets=tuple(range(5, 101, 5)))
+ITL_MS = metrics.histogram(
+    "pt_serve_itl_ms",
+    "Gap between consecutive tokens of one request, milliseconds (one "
+    "observation per token after the first)",
+    buckets=metrics.exponential_buckets(0.01, 2.0, 24))
 
 _RID = itertools.count(1)
 
@@ -56,9 +65,12 @@ class Request:
     eos_id: Optional[int] = None
     rid: int = field(default_factory=lambda: next(_RID))
     tokens: List[int] = field(default_factory=list)
+    # one instant per token, on the batcher's clock: the instant the
+    # tokens of its step (the prefill, for the first) were fetched
+    token_ts: List[float] = field(default_factory=list)
     submit_ts: Optional[float] = None     # set at batcher.submit()
-    ttft_s: Optional[float] = None        # submit -> first token
-    latency_s: Optional[float] = None     # submit -> completion
+    ttft_s: Optional[float] = None        # token_ts[0] - submit_ts
+    latency_s: Optional[float] = None     # token_ts[-1] - submit_ts
     slot: Optional[int] = None
     prefix_len: int = 0                   # cached-prefix tokens reused
     on_complete: Optional[Callable[["Request"], None]] = None
@@ -142,7 +154,8 @@ class ContinuousBatcher:
         if req.span is None:
             # direct-batcher callers get the root span here; the threaded
             # server begins it earlier, in the submitter's own thread
-            req.span = spans.begin("serve_request", rid=req.rid)
+            req.span = spans.begin("serve_request", t0=req.submit_ts,
+                                   rid=req.rid)
         if self.slo is not None:
             err = self.slo.check_admit(len(self.waiting))
             if err is not None:
@@ -170,7 +183,8 @@ class ContinuousBatcher:
             req.on_complete(req)
 
     def _complete(self, req: Request, completed: List[Request]) -> None:
-        req.latency_s = self._clock() - req.submit_ts
+        done = req.token_ts[-1]
+        req.latency_s = done - req.submit_ts
         req.slot = None
         req.outcome = "completed"
         COMPLETED.inc()
@@ -180,9 +194,10 @@ class ContinuousBatcher:
             # scheduler's own clock, so the three children sum to latency
             spans.record("decode_steps",
                          (req.latency_s - req.ttft_s) * 1e3,
-                         parent="serve_request", rid=req.rid,
-                         steps=len(req.tokens) - 1)
-        spans.end(req.span, tokens=len(req.tokens), outcome="completed")
+                         parent="serve_request", t0=req.token_ts[0],
+                         rid=req.rid, steps=len(req.tokens) - 1)
+        spans.end(req.span, done, tokens=len(req.tokens),
+                  outcome="completed")
         journal.emit("serve_complete", rid=req.rid,
                      tokens=len(req.tokens),
                      ttft_s=round(req.ttft_s, 6),
@@ -215,22 +230,24 @@ class ContinuousBatcher:
             req = self.waiting.popleft()
             n = len(np.asarray(req.prompt).reshape(-1))
             t_pre = self._clock()
-            tok = self.engine.prefill(slot, req.prompt)
-            now = self._clock()
+            # `step`: the decode step this admission runs ahead of
+            with spans.span("prefill", parent="serve_request", t0=t_pre,
+                            rid=req.rid, step=self.steps + 1) as sp:
+                tok = self.engine.prefill(slot, req.prompt)
+                now = self._clock()
+                # what THIS admission actually dispatched: on a prefix
+                # hit the bucket is the (smaller) suffix bucket and
+                # prefix_len counts the reused tokens
+                info = getattr(self.engine, "admit_info", None) or \
+                    {"prefix_len": 0, "bucket": self.engine.bucket_for(n)}
+                sp.close(now, bucket=info["bucket"])
             req.ttft_s = now - req.submit_ts
-            # what THIS admission actually dispatched: on a prefix hit
-            # the bucket is the (smaller) suffix bucket and prefix_len
-            # counts the reused tokens
-            info = getattr(self.engine, "admit_info", None) or \
-                {"prefix_len": 0, "bucket": self.engine.bucket_for(n)}
             req.prefix_len = int(info.get("prefix_len", 0))
             # queue_wait + prefill == ttft_s exactly: same clock, same
             # instants — the TTFT decomposition SERVING.md documents
             spans.record("queue_wait", (t_pre - req.submit_ts) * 1e3,
-                         parent="serve_request", rid=req.rid)
-            spans.record("prefill", (now - t_pre) * 1e3,
-                         parent="serve_request", rid=req.rid,
-                         bucket=info["bucket"])
+                         parent="serve_request", t0=req.submit_ts,
+                         rid=req.rid)
             if req.prefix_len > 0:
                 # prefix-cache hit: a serve_suffix child over the SAME
                 # interval as prefill (parent="prefill", not a sibling
@@ -238,9 +255,10 @@ class ContinuousBatcher:
                 # stays exact while the trace shows which admissions ran
                 # the suffix-only path
                 spans.record("serve_suffix", (now - t_pre) * 1e3,
-                             parent="prefill", rid=req.rid,
+                             parent="prefill", t0=t_pre, rid=req.rid,
                              prefix_len=req.prefix_len,
                              bucket=info["bucket"])
+            req.token_ts.append(now)
             req.tokens.append(tok)
             req.slot = slot
             ADMITTED.inc()
@@ -263,18 +281,28 @@ class ContinuousBatcher:
         """One scheduler iteration; returns requests completed by it."""
         completed: List[Request] = []
         self._admit(completed)
-        if self.active:
-            toks = self.engine.decode()
-            self.steps += 1
-            self.live_slot_steps += self.active
-            for slot, req in enumerate(self.slots):
-                if req is None:
-                    continue
-                req.tokens.append(int(toks[slot]))
-                TOKENS.inc()
-                if req.done:
-                    self.slots[slot] = None
-                    self._complete(req, completed)
+        live = self.active
+        if live:
+            n = self.steps + 1
+            with spans.span("decode_step", t0=self._clock(), step=n) as sp:
+                toks = self.engine.decode()
+                now = self._clock()     # the step's tokens are fetched
+                sp.close(now)
+            self.steps = n
+            self.live_slot_steps += live
+            with spans.span("harvest", t0=now, step=n) as sp:
+                for slot, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    req.tokens.append(int(toks[slot]))
+                    ITL_MS.observe((now - req.token_ts[-1]) * 1e3)
+                    req.token_ts.append(now)
+                    if req.done:
+                        self.slots[slot] = None
+                        self._complete(req, completed)
+                TOKENS.inc(live)
+                OCCUPANCY_PCT.observe(100.0 * live / len(self.slots))
+                sp.close(self._clock())
         OCCUPANCY.set(self.active)
         return completed
 

@@ -7,9 +7,10 @@ serve time and pass into the jitted steps as arguments (engine.py), so
 N workers cost one copy of the weights plus N caches.
 
 Reuses the existing production machinery instead of growing parallel
-plumbing: every loop iteration calls `resilience.health.tick()` (the
-launcher's heartbeat/hang detector watches serving like it watches
-training), a crashed loop dumps a flight-recorder crash bundle before
+plumbing: every program the engine dispatches ticks
+`resilience.health` through its `StepTelemetry`, telemetry on or off
+(the launcher's heartbeat/hang detector watches serving like it
+watches training), a crashed loop dumps a flight-recorder crash bundle before
 failing its in-flight requests, and queue depth is exported through
 the PR 2 metrics registry (`pt_serve_queue_depth`).
 """
@@ -21,7 +22,6 @@ import time
 from typing import List, Optional, Sequence
 
 from ...observability import flight, httpd, metrics, spans
-from ...resilience import health
 from .engine import GenerationEngine
 from .scheduler import ContinuousBatcher, Request
 from .slo import AdmissionController, ShedError, SLOPolicy
@@ -196,7 +196,8 @@ class InferenceServer:
         # root span begins on the SUBMITTER's thread (same instant as
         # submit_ts) and ends in the worker loop at _complete — the
         # begin/end cross-thread form exists for exactly this hand-off
-        req.span = spans.begin("serve_request", rid=req.rid)
+        req.span = spans.begin("serve_request", t0=req.submit_ts,
+                               rid=req.rid)
         handle = ServeHandle(req)
         req.on_complete = handle._completed
         self._queue.put(handle)
@@ -220,22 +221,32 @@ class InferenceServer:
         except Exception as exc:   # invalid request must not kill the loop
             handle._finish(exc)
 
+    def _wait_for_request(self) -> Optional[ServeHandle]:
+        """Block until a request arrives; None once the server stops."""
+        while not self._stop.is_set():
+            try:
+                return self._queue.get(timeout=self._poll_s)
+            except queue.Empty:
+                pass
+        return None
+
     def _loop(self, engine: GenerationEngine) -> None:
         batcher = ContinuousBatcher(engine, slo=self._slo)
         try:
             while True:
-                self._drain_into(batcher)
+                with spans.span("drain", step=batcher.steps + 1):
+                    self._drain_into(batcher)
                 if batcher.idle:
-                    if self._stop.is_set():
+                    with spans.span("loop_idle", step=batcher.steps + 1):
+                        handle = self._wait_for_request()
+                    # the device sat idle for want of a request, not of
+                    # the host: no host_gap across this
+                    engine.note_idle()
+                    if handle is None:
                         return
-                    try:
-                        handle = self._queue.get(timeout=self._poll_s)
-                    except queue.Empty:
-                        continue
                     self._submit_or_fail(batcher, handle)
                     continue
                 batcher.step()
-                health.tick()
         except BaseException as exc:
             flight.dump_crash_bundle("serve_loop", exc)
             self._fail_pending(batcher, exc)

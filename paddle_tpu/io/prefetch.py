@@ -120,7 +120,7 @@ class DevicePrefetcher:
             tracing.record_feed_stall(wait_ms)
             # the queue wait alone, as a child of the caller's "feed"
             # span: separates feed starvation from batch unpack cost
-            spans.record("feed_wait", wait_ms, parent=spans.current())
+            spans.record("feed_wait", wait_ms, parent=spans.current(), t0=t0)
             return payload
         self._done = True
         if kind == "exc":

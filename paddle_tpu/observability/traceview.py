@@ -8,8 +8,9 @@ chrome://tracing and https://ui.perfetto.dev open directly:
 
   * one track per rank x thread (pid = rank, tid = the emitting
     thread), named via metadata events;
-  * every span as a complete ("X") slice — span journal events record
-    their END timestamp plus ``dur_ms``, so slice start = ts - dur;
+  * every span as a complete ("X") slice from its recorded start
+    ``t0`` (journals older than that field: the emit time ``ts`` minus
+    ``dur_ms``, which misplaces a span banked after the fact);
   * ``serve_admit`` / ``serve_complete`` / ``serve_shed`` as instant
     events and a flow arrow per request (id = rid) from the
     ``serve_request`` slice's start to its completion — the
@@ -57,7 +58,9 @@ TRACE_JSON = "trace.json"
 _TRAIN = frozenset(("step", "feed", "feed_wait", "compile", "dispatch",
                     "host"))
 _SERVE = frozenset(("serve_request", "queue_wait", "prefill",
-                    "serve_suffix", "decode_steps"))
+                    "serve_suffix", "decode_steps", "decode_step", "fetch",
+                    "harvest", "drain", "loop_idle", "host_gap_decode",
+                    "host_gap_prefill"))
 
 
 # ------------------------------------------------- shared serializer
@@ -114,6 +117,14 @@ def _tid_of(rec: dict) -> int:
         return 0
 
 
+def _start_of(rec: dict) -> float:
+    """A span's start on the epoch clock."""
+    t0 = rec.get("t0")
+    if isinstance(t0, (int, float)):
+        return float(t0)
+    return rec["ts"] - rec["dur_ms"] / 1e3
+
+
 def _cat_of(name: str) -> str:
     if name in _TRAIN:
         return "train"
@@ -137,7 +148,7 @@ def build_trace(records: List[dict]) -> List[dict]:
              and isinstance(r.get("ts"), (int, float))]
     if not spans_ and not admits and not completes and not sheds:
         return []
-    starts = [r["ts"] - r["dur_ms"] / 1e3 for r in spans_]
+    starts = [_start_of(r) for r in spans_]
     starts += [r["ts"] for r in admits + completes + sheds]
     t0 = min(starts)
 
@@ -155,7 +166,7 @@ def build_trace(records: List[dict]) -> List[dict]:
         pid, tid = _rank_of(r), _tid_of(r)
         tracks[(pid, tid)] = None
         name = str(r.get("name", "?"))
-        start_us = us(r["ts"] - r["dur_ms"] / 1e3)
+        start_us = us(_start_of(r))
         args = {}
         for key in ("parent", "trace"):
             if r.get(key):
@@ -188,7 +199,8 @@ def build_trace(records: List[dict]) -> List[dict]:
                 fin_us, fin_pid, fin_tid = us(done["ts"]), \
                     _rank_of(done), _tid_of(done)
             else:
-                fin_us, fin_pid, fin_tid = us(r["ts"]), pid, tid
+                fin_us, fin_pid, fin_tid = \
+                    start_us + r["dur_ms"] * 1e3, pid, tid
             events.append(trace_event(
                 "serve_request", fin_us, pid=fin_pid, tid=fin_tid,
                 cat="serve", ph="f", bp="e", id=int(rid)))

@@ -17,9 +17,10 @@ engine (jit train/eval step, to_static TracedLayer, static Executor):
     entry-to-entry gaps into `pt_step_interval_seconds{engine=...}`,
     whose mean IS the steady-state step time of a saturated loop.
 
-Each span also opens a `utils.profiler.RecordEvent` (lazily imported so
-this module stays pure stdlib) so the same boundaries show up in chrome
-traces when the profiler is on.
+Each dispatch is also one `spans.span` — `dispatch` on a hit, `compile`
+on a miss, labelled `step:<engine>` / `compile:<engine>` on the profiler's
+line — so the same boundary is a `pt_span_ms` series, a record in the
+span ring and a `jax.profiler` host annotation, through one call.
 
 Telemetry defaults ON and is cheap (a set lookup + two clock reads per
 step); `PADDLE_TPU_TELEMETRY=0` or `enable(False)` turns the spans into
@@ -92,28 +93,26 @@ FEED_STALL = metrics.histogram(
 class _Span:
     """One dispatch measurement; hand back via StepTelemetry.step()."""
 
-    __slots__ = ("tel", "miss", "t0", "_ev", "cache0", "_pspan")
+    __slots__ = ("tel", "miss", "t0", "cache0", "_pspan")
 
     def __init__(self, tel: "StepTelemetry", miss: bool):
         self.tel = tel
         self.miss = miss
-        self._ev = None
         self.cache0 = None
         self._pspan = None
 
     def __enter__(self):
         if self.tel is not None:
-            self._ev = _record_event(
-                ("compile:" if self.miss else "step:") + self.tel.engine)
-            if self._ev is not None:
-                self._ev.begin()
-            # the same boundary as a profiling span: "compile" on a cache
-            # miss, "dispatch" on a hit — nested under whatever span the
-            # caller holds open (fit's "step"), so step time decomposes
-            self._pspan = _open_span("compile" if self.miss else "dispatch",
-                                     engine=self.tel.engine)
-            if self._pspan is not None:
-                self._pspan.__enter__()
+            # "compile" on a cache miss, "dispatch" on a hit — nested
+            # under whatever span the caller holds open (fit's "step",
+            # the batcher's "decode_step"), so step time decomposes.
+            # Imported here: spans imports this module for enabled()
+            from . import spans
+            name, label = ("compile", "compile:") if self.miss \
+                else ("dispatch", "step:")
+            self._pspan = spans.span(name, engine=self.tel.engine,
+                                     label=label + self.tel.engine)
+            self._pspan.__enter__()
             if self.miss and _cache_probe is not None:
                 try:
                     self.cache0 = _cache_probe()
@@ -125,46 +124,13 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         if self.tel is not None:
             dt = time.perf_counter() - self.t0
-            if self._ev is not None:
-                self._ev.end()
-            if self._pspan is not None:
-                self._pspan.__exit__(exc_type, exc, tb)
+            self._pspan.__exit__(exc_type, exc, tb)
             if exc_type is None:
                 self.tel._finish(self, dt)
         return False
 
 
 _NULL_SPAN = _Span(None, False)
-
-_spans_mod = None
-
-
-def _open_span(name: str, **attrs):
-    """Profiling span for a dispatch boundary. Lazy + cached import so
-    tracing (imported by spans for the enabled() switch) never forms a
-    load-time cycle with it; returns None if spans is unavailable."""
-    global _spans_mod
-    if _spans_mod is None:
-        try:
-            from . import spans as _spans_mod_imp
-            _spans_mod = _spans_mod_imp
-        except Exception:
-            _spans_mod = False
-    if _spans_mod is False:
-        return None
-    return _spans_mod.span(name, **attrs)
-
-
-def _record_event(name: str):
-    # lazy: utils.profiler pulls in jax; only touch it when a profiler
-    # session could actually be live
-    try:
-        from ..utils import profiler
-        if profiler.profiler_enabled():
-            return profiler.RecordEvent(name)
-    except Exception:
-        pass
-    return None
 
 
 class StepTelemetry:
@@ -186,6 +152,9 @@ class StepTelemetry:
 
     def step(self, signature) -> _Span:
         if not _enabled:
+            # liveness does not go off with telemetry: the launcher's
+            # hang detector still has to see this loop make progress
+            _health_tick()
             return _NULL_SPAN
         miss = signature not in self._seen
         if miss:
@@ -242,8 +211,10 @@ _health_tick_fn = None
 
 
 def _health_tick():
-    """Any finished engine dispatch counts as liveness for the launcher's
-    hang detector. Lazy + cached: observability must not import resilience
+    """Any engine dispatch counts as liveness for the launcher's hang
+    detector: the one tick source every loop shares (`Model.evaluate`, a
+    hand-written loop over a compiled step, `Executor.run`, the server's
+    decode loop). Lazy + cached: observability must not import resilience
     at module load (resilience imports observability back, best-effort)."""
     global _health_tick_fn
     if _health_tick_fn is None:

@@ -20,7 +20,9 @@ goes stale while the pid is still alive (distributed/launch.py).
 Tick sources (all rate-limited through one writer, default 1s):
   * `Model.fit`'s batch loop (hapi/model.py, next to the chaos hook);
   * `TrainEpochRange.get()` at every epoch boundary;
-  * `StepTelemetry._finish` — any engine dispatch counts as progress.
+  * `StepTelemetry` — any engine dispatch counts as progress: a compiled
+    train/eval step, `Executor.run`, the server's prefill and decode
+    (at `_finish`; with telemetry off, as `step()` hands out the no-op).
 
 Workers configure themselves from the env the launcher exports
 (`PADDLE_TPU_HEARTBEAT_DIR` + `PADDLE_TRAINER_ID`); without it every hook
