@@ -156,22 +156,23 @@ def bench_fused_adamw(numel=768 * 3072, dtype=jnp.float32):
 
 
 def bench_paged_decode(B=8, H=12, T=2048, D=64, live=256, quantized=True,
-                       dtype=jnp.float32):
+                       dtype=jnp.float32, L=2):
     """The serving megakernel vs the full-depth masked einsum it
-    replaces: CHAIN fused decode steps (cache threaded through, length
-    pinned at `live`) against the same steps as write + dequant + masked
-    einsum over all T positions. The speedup is the HBM-traffic ratio
-    the clamped BlockSpec buys (reads scale with `live`, not T)."""
+    replaces: CHAIN fused decode steps on one layer of a stacked
+    [L, B, H, T, D] cache (updated in place, length pinned at `live`)
+    against the same steps as write + dequant + masked einsum over all T
+    positions of one layer. The speedup is the HBM-traffic ratio the
+    clamped BlockSpec buys (reads scale with `live`, not T)."""
     from paddle_tpu.ops import pallas_kernels as pk
     from paddle_tpu.inference.serving.cache import quantize_kv
-    blk = pk._paged_block(T)
     interp = jax.default_backend() != "tpu"
+    blk = pk._paged_block(T, interp)
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(B, H, 1, D), dtype)
     nk = jnp.asarray(rs.randn(B, H, 1, D), dtype)
     nv = jnp.asarray(rs.randn(B, H, 1, D), dtype)
-    kf = jnp.asarray(rs.randn(B, H, T, D), dtype)
-    vf = jnp.asarray(rs.randn(B, H, T, D), dtype)
+    kf = jnp.asarray(rs.randn(L, B, H, T, D), dtype)
+    vf = jnp.asarray(rs.randn(L, B, H, T, D), dtype)
     lens = jnp.full((B,), live, jnp.int32)
     if quantized:
         kc, ks = quantize_kv(kf)
@@ -182,11 +183,9 @@ def bench_paged_decode(B=8, H=12, T=2048, D=64, live=256, quantized=True,
     @jax.jit
     def fused(q, kc, vc, ks, vs):
         for _ in range(CHAIN):
-            out, kc, vc, ks2, vs2 = pk._paged_decode(
-                q, kc, vc, lens, nk, nv, ks, vs, block_k=blk,
+            out, kc, vc, ks, vs = pk._paged_decode(
+                q, kc, vc, lens, nk, nv, ks, vs, layer=L - 1, block_k=blk,
                 interpret=interp)
-            if quantized:
-                ks, vs = ks2, vs2
             q = (q + 1e-3 * out).astype(q.dtype)
         return q
 
@@ -223,11 +222,15 @@ def bench_paged_decode(B=8, H=12, T=2048, D=64, live=256, quantized=True,
             q = (q + 1e-3 * out).astype(q.dtype)
         return q
 
+    def one(a):
+        return None if a is None else a[L - 1]
+
     tp = timeit(fused, q, kc, vc, ks, vs, iters=3) / CHAIN
-    tx = timeit(einsum, q, kc, vc, ks, vs, iters=3) / CHAIN
+    tx = timeit(einsum, q, one(kc), one(vc), one(ks), one(vs),
+                iters=3) / CHAIN
     return {"config": "paged_decode_attention",
             "kernel": "paged_decode_attention",
-            "shape": [B, H, T, D], "live_len": live,
+            "shape": [L, B, H, T, D], "live_len": live,
             "block_k": blk, "int8": bool(quantized),
             "dtype": str(dtype.__name__),
             "pallas_ms": round(tp * 1e3, 3), "xla_ms": round(tx * 1e3, 3),

@@ -178,14 +178,17 @@ def paged_decode_cost(batch: int, n_heads: int, t_max: int, head_dim: int,
     einsum path vs fused Pallas megakernel — the static proof that the
     fused path's bytes scale with LIVE length, not cache capacity.
 
-    The einsum path reads (and for int8, dequantizes to f32) the full
+    One layer's call on the stacked [L, B, H, t_max, D] cache. The einsum
+    path reads (and for int8, dequantizes to f32) that layer's full
     [B, H, t_max, D] K and V every step; with `windows` it reads the
     smallest prefill bucket covering max(lens)+1, still shared across
     the whole batch. The megakernel's clamped BlockSpec index map reads
     only each slot's live blocks: ceil((live+1)/block_k)·block_k
     positions per (slot, head). Scales add 4 bytes/position when
-    quantized. q/new-token/output traffic is identical on both paths
-    and omitted."""
+    quantized. Both paths update the stacked cache in place (the kernel
+    writes back the one append block a (slot, head), the einsum path
+    scatters one row); that write, and the q/new-token/output traffic,
+    are the same order on both paths and omitted."""
     kv_b = (1 if quantized else dtype_bytes) * head_dim
     if quantized:
         kv_b += 4                       # f32 per-token k/v scale

@@ -28,13 +28,16 @@ Two throughput multipliers live here (ROADMAP item 3c):
     prompt skip recomputing it — the engine copies the cached K/V into
     the slot and prefills only the suffix.
 
-`LayerCacheView` is the per-layer window handed to `GPTAttention`
-inside a traced serving step: the attention layer writes the step's
-K/V at each slot's length index and REPLACES `.k`/`.v` (and the
-scales, when quantized) on the view with the updated buffers, which
-the engine stacks back into the cache state it returns from the jitted
-function. The view is a plain python carrier of traced arrays scoped
-to one trace — nothing escapes it.
+`LayerCacheView` is what `GPTAttention` is handed for one layer inside a
+traced decode step: a layer index into ONE `StackedKV` carrier that all
+the step's views share. The attention layer appends the step's K/V row
+to its layer of the stacked buffers, in place (the paged kernel aliases
+the cache to its output; the einsum fallback scatters one row a slot),
+and REPLACES the carrier's arrays with the updated ones. After the model
+has run, the carrier's arrays are the cache state the jitted function
+returns: nothing is sliced out of the stacked cache and nothing is
+stacked back. The carrier is a plain python holder of traced arrays
+scoped to one trace — nothing escapes it.
 """
 from __future__ import annotations
 
@@ -44,8 +47,8 @@ from typing import Optional, Sequence, Tuple
 
 from ...observability import metrics
 
-__all__ = ["LayerCacheView", "PagedKVCache", "PrefixCache", "bucket_for",
-           "dequantize_kv", "quantize_kv"]
+__all__ = ["LayerCacheView", "PagedKVCache", "PrefixCache", "StackedKV",
+           "bucket_for", "dequantize_kv", "quantize_kv"]
 
 PREFIX_HITS = metrics.counter(
     "pt_prefix_cache_hits_total",
@@ -65,16 +68,33 @@ PREFIX_CACHE_BYTES_ENV = "PADDLE_TPU_PREFIX_CACHE_BYTES"
 _PREFIX_CACHE_DEFAULT = 256 << 20
 
 
-class LayerCacheView:
-    """One layer's slice of the paged cache during a traced step.
+class StackedKV:
+    """The stacked cache arrays of one traced decode step.
 
-    k/v: [B, n_heads, max_seq_len, head_dim] (traced); lens: int32 [B].
-    For a quantized cache, k/v are int8 and k_scale/v_scale carry the
-    float32 per-(slot, head, token) scales [B, n_heads, max_seq_len]
-    (None otherwise). `GPTAttention.forward` detects this type
-    (duck-typed on `.lens`), writes the incoming K/V at each slot's
-    `lens` offset (quantizing on append), attends over positions
-    `<= lens`, and stores the updated buffers back on the view.
+    k/v: [n_layers, B, n_heads, max_seq_len, head_dim] (traced); lens:
+    int32 [B], each slot's length BEFORE this step's token. For a
+    quantized cache k/v are int8 and k_scale/v_scale carry the float32
+    per-(layer, slot, head, token) scales [n_layers, B, n_heads,
+    max_seq_len] (None otherwise). Each layer's attention replaces the
+    arrays with its updated ones."""
+
+    __slots__ = ("k", "v", "lens", "k_scale", "v_scale")
+
+    def __init__(self, k, v, lens, k_scale=None, v_scale=None):
+        self.k = k
+        self.v = v
+        self.lens = lens
+        self.k_scale = k_scale
+        self.v_scale = v_scale
+
+
+class LayerCacheView:
+    """One layer of the paged cache during a traced step: `layer` (a
+    static int) into the `StackedKV` carrier `kv` that every view of the
+    step shares. `GPTAttention.forward` detects this type (duck-typed on
+    `.lens`), writes the incoming K/V at `(layer, slot, :, lens[slot])`
+    (quantizing on append), attends that layer over positions `<= lens`,
+    and stores the updated stacked buffers back on the carrier.
 
     `windows`: optional static tuple of attend-window lengths (the
     engine passes its prefill buckets + max_seq_len, sorted). The
@@ -84,16 +104,16 @@ class LayerCacheView:
     full-depth attention (legacy callers). Shapes stay static either
     way — the traced lens picks a branch, never a shape."""
 
-    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "windows")
+    __slots__ = ("kv", "layer", "windows")
 
-    def __init__(self, k, v, lens, k_scale=None, v_scale=None,
-                 windows=None):
-        self.k = k
-        self.v = v
-        self.lens = lens
-        self.k_scale = k_scale
-        self.v_scale = v_scale
+    def __init__(self, kv, layer, windows=None):
+        self.kv = kv
+        self.layer = int(layer)
         self.windows = windows
+
+    @property
+    def lens(self):
+        return self.kv.lens
 
 
 def bucket_for(length: int, buckets: Sequence[int]) -> int:

@@ -347,11 +347,11 @@ class GenerationEngine:
             RNG.key = key
             gpt = self._gpt
             kc, vc, ksc, vsc, lens = self._split_cache(cache)
+            # one carrier of the stacked arrays; each layer's attention
+            # appends its row in place and leaves the updated arrays here
+            kv = cache_mod.StackedKV(kc, vc, lens, ksc, vsc)
             views = [cache_mod.LayerCacheView(
-                        kc[i], vc[i], lens,
-                        None if ksc is None else ksc[i],
-                        None if vsc is None else vsc[i],
-                        windows=self._decode_windows)
+                        kv, i, windows=self._decode_windows)
                      for i in range(self._n_layers)]
             # new token's absolute position == tokens already resident;
             # clamped so idle slots that hit the wall index a real row
@@ -365,13 +365,9 @@ class GenerationEngine:
                 logits = _lm_logits(
                     hidden, gpt.embeddings.word_embeddings.weight)
             tok = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
-            kc = jnp.stack([v.k for v in views])
-            vc = jnp.stack([v.v for v in views])
-            if self.kv.quantized:
-                ksc = jnp.stack([v.k_scale for v in views])
-                vsc = jnp.stack([v.v_scale for v in views])
             lens = jnp.minimum(lens + 1, jnp.int32(self.max_seq_len))
-            return self._join_cache(kc, vc, ksc, vsc, lens), tok, RNG.key
+            return (self._join_cache(kv.k, kv.v, kv.k_scale, kv.v_scale,
+                                     lens), tok, RNG.key)
         finally:
             for m, a in zip(self._mutable, saved):
                 m._data = a

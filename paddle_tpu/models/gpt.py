@@ -88,11 +88,14 @@ def _paged_decode_attention(q, k, v, view):
     """Single-token attention against a static-shape paged KV cache.
 
     q/k/v: [B, nh, 1, hd]; view (inference/serving/cache.LayerCacheView)
-    carries k/v buffers [B, nh, T_max, hd] + per-slot lengths int32 [B].
+    names a layer of the carrier `view.kv`, whose stacked k/v buffers
+    [L, B, nh, T_max, hd] + per-slot lengths int32 [B] every layer of the
+    step shares. Either path appends this layer's row to the stacked
+    buffers in place and leaves the updated buffers on the carrier.
 
     Fast path — the fused Pallas megakernel
     (ops/pallas_kernels.paged_decode_attention_or_none): one launch per
-    step doing length-masked flash attention over only the LIVE cache
+    layer doing length-masked flash attention over only the LIVE cache
     blocks, with the new-token append (incl. int8 quantize) and the
     k_scale/v_scale dequant folded in, so per-token HBM traffic scales
     with live length rather than cache capacity. Counter
@@ -100,8 +103,8 @@ def _paged_decode_attention(q, k, v, view):
 
     Fallback (flag off / ineligible shape / unhealthy Mosaic / CPU) —
     the windowed XLA einsum, counter {path=xla_paged}: the new K/V is
-    written at each slot's length index with a vmapped
-    `dynamic_update_slice`, then attention runs over a STATIC window
+    scattered to `(layer, slot, :, lens[slot], :)` of the stacked
+    buffer, then attention runs over a STATIC window of that layer
     chosen by `lax.switch` from view.windows (the serving prefill
     buckets + T_max): the smallest bucket covering max(lens)+1. Each
     branch slices, dequantizes (int8) and attends that window only, so
@@ -113,57 +116,45 @@ def _paged_decode_attention(q, k, v, view):
     import jax
     import jax.numpy as jnp
     qa, ka, va = q._data, k._data, v._data
-    lens = view.lens
+    kv, layer = view.kv, view.layer
+    lens = kv.lens
     from ..ops import pallas_kernels as pk
     fused = pk.paged_decode_attention_or_none(
-        qa, view.k, view.v, lens, ka, va, view.k_scale, view.v_scale)
+        qa, kv.k, kv.v, lens, ka, va, kv.k_scale, kv.v_scale, layer=layer)
     if fused is not None:
-        out, view.k, view.v, ks, vs = fused
-        if view.k_scale is not None:
-            view.k_scale, view.v_scale = ks, vs
+        out, kv.k, kv.v, kv.k_scale, kv.v_scale = fused
         return Tensor(out.astype(qa.dtype), _internal=True)
     pk._note_attn_path("xla_paged")
 
-    def _write(buf, new, ln):
-        z = jnp.int32(0)
-        return jax.lax.dynamic_update_slice(
-            buf, new, (z, ln.astype(jnp.int32), z))
+    t_max = kv.k.shape[3]
+    slots = jnp.arange(lens.shape[0])
+    # a slot that hit the wall rewrites its last row, as the kernel does
+    row = jnp.minimum(lens, t_max - 1).astype(jnp.int32)
 
-    def _write_scale(buf, new, ln):
-        return jax.lax.dynamic_update_slice(
-            buf, new, (jnp.int32(0), ln.astype(jnp.int32)))
+    def _append(buf, new):
+        """buf[layer, b, :, row[b]] = new[b, :, 0] for every slot b."""
+        return buf.at[layer, slots, :, row].set(new[:, :, 0].astype(
+            buf.dtype))
 
-    quantized = view.k_scale is not None
+    quantized = kv.k_scale is not None
     if quantized:
         from ..inference.serving.cache import quantize_kv
-        qk, k_sc = quantize_kv(ka)      # int8 [B,nh,1,hd] + f32 [B,nh,1]
-        qv, v_sc = quantize_kv(va)
-        kb = jax.vmap(_write)(view.k, qk, lens)
-        vb = jax.vmap(_write)(view.v, qv, lens)
-        ksb = jax.vmap(_write_scale)(view.k_scale, k_sc, lens)
-        vsb = jax.vmap(_write_scale)(view.v_scale, v_sc, lens)
-        view.k, view.v = kb, vb
-        view.k_scale, view.v_scale = ksb, vsb
-    else:
-        kb = jax.vmap(_write)(view.k, ka.astype(view.k.dtype), lens)
-        vb = jax.vmap(_write)(view.v, va.astype(view.v.dtype), lens)
-        view.k, view.v = kb, vb
-        ksb = vsb = None
+        ka, k_sc = quantize_kv(ka)      # int8 [B,nh,1,hd] + f32 [B,nh,1]
+        va, v_sc = quantize_kv(va)
+        kv.k_scale = _append(kv.k_scale, k_sc)
+        kv.v_scale = _append(kv.v_scale, v_sc)
+    kv.k = _append(kv.k, ka)
+    kv.v = _append(kv.v, va)
+    kc, vc, ksc, vsc = kv.k, kv.v, kv.k_scale, kv.v_scale
     scale = 1.0 / math.sqrt(qa.shape[-1])
-    t_max = kb.shape[2]
 
     def _attend(w):
-        """Attend the first `w` (static) cache positions."""
-        kw = jax.lax.slice_in_dim(kb, 0, w, axis=2)
-        vw = jax.lax.slice_in_dim(vb, 0, w, axis=2)
+        """Attend the first `w` (static) cache positions of the layer."""
+        kf = kc[layer, :, :, :w].astype(jnp.float32)
+        vf = vc[layer, :, :, :w].astype(jnp.float32)
         if quantized:
-            ksw = jax.lax.slice_in_dim(ksb, 0, w, axis=2)
-            vsw = jax.lax.slice_in_dim(vsb, 0, w, axis=2)
-            kf = kw.astype(jnp.float32) * ksw[..., None]
-            vf = vw.astype(jnp.float32) * vsw[..., None]
-        else:
-            kf = kw.astype(jnp.float32)
-            vf = vw.astype(jnp.float32)
+            kf = kf * ksc[layer, :, :, :w, None]
+            vf = vf * vsc[layer, :, :, :w, None]
         scores = jnp.einsum("bhqd,bhkd->bhqk", qa.astype(jnp.float32),
                             kf) * scale
         # freshly written token sits AT index lens -> keep pos <= lens

@@ -1492,23 +1492,30 @@ def flash_attention_or_none(query, key, value, attn_mask, is_causal,
 # ---------------------------------------------------------------------------
 # Fused paged-decode attention (the serving megakernel)
 #
-# One Pallas program family per decode step over grid (slot, head, k-block):
-# length-masked flash-style attention over the paged KV cache that READS
-# only the live blocks of each slot (the k/v BlockSpec index map clamps the
-# block index to lens[slot]//block_k, so Mosaic's revisiting optimization
-# never fetches the empty tail — per-token HBM traffic scales with live
-# length, not T_max). Folded into the same pass:
+# One Pallas program family per decode step over grid (slot, head, k-block),
+# called once a layer on the STACKED cache [L, B, H, T, D] with the layer
+# index as a second scalar-prefetch operand: length-masked flash-style
+# attention over the paged KV cache that READS only the live blocks of each
+# slot (the k/v BlockSpec index map clamps the block index to
+# lens[slot]//block_k, so Mosaic's revisiting optimization never fetches the
+# empty tail — per-token HBM traffic scales with live length, not T_max).
+# Folded into the same pass:
 #   * the new-token KV append: the incoming k/v row is substituted into the
 #     fetched append block in-register (and, for int8 caches, quantized
-#     in-kernel with quantize_kv's exact absmax rule) and the block is
+#     in-kernel with quantize_kv's exact absmax rule) and that ONE block is
 #     written back through the cache outputs — the einsum path's separate
-#     quantize + dynamic_update_slice round trip disappears;
+#     quantize + scatter round trip disappears;
 #   * int8 dequantization: k_scale multiplies the QK scores and v_scale the
 #     softmax probabilities (per-key scalars commute with the row dot
 #     products), so the f32 dequantized cache is never materialised.
-# Output blocks beyond a slot's live region are never written; those cache
-# positions are garbage by contract (exactly like the einsum path's
-# never-written tail) and masked out of every read.
+# The cache operands (k, v, and both scales when quantized) are aliased to
+# their outputs (input_output_aliases): the call updates the stacked buffer
+# in place, and L chained calls thread one HBM buffer through a decode step.
+# The cache output's block is the append block (layer, b, h, jm) for every
+# grid step j, written at j == jm alone; every other row of every layer
+# keeps its contents. Rows past a slot's length are whatever was there
+# before (zeros, or a previous tenant's rows) and are masked out of every
+# read.
 #
 # Dispatch: paged_decode_attention_or_none (flag, shape legality,
 # FLAGS_paged_flash_interpret for the CPU emulator). Flag off or an
@@ -1558,13 +1565,15 @@ def _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
     (slot, head). State (acc/m/l) lives in VMEM scratch across the j steps
     of a (slot, head) and is reset at j == 0. Steps past the append block
     (j > jm) do nothing — their k/v fetch was clamped to block jm by the
-    index map, so they cost neither HBM traffic nor compute."""
+    index map, so they cost neither HBM traffic nor compute. The cache
+    outputs hold the append block for every j (their index map does not
+    move with j) and are written at j == jm alone."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     nblk = pl.num_programs(2)
     ln = lens_ref[b]                          # live length, pre-append
     cl = jnp.minimum(ln, t_max - 1)           # append row (the einsum path's
-    jm = cl // block_k                        # dynamic_update_slice clamp)
+    jm = cl // block_k                        # index clamp)
     quantized = ks_ref is not None
 
     @pl.when(j == 0)
@@ -1588,26 +1597,25 @@ def _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
                 nk_ref[...].astype(jnp.float32))
             nvq, nvs = _kernel_quantize_row(
                 nv_ref[...].astype(jnp.float32))
-            kq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-                nkq, row_sel.shape, (0, 1)), k_ref[...])
-            vq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-                nvq, row_sel.shape, (0, 1)), v_ref[...])
             ks = jnp.where(app_lane, nks, ks_ref[...])         # [1, bk]
             vs = jnp.where(app_lane, nvs, vs_ref[...])
-            ko_ref[...] = kq
-            vo_ref[...] = vq
-            kso_ref[...] = ks
-            vso_ref[...] = vs
         else:
-            kq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-                nk_ref[...].astype(ko_ref.dtype), row_sel.shape, (0, 1)),
-                k_ref[...])
-            vq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-                nv_ref[...].astype(vo_ref.dtype), row_sel.shape, (0, 1)),
-                v_ref[...])
+            nkq = nk_ref[...].astype(ko_ref.dtype)
+            nvq = nv_ref[...].astype(vo_ref.dtype)
+            ks = vs = None
+        kq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
+            nkq, row_sel.shape, (0, 1)), k_ref[...])
+        vq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
+            nvq, row_sel.shape, (0, 1)), v_ref[...])
+
+        @pl.when(j == jm)
+        def _append():
             ko_ref[...] = kq
             vo_ref[...] = vq
-            ks = vs = None
+            if quantized:
+                kso_ref[...] = ks
+                vso_ref[...] = vs
+
         q = q_ref[...].astype(jnp.float32) * sm_scale          # [1, d]
         s = jax.lax.dot_general(q, kq.astype(jnp.float32),
                                 (((1,), (1,)), ((), ())),
@@ -1622,10 +1630,10 @@ def _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         pd = p * vs if quantized else p  # fold v_scale into the probs
-        # The append block's rows past ln are uninitialized cache (this
-        # kernel never writes the dead tail) — a NaN row there would
-        # poison the PV dot through 0*NaN, so hard-select both factors
-        # to zero rather than relying on p == 0.
+        # The append block's rows past ln were not written by this slot's
+        # tenant (stale rows; garbage in a test) — a NaN row there would
+        # poison the PV dot through 0*NaN, so hard-select both factors to
+        # zero rather than relying on p == 0.
         pd = jnp.where(pos <= ln, pd, 0.0)
         vrow = (j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, d), 0)) <= ln
@@ -1643,17 +1651,20 @@ def _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
         o_ref[...] = (acc_ref[...] / ell).astype(o_ref.dtype)
 
 
-def _paged_f_kernel(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, o_ref,
-                    ko_ref, vo_ref, acc_ref, m_ref, l_ref, *, block_k,
-                    t_max, sm_scale):
+def _paged_f_kernel(lens_ref, layer_ref, q_ref, nk_ref, nv_ref, k_ref,
+                    v_ref, o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref, *,
+                    block_k, t_max, sm_scale):
+    del layer_ref  # read by the index maps only
     _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, None, None,
                 o_ref, ko_ref, vo_ref, None, None, acc_ref, m_ref, l_ref,
                 block_k=block_k, t_max=t_max, sm_scale=sm_scale)
 
 
-def _paged_q_kernel(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
-                    vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
-                    acc_ref, m_ref, l_ref, *, block_k, t_max, sm_scale):
+def _paged_q_kernel(lens_ref, layer_ref, q_ref, nk_ref, nv_ref, k_ref,
+                    v_ref, ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref,
+                    vso_ref, acc_ref, m_ref, l_ref, *, block_k, t_max,
+                    sm_scale):
+    del layer_ref
     _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
                 vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref, acc_ref,
                 m_ref, l_ref, block_k=block_k, t_max=t_max,
@@ -1661,85 +1672,114 @@ def _paged_q_kernel(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
 
 
 def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
-                  v_scale, *, block_k, interpret):
-    """Run the megakernel. q/new_k/new_v: [B, H, 1, D]; caches
-    [B, H, T, D] (+f32 scales [B, H, T] when int8). Returns
-    (out, k_cache', v_cache', k_scale'|None, v_scale'|None)."""
+                  v_scale, *, layer, block_k, interpret):
+    """Run the megakernel on layer `layer` of the stacked cache.
+    q/new_k/new_v: [B, H, 1, D]; caches [L, B, H, T, D] (+f32 scales
+    [L, B, H, T] when int8); `layer` an int or an int32 scalar. Returns
+    (out, k_cache', v_cache', k_scale'|None, v_scale'|None): the caches
+    are the operands updated in place (aliased), one row a (slot, head).
+
+    The layer rides as a second scalar-prefetch operand, not as a
+    constant folded into the index maps: every layer of a decode step is
+    then the same kernel, traced and lowered once."""
     B, H, _, D = q.shape
-    T = k_cache.shape[2]
+    T = k_cache.shape[3]
     quantized = k_scale is not None
     sm_scale = float(D) ** -0.5
 
-    def kv_map(b, h, j, lens):
-        jm = jnp.minimum(lens[b], T - 1) // block_k
-        return (b, h, jnp.minimum(j, jm), _I0)
+    def _jm(b, lens):
+        return jnp.minimum(lens[b], T - 1) // block_k
 
-    def sc_map(b, h, j, lens):
-        jm = jnp.minimum(lens[b], T - 1) // block_k
-        return (b, h, _I0, jnp.minimum(j, jm))
+    def kv_map(b, h, j, lens, layer):
+        return (layer[0], b, h, jnp.minimum(j, _jm(b, lens)), _I0)
 
-    def tok_map(b, h, j, lens):
+    def kv_out_map(b, h, j, lens, layer):
+        return (layer[0], b, h, _jm(b, lens), _I0)
+
+    def sc_map(b, h, j, lens, layer):
+        return (layer[0], b, h, _I0, jnp.minimum(j, _jm(b, lens)))
+
+    def sc_out_map(b, h, j, lens, layer):
+        return (layer[0], b, h, _I0, _jm(b, lens))
+
+    def tok_map(b, h, j, lens, layer):
         return (b, h, _I0, _I0)
 
-    kv_spec = pl.BlockSpec((None, None, block_k, D), kv_map)
-    # scales ride as [B, H, 1, T]: a (1, block_k) block is then "the full
-    # second-minor axis x a lane-aligned slice", which Mosaic accepts; a
-    # block of 1 row out of H over [B, H, T] is neither
-    sc_spec = pl.BlockSpec((None, None, 1, block_k), sc_map)
+    kv_block = (None, None, None, block_k, D)
+    # scales ride as [L, B, H, 1, T]: a (1, block_k) block is then "the
+    # full second-minor axis x a lane-aligned slice", which Mosaic
+    # accepts; a block of 1 row out of H over [B, H, T] is neither
+    sc_block = (None, None, None, 1, block_k)
     tok_spec = pl.BlockSpec((None, None, 1, D), tok_map)
-    in_specs = [tok_spec, tok_spec, tok_spec, kv_spec, kv_spec]
-    out_specs = [tok_spec, kv_spec, kv_spec]
+    in_specs = [tok_spec, tok_spec, tok_spec,
+                pl.BlockSpec(kv_block, kv_map),
+                pl.BlockSpec(kv_block, kv_map)]
+    out_specs = [tok_spec, pl.BlockSpec(kv_block, kv_out_map),
+                 pl.BlockSpec(kv_block, kv_out_map)]
     out_shape = [jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
                  jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                  jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
     operands = [q, new_k, new_v, k_cache, v_cache]
     if quantized:
-        in_specs += [sc_spec, sc_spec]
-        out_specs += [sc_spec, sc_spec]
-        out_shape += [jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32)] * 2
-        operands += [k_scale.reshape(B, H, 1, T),
-                     v_scale.reshape(B, H, 1, T)]
+        sc_shape = k_scale.shape[:3] + (1, T)
+        in_specs += [pl.BlockSpec(sc_block, sc_map)] * 2
+        out_specs += [pl.BlockSpec(sc_block, sc_out_map)] * 2
+        out_shape += [jax.ShapeDtypeStruct(sc_shape, jnp.float32)] * 2
+        operands += [k_scale.reshape(sc_shape), v_scale.reshape(sc_shape)]
         kernel = _paged_q_kernel
     else:
         kernel = _paged_f_kernel
     kern = functools.partial(kernel, block_k=block_k, t_max=T,
                              sm_scale=sm_scale)
+    n_prefetch = 2                     # lens, layer
+    n_tok = 3                          # q, new_k, new_v
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=n_prefetch,
         grid=(B, H, T // block_k),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((1, D), jnp.float32),
                         pltpu.VMEM((1, _LANES), jnp.float32),
                         pltpu.VMEM((1, _LANES), jnp.float32)])
+    # cache operand i (after the prefetch and token operands) -> output
+    # 1 + i (after `out`); the alias indices count the prefetch operands
+    aliases = {n_prefetch + n_tok + i: 1 + i
+               for i in range(len(operands) - n_tok)}
     outs = _pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
+                        input_output_aliases=aliases,
                         interpret=interpret)(
-        lens.astype(jnp.int32), *operands)
+        lens.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     if quantized:
         out, ko, vo, kso, vso = outs
-        return out, ko, vo, kso.reshape(B, H, T), vso.reshape(B, H, T)
+        return (out, ko, vo, kso.reshape(k_scale.shape),
+                vso.reshape(v_scale.shape))
     out, ko, vo = outs
     return out, ko, vo, None, None
 
 
 def _check_paged():
     """The float paged-decode kernel at a small but representative shape
-    (multi-block, ragged lens incl. an idle slot): output AND the written
-    cache region are value-checked against the einsum oracle."""
-    B, H, T, D = 2, 2, 256, 64
+    (stacked cache of 3 layers, multi-block, ragged lens incl. an idle
+    slot), called for the middle layer: the output and that layer's cache
+    are value-checked against the einsum oracle, and every row the call
+    did not append — the other layers, and the named layer's rows past
+    each slot's length — must come back bit-identical."""
+    L, B, H, T, D = 3, 2, 2, 256, 64
+    layer = 1
     blk = _paged_block(T, interpret=False)
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
     nk = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
     nv = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
-    k = jnp.asarray(rs.randn(B, H, T, D), jnp.float32)
-    v = jnp.asarray(rs.randn(B, H, T, D), jnp.float32)
+    k = jnp.asarray(rs.randn(L, B, H, T, D), jnp.float32)
+    v = jnp.asarray(rs.randn(L, B, H, T, D), jnp.float32)
     lens = jnp.asarray([0, 130], jnp.int32)
 
     @jax.jit
     def run(q):
         out, ko, vo, _, _ = _paged_decode(
-            q, k, v, lens, nk, nv, None, None, block_k=blk,
+            q, k, v, lens, nk, nv, None, None, layer=layer, block_k=blk,
             interpret=False)
         return out, ko, vo
 
@@ -1749,8 +1789,8 @@ def _check_paged():
         z = jnp.int32(0)
         return jax.lax.dynamic_update_slice(buf, new, (z, ln, z))
 
-    kb = jax.vmap(wr)(k, nk, lens)
-    vb = jax.vmap(wr)(v, nv, lens)
+    kb = jax.vmap(wr)(k[layer], nk, lens)
+    vb = jax.vmap(wr)(v[layer], nv, lens)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kb) * (float(D) ** -0.5)
     valid = (jnp.arange(T)[None, None, None, :]
              <= lens[:, None, None, None])
@@ -1758,16 +1798,11 @@ def _check_paged():
     want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vb)
     out_ok = np.allclose(np.asarray(out), np.asarray(want), rtol=2e-3,
                          atol=2e-3)
-    # cache check restricted to live+append positions: the tail past lens
-    # is garbage by contract (never written, never read unmasked) — select
-    # rather than multiply so a NaN tail cannot leak into the comparison
-    live = np.asarray(valid)[:, :1, 0, :, None]                # [B,1,T,1]
-
-    def _live_eq(got, want):
-        return bool(np.allclose(np.where(live, np.asarray(got), 0.0),
-                                np.where(live, np.asarray(want), 0.0)))
-
-    cache_ok = _live_eq(ko, kb) and _live_eq(vo, vb)
+    # the whole stacked cache, exactly: the call changes one row a
+    # (slot, head) of the named layer and nothing else
+    cache_ok = all(
+        np.array_equal(np.asarray(got), np.asarray(was.at[layer].set(want)))
+        for got, was, want in ((ko, k, kb), (vo, v, vb)))
     if not (out_ok and cache_ok):
         raise PallasSelfCheckError(
             "paged-decode attention disagrees with the einsum oracle on "
@@ -1777,24 +1812,26 @@ def _check_paged():
 
 
 def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
-                                   new_v, k_scale=None, v_scale=None):
+                                   new_v, k_scale=None, v_scale=None, *,
+                                   layer):
     """Gate + dispatch for the fused paged-decode attention kernel.
 
     Arrays only (the Tensor-level caller is models/gpt.py's
-    _paged_decode_attention): q/new_k/new_v [B, H, 1, D], caches
-    [B, H, T, D] (+ scales [B, H, T] for int8), lens [B] int32 = live
-    length per slot BEFORE this token. Returns (out, k_cache', v_cache',
-    k_scale', v_scale') — the updated cache carries the appended token —
-    or None when the caller must take the windowed einsum (flag off,
-    ineligible shape, or interpret mode without
+    _paged_decode_attention): q/new_k/new_v [B, H, 1, D], the STACKED
+    caches [L, B, H, T, D] (+ scales [L, B, H, T] for int8), `layer` the
+    layer this call attends and appends to, lens [B] int32 = live length
+    per slot BEFORE this token. Returns (out, k_cache', v_cache',
+    k_scale', v_scale') — the stacked cache updated in place, carrying
+    the appended token — or None when the caller must take the windowed
+    einsum (flag off, ineligible shape, or interpret mode without
     FLAGS_paged_flash_interpret). Bumps
     pt_attn_path_total{path=paged_flash} at trace time when it fires."""
     if not flag("paged_flash_decode"):
         return None
-    if q.ndim != 4 or q.shape[2] != 1 or k_cache.ndim != 4:
+    if q.ndim != 4 or q.shape[2] != 1 or k_cache.ndim != 5:
         return None
     B, H, _, D = q.shape
-    T = k_cache.shape[2]
+    T = k_cache.shape[3]
     interpret = jax.default_backend() != "tpu"
     blk = _paged_block(T, interpret)
     if blk is None or D % 8 != 0 or D > 256:
@@ -1806,4 +1843,5 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
             return None  # keep the emulator cheap (CPU tests/smoke only)
     _note_attn_path("paged_flash")
     return _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
-                         v_scale, block_k=blk, interpret=interpret)
+                         v_scale, layer=layer, block_k=blk,
+                         interpret=interpret)

@@ -4,15 +4,20 @@ composed-XLA oracles.
 
 Three layers of evidence:
 
-  * kernel-level — `_paged_decode` vs a numpy oracle that replays the
-    exact serving semantics (append the new token at position lens[b],
-    dequantize the int8 window, attend over pos <= lens[b]), across
-    dtype (f32 / bf16 / int8-cache), ragged lens including idle slots,
-    NaN garbage in the unwritten tail, and the full-slot clamp;
+  * kernel-level — `_paged_decode` on one layer of a STACKED cache vs a
+    numpy oracle that replays the exact serving semantics (append the
+    new token at position lens[b], dequantize the int8 window, attend
+    over pos <= lens[b]), across dtype (f32 / bf16 / int8-cache),
+    ragged lens including idle slots, NaN garbage in the unwritten
+    tail, and the full-slot clamp; every row the call did not append —
+    other layers, the dead tail — comes back bit-identical (the cache
+    is aliased to the output and updated in place);
   * dispatch/engine-level — the gate chain (flag, shape, interpret
     caps), probe-failure capture (journal event + counter + fallback),
     the compile-once contract, prefix-hit suffix admission through the
-    fused path, and token parity against the windowed-einsum engine;
+    fused path, token parity against the windowed-einsum engine, and
+    the decode step's jaxpr: no restack of the cache on either path,
+    every kernel call aliased;
   * block-fusion level — the (y, z) pair primitive and the
     FLAGS_fused_block decoder-layer wiring vs the unfused model.
 """
@@ -73,49 +78,72 @@ def _oracle(q, kc, vc, lens, nk, nv, ks=None, vs=None):
     return out, kc, vc, (ks if quant else None), (vs if quant else None)
 
 
+LAYER = 1      # the layer the kernel tests call; its neighbours must not move
+
+
 def _mk(B=2, H=2, T=96, D=16, lens=(5, 40), dtype=jnp.float32,
-        quantized=False, nan_tail=True, seed=0):
-    """Inputs with the cache tail PAST lens left as NaN garbage — the
-    hostile shape the engine actually produces (unwritten pages are
-    uninitialized memory)."""
+        quantized=False, nan_tail=True, seed=0, L=2):
+    """Inputs over a stacked cache [L, B, H, T, D] with the tail PAST lens
+    left as NaN garbage in every layer — the hostile shape a cache can
+    hold (rows past a slot's length are whatever was there before)."""
     rs = np.random.RandomState(seed)
     lens = np.asarray(lens, np.int32)
     q = jnp.asarray(rs.randn(B, H, 1, D), dtype)
     nk = jnp.asarray(rs.randn(B, H, 1, D), dtype)
     nv = jnp.asarray(rs.randn(B, H, 1, D), dtype)
-    kf = rs.randn(B, H, T, D)
-    vf = rs.randn(B, H, T, D)
+    kf = rs.randn(L, B, H, T, D)
+    vf = rs.randn(L, B, H, T, D)
     if quantized:
         kc, ks = _quantize_np(kf)
         vc, vs = _quantize_np(vf)
         if nan_tail:     # scales past lens are garbage; payload is int8
             for b in range(B):
-                ks[b, :, lens[b]:] = np.nan
-                vs[b, :, lens[b]:] = np.nan
+                ks[:, b, :, lens[b]:] = np.nan
+                vs[:, b, :, lens[b]:] = np.nan
         return (q, jnp.asarray(kc), jnp.asarray(vc),
                 jnp.asarray(lens), nk, nv,
                 jnp.asarray(ks), jnp.asarray(vs))
     if nan_tail:
         for b in range(B):
-            kf[b, :, lens[b]:] = np.nan
-            vf[b, :, lens[b]:] = np.nan
+            kf[:, b, :, lens[b]:] = np.nan
+            vf[:, b, :, lens[b]:] = np.nan
     return (q, jnp.asarray(kf, dtype), jnp.asarray(vf, dtype),
             jnp.asarray(lens), nk, nv, None, None)
 
 
-def _run(args, T=96):
+def _layer_args(args, layer=LAYER):
+    """The oracle's view: the named layer cut out of the stacked args."""
+    q, kc, vc, lens, nk, nv, ks, vs = args
+    cut = lambda a: None if a is None else np.asarray(a)[layer]  # noqa: E731
+    return (q, cut(kc), cut(vc), lens, nk, nv, cut(ks), cut(vs))
+
+
+def _run(args, T=96, layer=LAYER):
     blk = pk._paged_block(T, interpret=True)
-    return pk._paged_decode(*args, block_k=blk, interpret=True)
+    return pk._paged_decode(*args, layer=layer, block_k=blk,
+                            interpret=True)
 
 
-def _check(args, atol, T=96):
-    out = _run(args, T=T)
-    ref = _oracle(*args)
+def _assert_rest_untouched(got, before, lens, layer=LAYER):
+    """Bit for bit (NaN payloads included): all of `got` equals `before`
+    but the appended rows (layer, b, :, lens[b]) — other layers, earlier
+    rows and the dead tail past each slot's length alike."""
+    got, before = np.array(got), np.array(before)
+    assert got.dtype == before.dtype and got.shape == before.shape
+    for b in range(lens.shape[0]):
+        got[layer, b, :, lens[b]] = 0
+        before[layer, b, :, lens[b]] = 0
+    assert got.tobytes() == before.tobytes()
+
+
+def _check(args, atol, T=96, layer=LAYER):
+    out = _run(args, T=T, layer=layer)
+    ref = _oracle(*_layer_args(args, layer))
     lens = np.asarray(args[3])
     np.testing.assert_allclose(np.asarray(out[0], np.float32), ref[0],
                                atol=atol, rtol=atol)
     for got, want, name in ((out[1], ref[1], "k"), (out[2], ref[2], "v")):
-        got, want = np.asarray(got), np.asarray(want)
+        got, want = np.asarray(got)[layer], np.asarray(want)
         for b in range(lens.shape[0]):     # live region incl. the append
             np.testing.assert_allclose(
                 got[b, :, :lens[b] + 1].astype(np.float32),
@@ -123,11 +151,14 @@ def _check(args, atol, T=96):
                 atol=atol, rtol=atol, err_msg=name)
     if args[6] is not None:
         for got, want in ((out[3], ref[3]), (out[4], ref[4])):
-            got, want = np.asarray(got), np.asarray(want)
+            got, want = np.asarray(got)[layer], np.asarray(want)
             for b in range(lens.shape[0]):
                 np.testing.assert_allclose(got[b, :, :lens[b] + 1],
                                            want[b, :, :lens[b] + 1],
                                            atol=2e-7, rtol=2e-5)
+    for got, before in zip(out[1:], (args[1], args[2], args[6], args[7])):
+        if before is not None:
+            _assert_rest_untouched(got, before, lens, layer)
 
 
 class TestPagedDecodeKernel:
@@ -158,7 +189,8 @@ class TestPagedDecodeKernel:
         # threaded kernel-to-kernel, vs the oracle at every step
         T, D = 96, 16
         args = list(_mk(B=1, H=2, T=T, D=D, lens=(30,)))
-        ref = [np.array(a) if a is not None else None for a in args]
+        ref = [np.array(a) if a is not None else None
+               for a in _layer_args(args)]
         rs = np.random.RandomState(9)
         for step in range(6):
             out = _run(tuple(args), T=T)
@@ -175,6 +207,29 @@ class TestPagedDecodeKernel:
             args[4], args[5] = jnp.asarray(nk, jnp.float32), \
                 jnp.asarray(nv, jnp.float32)
             ref[4], ref[5] = nk, nv
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    def test_layer_call_touches_only_its_appended_rows(self, quantized):
+        # L = 3, called for layer 1: layers 0 and 2 and every row of
+        # layer 1 other than the appended one come back bit-identical
+        # (scales too), and the appended rows are the new token
+        args = _mk(B=3, lens=(0, 31, 64), L=3, quantized=quantized)
+        out = _run(args, layer=1)
+        ref = _oracle(*_layer_args(args, 1))
+        lens = np.asarray(args[3])
+        pairs = [(out[1], args[1], ref[1]), (out[2], args[2], ref[2])]
+        if quantized:
+            pairs += [(out[3], args[6], ref[3]), (out[4], args[7], ref[4])]
+        for got, before, want in pairs:
+            _assert_rest_untouched(got, before, lens, layer=1)
+            for layer in (0, 2):
+                assert np.asarray(got)[layer].tobytes() == \
+                    np.asarray(before)[layer].tobytes()
+            for b in range(3):     # the oracle's scale may sit an ulp off
+                np.testing.assert_allclose(
+                    np.asarray(got)[1, b, :, lens[b]],
+                    np.asarray(want)[b, :, lens[b]], rtol=2e-5, atol=0)
 
     def test_paged_block_chooser(self):
         assert pk._paged_block(2048, interpret=True) == 128
@@ -200,7 +255,8 @@ class TestDispatchGate:
     def test_interpret_dispatch_fires(self, interp_on):
         q, kc, vc, lens, nk, nv, _, _ = _mk(nan_tail=False)
         before = pk.attention_path_counts()["paged_flash"]
-        out = pk.paged_decode_attention_or_none(q, kc, vc, lens, nk, nv)
+        out = pk.paged_decode_attention_or_none(q, kc, vc, lens, nk, nv,
+                                                layer=LAYER)
         assert out is not None
         assert pk.attention_path_counts()["paged_flash"] == before + 1
 
@@ -208,19 +264,19 @@ class TestDispatchGate:
         set_flags({"paged_flash_decode": False})
         q, kc, vc, lens, nk, nv, _, _ = _mk(nan_tail=False)
         assert pk.paged_decode_attention_or_none(
-            q, kc, vc, lens, nk, nv) is None
+            q, kc, vc, lens, nk, nv, layer=LAYER) is None
 
     def test_interpret_caps_reject_big_shapes(self, interp_on):
         q, kc, vc, lens, nk, nv, _, _ = _mk(B=16, H=8, T=64, D=16,
                                             lens=(1,) * 16,
                                             nan_tail=False)
         assert pk.paged_decode_attention_or_none(
-            q, kc, vc, lens, nk, nv) is None     # B*H = 128 > 64
+            q, kc, vc, lens, nk, nv, layer=LAYER) is None     # B*H = 128 > 64
 
     def test_odd_head_dim_rejected(self, interp_on):
         q, kc, vc, lens, nk, nv, _, _ = _mk(D=12, nan_tail=False)
         assert pk.paged_decode_attention_or_none(
-            q, kc, vc, lens, nk, nv) is None     # D % 8 != 0
+            q, kc, vc, lens, nk, nv, layer=LAYER) is None     # D % 8 != 0
 
 
 def _tiny(**kw):
@@ -312,6 +368,67 @@ class TestEngineFusedPath:
         set_flags({"paged_flash_decode": False})
         plain, _ = serve()
         assert [t for t, _ in fused] == [t for t, _ in plain]
+
+    @staticmethod
+    def _decode_eqns(kv_dtype):
+        """(every equation of `_decode_fn`'s jaxpr, sub-jaxprs included;
+        the shapes of the engine's stacked cache arrays)."""
+        import paddle_tpu as paddle
+        from paddle_tpu.framework.random import RNG
+        from paddle_tpu.inference.serving import GenerationEngine
+        paddle.seed(0)
+        eng = GenerationEngine(_tiny(), max_batch=2, max_seq_len=32,
+                               prefill_buckets=(8,), kv_dtype=kv_dtype)
+        closed = jax.make_jaxpr(eng._decode_fn)(
+            [p._data for p in eng._weights],
+            [b._data for b in eng._buffers], RNG.key, eng.kv.state(),
+            eng._last)
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for v in eqn.params.values():
+                    for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from walk(sub)
+
+        shapes = {tuple(a.shape) for a in eng.kv.state()[:-1]}
+        return list(walk(closed.jaxpr)), shapes
+
+    @staticmethod
+    def _assert_no_restack(eqns, shapes):
+        # jnp.stack of the per-layer results is a `concatenate` whose
+        # result has the stacked cache's shape
+        for eqn in eqns:
+            if eqn.primitive.name == "concatenate":
+                assert tuple(eqn.outvars[0].aval.shape) not in shapes, eqn
+
+    @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+    def test_decode_jaxpr_aliases_cache_and_never_restacks(
+            self, interp_on, kv_dtype):
+        eqns, shapes = self._decode_eqns(kv_dtype)
+        self._assert_no_restack(eqns, shapes)
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert len(calls) == 2                      # one a layer
+        # the kernel sees the scales as [L, B, H, 1, T]
+        shapes |= {s[:3] + (1, s[3]) for s in shapes if len(s) == 4}
+        for eqn in calls:
+            aliases = dict(eqn.params["input_output_aliases"])
+            cache_ops = [i for i, v in enumerate(eqn.invars)
+                         if tuple(v.aval.shape) in shapes]
+            assert len(cache_ops) == (4 if kv_dtype == "int8" else 2)
+            for i in cache_ops:
+                assert i in aliases, (i, aliases)
+                out = eqn.outvars[aliases[i]].aval
+                assert (out.shape, out.dtype) == (
+                    eqn.invars[i].aval.shape, eqn.invars[i].aval.dtype)
+
+    @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+    def test_einsum_fallback_jaxpr_never_restacks(self, kv_dtype):
+        eqns, shapes = self._decode_eqns(kv_dtype)
+        assert not [e for e in eqns if e.primitive.name == "pallas_call"]
+        self._assert_no_restack(eqns, shapes)
 
     def test_cpu_default_takes_einsum_fallback(self):
         # without FLAGS_paged_flash_interpret the CPU engine must land
