@@ -38,6 +38,14 @@ has run, the carrier's arrays are the cache state the jitted function
 returns: nothing is sliced out of the stacked cache and nothing is
 stacked back. The carrier is a plain python holder of traced arrays
 scoped to one trace — nothing escapes it.
+
+Two kinds of layer live side by side in one manager (`layer_kinds`): a
+"full" layer keeps every row up to `max_seq_len`; a "window" layer (sliding
+-window attention) keeps a ring of `window` rows, position p at row
+`p mod window`, so its bytes do not grow with the context. Each kind is
+one stack: full `k/v [L_f, B, H_kv, max_seq_len, hd]`, window `wk/wv
+[L_w, B, H_kv, window, hd]`; a model with no window layer has no window
+stack and its state is the three (or five) arrays it always was.
 """
 from __future__ import annotations
 
@@ -63,6 +71,15 @@ PREFIX_BYTES = metrics.gauge(
     "pt_prefix_cache_bytes",
     "Bytes of K/V (+scales) currently held by the prefix cache")
 
+KV_BYTES = metrics.gauge(
+    "pt_kv_bytes", "Bytes the paged KV cache reserves, by kind of layer",
+    labelnames=("kind",))
+KV_ROWS_LIVE = metrics.histogram(
+    "pt_kv_rows_live",
+    "Cache rows the live requests hold in one layer of a kind, one "
+    "observation a decode step", labelnames=("kind",),
+    buckets=metrics.exponential_buckets(64, 2, 16))
+
 # env knob: default byte budget for each engine's PrefixCache; 0 disables
 PREFIX_CACHE_BYTES_ENV = "PADDLE_TPU_PREFIX_CACHE_BYTES"
 _PREFIX_CACHE_DEFAULT = 256 << 20
@@ -75,17 +92,22 @@ class StackedKV:
     int32 [B], each slot's length BEFORE this step's token. For a
     quantized cache k/v are int8 and k_scale/v_scale carry the float32
     per-(layer, slot, head, token) scales [n_layers, B, n_heads,
-    max_seq_len] (None otherwise). Each layer's attention replaces the
-    arrays with its updated ones."""
+    max_seq_len] (None otherwise). wk/wv: the window layers' rings
+    [n_window_layers, B, n_heads, window, head_dim], None when the model
+    has none. Each layer's attention replaces the arrays with its
+    updated ones."""
 
-    __slots__ = ("k", "v", "lens", "k_scale", "v_scale")
+    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv")
 
-    def __init__(self, k, v, lens, k_scale=None, v_scale=None):
+    def __init__(self, k, v, lens, k_scale=None, v_scale=None, wk=None,
+                 wv=None):
         self.k = k
         self.v = v
         self.lens = lens
         self.k_scale = k_scale
         self.v_scale = v_scale
+        self.wk = wk
+        self.wv = wv
 
 
 class LayerCacheView:
@@ -102,14 +124,18 @@ class LayerCacheView:
     smallest window covering max(lens)+1 instead of attending (and,
     for int8, dequantizing) the full T_max buffer every step. None →
     full-depth attention (legacy callers). Shapes stay static either
-    way — the traced lens picks a branch, never a shape."""
+    way — the traced lens picks a branch, never a shape.
 
-    __slots__ = ("kv", "layer", "windows")
+    `kind`: "full" (rows of `kv.k/v`) or "window" (the ring `kv.wk/wv`);
+    `layer` counts within the stack of its kind."""
 
-    def __init__(self, kv, layer, windows=None):
+    __slots__ = ("kv", "layer", "windows", "kind")
+
+    def __init__(self, kv, layer, windows=None, kind="full"):
         self.kv = kv
         self.layer = int(layer)
         self.windows = windows
+        self.kind = kind
 
     @property
     def lens(self):
@@ -162,10 +188,16 @@ class PagedKVCache:
     `kv_dtype="int8"` stores k/v as int8 plus float32 `k_scale`/
     `v_scale` side-buffers of shape [n_layers, max_batch, n_heads,
     max_seq_len] — ~0.53x the bytes of bf16 at head_dim 64, which is
-    the whole point: more decode slots per HBM byte."""
+    the whole point: more decode slots per HBM byte.
+
+    `layer_kinds` ("full" | "window" a layer; default all full) with
+    `window` splits the layers into the two stacks of the module
+    docstring; `n_heads` is the number of key-value heads."""
 
     def __init__(self, n_layers: int, max_batch: int, n_heads: int,
-                 max_seq_len: int, head_dim: int, kv_dtype="float32"):
+                 max_seq_len: int, head_dim: int, kv_dtype="float32",
+                 layer_kinds: Optional[Sequence[str]] = None,
+                 window: Optional[int] = None):
         import jax.numpy as jnp
         self.n_layers = int(n_layers)
         self.max_batch = int(max_batch)
@@ -174,7 +206,19 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.kv_dtype = str(kv_dtype)
         self.quantized = self.kv_dtype == "int8"
-        shape = (self.n_layers, self.max_batch, self.n_heads,
+        self.layer_kinds = tuple(layer_kinds or ("full",) * self.n_layers)
+        if len(self.layer_kinds) != self.n_layers \
+                or set(self.layer_kinds) - {"full", "window"}:
+            raise ValueError("layer_kinds must name each of the %d layers "
+                             "\"full\" or \"window\"" % self.n_layers)
+        n_window = self.layer_kinds.count("window")
+        # a ring never needs more rows than a slot can hold
+        self.window = min(int(window or 0), self.max_seq_len)
+        if n_window and self.window < 1:
+            raise ValueError("window layers need a window")
+        if n_window and self.quantized:
+            raise ValueError("an int8 cache has no window layers yet")
+        shape = (self.n_layers - n_window, self.max_batch, self.n_heads,
                  self.max_seq_len, self.head_dim)
         store = jnp.int8 if self.quantized else self.kv_dtype
         self.k = jnp.zeros(shape, store)
@@ -185,25 +229,55 @@ class PagedKVCache:
             self.v_scale = jnp.zeros(shape[:-1], jnp.float32)
         else:
             self.k_scale = self.v_scale = None
+        if n_window:
+            ring = (n_window,) + shape[1:3] + (self.window, self.head_dim)
+            self.wk = jnp.zeros(ring, store)
+            self.wv = jnp.zeros(ring, store)
+        else:
+            self.wk = self.wv = None
+        for kind, n in self.nbytes_by_kind().items():
+            KV_BYTES.labels(kind).set(n)
+
+    def layer_index(self, layer: int) -> Tuple[str, int]:
+        """(kind, index within that kind's stack) of a model layer."""
+        kind = self.layer_kinds[layer]
+        return kind, self.layer_kinds[:layer].count(kind)
+
+    def nbytes_by_kind(self) -> dict:
+        full = int(self.k.nbytes) + int(self.v.nbytes)
+        if self.quantized:
+            full += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
+        ring = 0 if self.wk is None else \
+            int(self.wk.nbytes) + int(self.wv.nbytes)
+        return {"full": full, "window": ring}
 
     @property
     def nbytes(self) -> int:
-        n = int(self.k.nbytes) + int(self.v.nbytes) + int(self.lens.nbytes)
-        if self.quantized:
-            n += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
-        return n
+        return sum(self.nbytes_by_kind().values()) + int(self.lens.nbytes)
+
+    def observe_live_rows(self, lengths) -> None:
+        """One observation a kind of `pt_kv_rows_live`: the rows that
+        requests of these context lengths hold in one layer of it."""
+        KV_ROWS_LIVE.labels("full").observe(
+            float(sum(min(n, self.max_seq_len) for n in lengths)))
+        if self.wk is not None:
+            KV_ROWS_LIVE.labels("window").observe(
+                float(sum(min(n, self.window) for n in lengths)))
 
     def state(self) -> Tuple:
         """Flat state tuple the jitted steps thread (and donate).
 
         Float: (k, v, lens). Quantized: (k, v, k_scale, v_scale, lens)
-        — the scales MUST travel with the values they decode."""
+        — the scales MUST travel with the values they decode. With
+        window layers: (k, v, wk, wv, lens)."""
         if self.quantized:
             return self.k, self.v, self.k_scale, self.v_scale, self.lens
+        if self.wk is not None:
+            return self.k, self.v, self.wk, self.wv, self.lens
         return self.k, self.v, self.lens
 
     def set_state(self, *state) -> None:
-        want = 5 if self.quantized else 3
+        want = 5 if self.quantized or self.wk is not None else 3
         if len(state) == 1 and isinstance(state[0], (tuple, list)):
             state = tuple(state[0])
         if len(state) != want:
@@ -221,6 +295,8 @@ class PagedKVCache:
                     % (name, arr.dtype, self.kv_dtype, ref.dtype))
         if self.quantized:
             self.k, self.v, self.k_scale, self.v_scale, self.lens = state
+        elif self.wk is not None:
+            self.k, self.v, self.wk, self.wv, self.lens = state
         else:
             self.k, self.v, self.lens = state
 

@@ -41,6 +41,28 @@ per trace) in `prefill_compiles`/`suffix_prefill_compiles`/
 `decode_compiles` — the numbers the tests and the SERVING_SMOKE gate
 assert on, immune to the telemetry kill-switch.
 
+The engine reads no attribute of any particular model. It calls
+`model.serving()` and asks the answer for what it needs:
+
+  n_layers, kv_heads, head_dim     the cache's geometry
+  layer_kinds, window              "full" | "window" a layer (cache.py)
+  max_positions                    the longest context the model places
+  prefix_cache                     whether a stored prompt head can be
+                                   re-inserted (not into a ring)
+  selfchecks                       the Pallas self-checks its kernels need
+  moe_layers, moe_top_k, moe_experts
+                                   expert layers; a step of such a model
+                                   sends its routing statistics (int32
+                                   [2]) back WITH its tokens
+  prefill(ids, true_len[, prefix]) -> (logits [1, 1, V] at the last real
+                                   row, [k a layer], [v a layer], stats)
+  decode(last, views)              -> (logits [B, 1, V], stats), the
+                                   views' carrier left holding the
+                                   updated cache
+
+`models/gpt.py` and `models/decoder.py` each answer it; the executables
+of a GPT are what they were before the question was asked.
+
 Weights are functionalized exactly like jit/engine.py's eval step:
 parameter `_data` is swapped for traced inputs during the trace and
 restored in `finally`; at dispatch time weights pass as arguments, so
@@ -56,7 +78,6 @@ import numpy as np
 
 from ...framework import state
 from ...framework.random import RNG
-from ...framework.tensor import Tensor
 from ...observability import memprof, metrics, spans, tracing
 from . import cache as cache_mod
 
@@ -65,6 +86,21 @@ __all__ = ["GenerationEngine"]
 PREFILL_BUCKET_HITS = metrics.counter(
     "pt_serve_prefill_bucket_total",
     "Prefills served per prompt-length bucket", labelnames=("bucket",))
+
+MOE_TOUCHED = metrics.histogram(
+    "pt_moe_experts_touched",
+    "Experts that got at least one assignment, mean over the expert "
+    "layers; one observation a decode step and a prefill",
+    buckets=metrics.exponential_buckets(1, 2, 12))
+MOE_LOAD = metrics.histogram(
+    "pt_moe_load_max_over_mean",
+    "The fullest expert's assignments over the mean expert's, mean over "
+    "the expert layers; one observation a decode step and a prefill",
+    buckets=metrics.exponential_buckets(1, 1.5, 16))
+MOE_ASSIGNMENTS = metrics.counter(
+    "pt_moe_assignments_total",
+    "Token-expert assignments the expert layers computed (padding rows "
+    "and idle slots included: the device computes them)")
 
 # Trace-time weight swapping mutates shared Layer state (`p._data`); one
 # process-wide lock serializes dispatches so server workers sharing a
@@ -101,23 +137,25 @@ class GenerationEngine:
         from ...jit import compile_cache
         from ...ops.pallas_kernels import pallas_selfcheck
         compile_cache.configure()
-        # needs_paged: the decode step runs the paged-decode kernel
-        pallas_selfcheck(needs_prng=False, needs_paged=True)
-
-        gpt = getattr(model, "gpt", model)
-        if not hasattr(gpt, "layers") or not hasattr(gpt, "embeddings"):
+        if not callable(getattr(model, "serving", None)):
             raise TypeError(
-                "GenerationEngine expects a GPTForPretraining (or GPTModel);"
-                " got %r" % type(model).__name__)
+                "GenerationEngine expects a model that answers serving() "
+                "(GPTForPretraining, GPTModel, DecoderLM); got %r"
+                % type(model).__name__)
+        self._sv = sv = model.serving()
+        # the self-checks of the kernels THIS model's steps run
+        pallas_selfcheck(needs_prng=False,
+                         needs_paged="paged" in sv.selfchecks,
+                         needs=sv.selfchecks)
         model.eval()
         self.model = model
-        self._gpt = gpt
-        self._n_layers = len(gpt.layers)
-        attn = gpt.layers[0].attn
-        self._n_heads = attn.num_heads
-        self._head_dim = attn.head_dim
-        self._hidden = gpt.hidden_size
-        self._max_pos = gpt.embeddings.position_embeddings.weight.shape[0]
+        self._n_layers = sv.n_layers
+        self._n_heads = sv.kv_heads
+        self._head_dim = sv.head_dim
+        self._max_pos = sv.max_positions
+        self.span_attrs = {
+            "moe_layers": sv.moe_layers,
+            "window_layers": tuple(sv.layer_kinds).count("window")}
 
         buckets = sorted(set(int(b) for b in prefill_buckets))
         if not buckets or buckets[0] < 1:
@@ -144,7 +182,10 @@ class GenerationEngine:
 
         self.kv = cache_mod.PagedKVCache(
             self._n_layers, self.max_batch, self._n_heads,
-            self.max_seq_len, self._head_dim, kv_dtype=kv_dtype)
+            self.max_seq_len, self._head_dim, kv_dtype=kv_dtype,
+            layer_kinds=sv.layer_kinds, window=sv.window)
+        self._layer_index = [self.kv.layer_index(i)
+                             for i in range(self._n_layers)]
         self._last = jnp.zeros((self.max_batch, 1), jnp.int32)
         # static attend windows for the einsum decode fallback: the
         # prefill buckets + full depth, so short conversations pay for
@@ -152,7 +193,8 @@ class GenerationEngine:
         self._decode_windows = tuple(sorted(
             set(self.buckets) | {self.max_seq_len}))
 
-        budget = cache_mod.prefix_cache_budget(prefix_cache_bytes)
+        budget = cache_mod.prefix_cache_budget(prefix_cache_bytes) \
+            if sv.prefix_cache else 0
         self.prefix_cache = (cache_mod.PrefixCache(budget, self.buckets)
                              if budget > 0 else None)
         self.admit_info = {"prefix_len": 0, "bucket": 0}
@@ -172,33 +214,64 @@ class GenerationEngine:
 
     # -- cache-state plumbing ----------------------------------------------
 
-    def _split_cache(self, cache):
-        """(k, v, k_scale|None, v_scale|None, lens) from the flat state
-        tuple a jitted step received (see PagedKVCache.state)."""
+    def _carrier(self, cache):
+        """The flat state tuple a jitted step received (see
+        PagedKVCache.state) as one StackedKV."""
         if self.kv.quantized:
             kc, vc, ksc, vsc, lens = cache
-            return kc, vc, ksc, vsc, lens
+            return cache_mod.StackedKV(kc, vc, lens, ksc, vsc)
+        if self.kv.wk is not None:
+            kc, vc, wk, wv, lens = cache
+            return cache_mod.StackedKV(kc, vc, lens, wk=wk, wv=wv)
         kc, vc, lens = cache
-        return kc, vc, None, None, lens
+        return cache_mod.StackedKV(kc, vc, lens)
 
-    def _join_cache(self, kc, vc, ksc, vsc, lens):
+    def _state_of(self, kv, lens):
         if self.kv.quantized:
-            return kc, vc, ksc, vsc, lens
-        return kc, vc, lens
+            return kv.k, kv.v, kv.k_scale, kv.v_scale, lens
+        if self.kv.wk is not None:
+            return kv.k, kv.v, kv.wk, kv.wv, lens
+        return kv.k, kv.v, lens
+
+    def _split_kinds(self, ks, vs, tl):
+        """A layer's fresh K/V [1, H, Tb, hd] each -> (full layers
+        stacked [L_f, 1, H, Tb, hd] x 2, ring rows of the window layers
+        [L_w, 1, H, min(Tb, W), hd] x 2 or None). A prompt longer than
+        the window leaves its last W rows, position p at row p mod W."""
+        import jax.numpy as jnp
+        kinds = self.kv.layer_kinds
+        full = [i for i, k in enumerate(kinds) if k == "full"]
+        ring = [i for i, k in enumerate(kinds) if k == "window"]
+        fk = jnp.stack([ks[i] for i in full])
+        fv = jnp.stack([vs[i] for i in full])
+        if not ring:
+            return fk, fv, None
+        wk = jnp.stack([ks[i] for i in ring])
+        wv = jnp.stack([vs[i] for i in ring])
+        W, tb = self.kv.window, wk.shape[3]
+        if tb > W:
+            r = jnp.arange(W, dtype=jnp.int32)
+            src = jnp.clip(r + W * ((tl - 1 - r) // W), 0, tb - 1)
+            wk, wv = jnp.take(wk, src, axis=3), jnp.take(wv, src, axis=3)
+        return fk, fv, (wk, wv)
 
     def _insert_kv(self, cache, ks, vs, tl, slot, offset=0,
-                   prefix=None):
+                   prefix=None, ring=None):
         """Write freshly-computed float K/V [L,1,nh,T',hd] (quantizing
         first when the cache is int8) into `cache` at (slot, offset),
         optionally preceded by a VERBATIM stored prefix at offset 0,
-        and set the slot's length to `tl`. Runs inside a trace."""
+        and set the slot's length to `tl`. `ring`: the window layers'
+        rows (`_split_kinds`), written from row 0 of the slot's rings.
+        Runs inside a trace."""
         import jax
         import jax.numpy as jnp
         # the scope travels in the HLO's `op_name` metadata (HLO text,
         # xprof's op profile) whatever XLA fuses the insert into; the
         # fusions' instruction names do not change
         with jax.named_scope("insert_kv"):
-            kc, vc, ksc, vsc, lens = self._split_cache(cache)
+            kv = self._carrier(cache)
+            kc, vc, ksc, vsc, lens = kv.k, kv.v, kv.k_scale, kv.v_scale, \
+                kv.lens
             s, z = slot.astype(jnp.int32), jnp.int32(0)
             o = jnp.int32(offset)
             if self.kv.quantized:
@@ -223,7 +296,13 @@ class GenerationEngine:
                 vc, vs.astype(vc.dtype), (z, s, z, o, z))
             lens = jax.lax.dynamic_update_slice(
                 lens, jnp.reshape(tl, (1,)), (s,))
-            return self._join_cache(kc, vc, ksc, vsc, lens)
+            kv.k, kv.v, kv.k_scale, kv.v_scale = kc, vc, ksc, vsc
+            if ring is not None:
+                kv.wk = jax.lax.dynamic_update_slice(
+                    kv.wk, ring[0].astype(kv.wk.dtype), (z, s, z, z, z))
+                kv.wv = jax.lax.dynamic_update_slice(
+                    kv.wv, ring[1].astype(kv.wv.dtype), (z, s, z, z, z))
+            return self._state_of(kv, lens)
 
     # -- traced bodies ----------------------------------------------------
 
@@ -240,29 +319,16 @@ class GenerationEngine:
             for b, a in zip(self._buffers, buf_arrs):
                 b._data = a
             RNG.key = key
-            gpt = self._gpt
-            zero = [(Tensor(jnp.zeros((1, self._n_heads, 0, self._head_dim),
-                                      jnp.float32), _internal=True),) * 2
-                    for _ in range(self._n_layers)]
+            tl = true_len.astype(jnp.int32)
             with state.trace_guard(), state.no_grad_guard(), \
                     state.mesh_guard(None):
-                hidden, kvs = gpt(Tensor(ids, _internal=True), None, zero)
-                from ...models.gpt import _lm_logits
-                tl = true_len.astype(jnp.int32)
-                h_last = jax.lax.dynamic_slice(
-                    hidden._data,
-                    (jnp.int32(0), tl - 1, jnp.int32(0)),
-                    (1, 1, self._hidden))
-                logits = _lm_logits(
-                    Tensor(h_last, _internal=True),
-                    gpt.embeddings.word_embeddings.weight)
-            tok = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
-            ks = jnp.stack([c[0]._data for c in kvs])   # [L,1,nh,Tb,hd]
-            vs = jnp.stack([c[1]._data for c in kvs])
-            cache = self._insert_kv(cache, ks, vs, tl, slot)
+                logits, ks, vs, stats = self._sv.prefill(ids, tl)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            ks, vs, ring = self._split_kinds(ks, vs, tl)  # [L,1,nh,Tb,hd]
+            cache = self._insert_kv(cache, ks, vs, tl, slot, ring=ring)
             s, z = slot.astype(jnp.int32), jnp.int32(0)
             last = jax.lax.dynamic_update_slice(last, tok, (s, z))
-            return cache, last, tok, RNG.key
+            return (cache, last, tok, RNG.key) + self._packed(tok, stats)
         finally:
             for m, a in zip(self._mutable, saved):
                 m._data = a
@@ -290,7 +356,6 @@ class GenerationEngine:
             for b, a in zip(self._buffers, buf_arrs):
                 b._data = a
             RNG.key = key
-            gpt = self._gpt
             if self.kv.quantized:
                 pk, pv, pks, pvs = prefix
                 pkf = cache_mod.dequantize_kv(pk, pks)
@@ -298,32 +363,19 @@ class GenerationEngine:
             else:
                 pk, pv = prefix
                 pkf, pvf = pk, pv
-            legacy = [(Tensor(pkf[i], _internal=True),
-                       Tensor(pvf[i], _internal=True))
-                      for i in range(self._n_layers)]
-            sb = int(ids.shape[1])
-            pos = jnp.arange(sb, dtype=jnp.int32) + jnp.int32(p)
+            tl = true_len.astype(jnp.int32)
             with state.trace_guard(), state.no_grad_guard(), \
                     state.mesh_guard(None):
-                hidden, kvs = gpt(Tensor(ids, _internal=True),
-                                  Tensor(pos, _internal=True), legacy)
-                from ...models.gpt import _lm_logits
-                tl = true_len.astype(jnp.int32)
-                # hidden covers ONLY the suffix: its true last row sits
-                # at (total_len - prefix_len) - 1
-                h_last = jax.lax.dynamic_slice(
-                    hidden._data,
-                    (jnp.int32(0), tl - jnp.int32(p) - 1, jnp.int32(0)),
-                    (1, 1, self._hidden))
-                logits = _lm_logits(
-                    Tensor(h_last, _internal=True),
-                    gpt.embeddings.word_embeddings.weight)
-            tok = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
-            # kvs are prefix+suffix concats; keep only the fresh suffix —
+                # the model runs ONLY the suffix (its true last row sits
+                # at total_len - prefix_len - 1) over the stored prefix
+                logits, ks, vs, _ = self._sv.prefill(
+                    ids, tl - jnp.int32(p), prefix=(pkf, pvf))
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # ks/vs are prefix+suffix concats; keep only the fresh suffix —
             # the stored prefix is re-inserted untouched (for int8 that
             # means NO dequantize->requantize round trip on a hit)
-            ks = jnp.stack([c[0]._data[:, :, p:, :] for c in kvs])
-            vs = jnp.stack([c[1]._data[:, :, p:, :] for c in kvs])
+            ks = jnp.stack([k[:, :, p:, :] for k in ks])
+            vs = jnp.stack([v[:, :, p:, :] for v in vs])
             cache = self._insert_kv(cache, ks, vs, tl, slot,
                                     offset=p, prefix=prefix)
             s, z = slot.astype(jnp.int32), jnp.int32(0)
@@ -345,33 +397,40 @@ class GenerationEngine:
             for b, a in zip(self._buffers, buf_arrs):
                 b._data = a
             RNG.key = key
-            gpt = self._gpt
-            kc, vc, ksc, vsc, lens = self._split_cache(cache)
             # one carrier of the stacked arrays; each layer's attention
             # appends its row in place and leaves the updated arrays here
-            kv = cache_mod.StackedKV(kc, vc, lens, ksc, vsc)
+            kv = self._carrier(cache)
             views = [cache_mod.LayerCacheView(
-                        kv, i, windows=self._decode_windows)
-                     for i in range(self._n_layers)]
-            # new token's absolute position == tokens already resident;
-            # clamped so idle slots that hit the wall index a real row
-            pos = jnp.minimum(lens, self._max_pos - 1)[:, None]
+                        kv, i, windows=self._decode_windows, kind=kind)
+                     for kind, i in self._layer_index]
             with state.trace_guard(), state.no_grad_guard(), \
                     state.mesh_guard(None):
-                hidden, _ = gpt(Tensor(last, _internal=True),
-                                Tensor(pos.astype(jnp.int32),
-                                       _internal=True), views)
-                from ...models.gpt import _lm_logits
-                logits = _lm_logits(
-                    hidden, gpt.embeddings.word_embeddings.weight)
-            tok = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
-            lens = jnp.minimum(lens + 1, jnp.int32(self.max_seq_len))
-            return (self._join_cache(kv.k, kv.v, kv.k_scale, kv.v_scale,
-                                     lens), tok, RNG.key)
+                logits, stats = self._sv.decode(last, views)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            lens = jnp.minimum(kv.lens + 1, jnp.int32(self.max_seq_len))
+            return (self._state_of(kv, lens), tok, RNG.key) \
+                + self._packed(tok, stats)
         finally:
             for m, a in zip(self._mutable, saved):
                 m._data = a
             RNG.key = saved_key
+
+    @staticmethod
+    def _packed(tok, stats):
+        """A step with routing statistics sends them back in the one
+        array its tokens come back in: int32 [n_tokens + 2]."""
+        import jax.numpy as jnp
+        if stats is None:
+            return ()
+        return (jnp.concatenate([tok.reshape(-1), stats]),)
+
+    def _observe_moe(self, stats, n_rows):
+        sv = self._sv
+        layers = float(sv.moe_layers)
+        MOE_TOUCHED.observe(stats[0] / layers)
+        MOE_LOAD.observe((stats[1] / layers)
+                         / (n_rows * sv.moe_top_k / float(sv.moe_experts)))
+        MOE_ASSIGNMENTS.inc(n_rows * sv.moe_top_k * layers)
 
     # -- host API ---------------------------------------------------------
 
@@ -420,7 +479,7 @@ class GenerationEngine:
         with _DISPATCH_LOCK:
             try:
                 with self._prefill_tel.step(("prefill", b)):
-                    kvstate, last, tok, key = self._jit_prefill(
+                    kvstate, last, tok, key, *packed = self._jit_prefill(
                         [p._data for p in self._weights],
                         [bf._data for bf in self._buffers], RNG.key,
                         self.kv.state(), self._last,
@@ -436,6 +495,10 @@ class GenerationEngine:
             if self.prefix_cache is not None:
                 self._store_prefix(prompt, n, slot)
         self.admit_info = {"prefix_len": 0, "bucket": b}
+        if packed:
+            out = self._fetch(packed[0])
+            self._observe_moe(out[1:], b)
+            return int(out[0])
         return int(self._fetch(tok)[0, 0])
 
     def _suffix_prefill(self, slot, prompt, n, p, entry, sb) -> int:
@@ -486,7 +549,7 @@ class GenerationEngine:
         with _DISPATCH_LOCK:
             try:
                 with self._decode_tel.step("decode"):
-                    kvstate, tok, key = self._jit_decode(
+                    kvstate, tok, key, *packed = self._jit_decode(
                         [p._data for p in self._weights],
                         [bf._data for bf in self._buffers], RNG.key,
                         self.kv.state(), self._last)
@@ -498,6 +561,10 @@ class GenerationEngine:
             RNG.key = key
             self.kv.set_state(kvstate)
             self._last = tok
+        if packed:
+            out = self._fetch(packed[0])
+            self._observe_moe(out[self.max_batch:], self.max_batch)
+            return out[:self.max_batch]
         return self._fetch(tok).reshape(-1)
 
     # -- the host's side of the gap between two programs -------------------

@@ -112,6 +112,12 @@ class ContinuousBatcher:
         self.slots: List[Optional[Request]] = [None] * engine.max_batch
         self.steps = 0
         self.live_slot_steps = 0
+        # what the engine's model adds to the prefill / decode_step spans
+        # (expert and window layers); the live rows are counted a step only
+        # where they differ by kind, for a cache that has window layers
+        self._span_attrs = dict(getattr(engine, "span_attrs", None) or {})
+        self._kv = engine.kv if self._span_attrs.get("window_layers") \
+            else None
 
     # -- introspection ----------------------------------------------------
 
@@ -232,7 +238,8 @@ class ContinuousBatcher:
             t_pre = self._clock()
             # `step`: the decode step this admission runs ahead of
             with spans.span("prefill", parent="serve_request", t0=t_pre,
-                            rid=req.rid, step=self.steps + 1) as sp:
+                            rid=req.rid, step=self.steps + 1,
+                            **self._span_attrs) as sp:
                 tok = self.engine.prefill(slot, req.prompt)
                 now = self._clock()
                 # what THIS admission actually dispatched: on a prefix
@@ -284,13 +291,18 @@ class ContinuousBatcher:
         live = self.active
         if live:
             n = self.steps + 1
-            with spans.span("decode_step", t0=self._clock(), step=n) as sp:
+            with spans.span("decode_step", t0=self._clock(), step=n,
+                            **self._span_attrs) as sp:
                 toks = self.engine.decode()
                 now = self._clock()     # the step's tokens are fetched
                 sp.close(now)
             self.steps = n
             self.live_slot_steps += live
             with spans.span("harvest", t0=now, step=n) as sp:
+                if self._kv is not None:
+                    self._kv.observe_live_rows(
+                        [len(r.prompt) + len(r.tokens) + 1
+                         for r in self.slots if r is not None])
                 for slot, req in enumerate(self.slots):
                     if req is None:
                         continue

@@ -406,6 +406,9 @@ class GPTModel(Layer):
             return Tensor(np.zeros((), np.float32), _internal=True)
         return total
 
+    def serving(self):
+        return _GPTServing(self)
+
     def forward(self, input_ids, position_ids=None, caches=None):
         x = self.embeddings(input_ids, position_ids)
         if caches is None:
@@ -432,10 +435,75 @@ def _lm_logits(hidden, word_embedding_weight):
     return constrain(logits, P(P.UNCONSTRAINED, "sep", "mp"))
 
 
+class _GPTServing:
+    """What `inference/serving/engine.GenerationEngine` asks of a model,
+    answered for GPT: every layer keeps all its rows, one key-value head
+    a query head, learned positions, the head tied to the embedding."""
+
+    prefix_cache = True
+    selfchecks = ("paged",)
+    window = 0
+    moe_layers = moe_top_k = moe_experts = 0
+
+    def __init__(self, gpt):
+        self._gpt = gpt
+        self.n_layers = len(gpt.layers)
+        attn = gpt.layers[0].attn
+        self.kv_heads, self.head_dim = attn.num_heads, attn.head_dim
+        self.layer_kinds = ("full",) * self.n_layers
+        self.max_positions = \
+            gpt.embeddings.position_embeddings.weight.shape[0]
+
+    def _head(self, hidden):
+        return _lm_logits(
+            hidden, self._gpt.embeddings.word_embeddings.weight)._data
+
+    def prefill(self, ids, true_len, prefix=None):
+        """ids [1, Tb] -> (logits [1, 1, V] of row true_len - 1, k and v
+        [1, nh, T, hd] a layer, None). With `prefix` (k, v stacked
+        [L, 1, nh, p, hd]) ids are the suffix behind it and the returned
+        k/v are prefix + suffix."""
+        import jax
+        import jax.numpy as jnp
+        gpt = self._gpt
+        if prefix is None:
+            pos = None
+            legacy = [(Tensor(jnp.zeros((1, self.kv_heads, 0, self.head_dim),
+                                        jnp.float32), _internal=True),) * 2
+                      for _ in range(self.n_layers)]
+        else:
+            pk, pv = prefix
+            legacy = [(Tensor(pk[i], _internal=True),
+                       Tensor(pv[i], _internal=True))
+                      for i in range(self.n_layers)]
+            pos = Tensor(jnp.arange(int(ids.shape[1]), dtype=jnp.int32)
+                         + jnp.int32(int(pk.shape[3])), _internal=True)
+        hidden, kvs = gpt(Tensor(ids, _internal=True), pos, legacy)
+        h_last = jax.lax.dynamic_slice(
+            hidden._data, (jnp.int32(0), true_len - 1, jnp.int32(0)),
+            (1, 1, gpt.hidden_size))
+        logits = self._head(Tensor(h_last, _internal=True))
+        return logits, [c[0]._data for c in kvs], \
+            [c[1]._data for c in kvs], None
+
+    def decode(self, last, views):
+        import jax.numpy as jnp
+        # new token's absolute position == tokens already resident;
+        # clamped so idle slots that hit the wall index a real row
+        pos = jnp.minimum(views[0].lens, self.max_positions - 1)[:, None]
+        hidden, _ = self._gpt(Tensor(last, _internal=True),
+                              Tensor(pos.astype(jnp.int32), _internal=True),
+                              views)
+        return self._head(hidden), None
+
+
 class GPTForPretraining(Layer):
     def __init__(self, gpt: GPTModel):
         super().__init__()
         self.gpt = gpt
+
+    def serving(self):
+        return _GPTServing(self.gpt)
 
     def forward(self, input_ids, position_ids=None):
         hidden = self.gpt(input_ids, position_ids)

@@ -124,14 +124,17 @@ def _check_flash_dropout():
                             bool(np.isfinite(grad).all()), mean))
 
 
-def pallas_selfcheck(needs_prng=True, needs_paged=False):
+def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
     """Run the TPU self-checks this entry point's kernels need, once per
     process; no-op off TPU (interpret mode never touches Mosaic).
 
     needs_prng=False (inference entry points) skips the dropout variant —
     eval traces never use it. needs_paged=True (the serving engine) adds
-    the paged-decode kernel. Raises whatever the compiler raises, or
-    PallasSelfCheckError on a value mismatch."""
+    the paged-decode kernel; `needs` names further kernels a served model
+    runs ("paged_gqa", "band_flash": the grouped-query decode and the
+    band prefill of models/decoder.py), checked only for such a model.
+    Raises whatever the compiler raises, or PallasSelfCheckError on a
+    value mismatch."""
     if jax.default_backend() != "tpu":
         return
     checks = []
@@ -139,8 +142,12 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False):
         checks.append(("flash", _check_flash))
         if needs_prng:
             checks.append(("flash_dropout", _check_flash_dropout))
+        if "band_flash" in needs:
+            checks.append(("band_flash", _check_band_flash))
     if needs_paged and flag("paged_flash_decode"):
         checks.append(("paged", _check_paged))
+    if "paged_gqa" in needs and flag("paged_flash_decode"):
+        checks.append(("paged_gqa", _check_paged_gqa))
     for name, check in checks:
         if name not in _SELFCHECKED:
             check()
@@ -1845,3 +1852,370 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
     return _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
                          v_scale, layer=layer, block_k=blk,
                          interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query kernels of models/decoder.py
+#
+# `paged_gqa_decode`: one new token a slot against the paged cache, G query
+# heads sharing each key-value head. One kernel serves both kinds of layer:
+# the caller gives, a slot, the row the new K/V is written to and the number
+# of live rows — (min(lens, T-1), min(lens+1, T)) for a full layer,
+# (lens mod W, min(lens+1, W)) for a window layer's ring, whose rows are in
+# no order and need none under a softmax. The stacked cache is aliased to
+# the output (PR 27's property) and only the 16-row group round the new row
+# is written back. Grid (B, H_kv, rows/block); blocks past the live rows are
+# clamped by the index map and skipped.
+#
+# `prefill_band_flash`: causal flash attention forward for a whole prompt,
+# the G query heads of a key-value head folded into the rows of one block,
+# with a grid axis over K/V blocks that visits only the blocks inside the
+# causal band and (window layers) the sliding window: neither K nor V is
+# ever whole in VMEM, so a 14k-token prompt fits.
+# ---------------------------------------------------------------------------
+
+_APPEND_ROWS = 16     # a bf16 tile's sublanes: the group written back
+
+
+def _gqa_block(rows, interpret):
+    """Key rows a grid step of the grouped-query decode kernel reads."""
+    if interpret:      # the emulator has no tiling: cross blocks early
+        for b in (16, 32):
+            if rows % b == 0 and rows // b >= 2:
+                return b
+        return rows if rows % _APPEND_ROWS == 0 else None
+    for b in (1024, 512, 256, 128):
+        if rows % b == 0:
+            return b
+    return None
+
+
+def _paged_gqa_kernel(row_ref, live_ref, layer_ref, q_ref, nk_ref, nv_ref,
+                      k_ref, v_ref, o_ref, ko_ref, vo_ref, acc_ref, m_ref,
+                      l_ref, *, block_k, sm_scale):
+    del layer_ref                    # read by the index maps only
+    b, j = pl.program_id(0), pl.program_id(2)
+    ar, nl = row_ref[b], live_ref[b]         # append row; live rows
+    jm, ja = (nl - 1) // block_k, ar // block_k
+    d = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j == ja)
+    def _append():
+        r0 = pl.multiple_of(
+            ((ar - j * block_k) // _APPEND_ROWS) * _APPEND_ROWS,
+            _APPEND_ROWS)
+        sel = jax.lax.broadcasted_iota(
+            jnp.int32, (_APPEND_ROWS, d), 0) == (ar - j * block_k - r0)
+        for new_ref, old_ref, out_ref in ((nk_ref, k_ref, ko_ref),
+                                          (nv_ref, v_ref, vo_ref)):
+            new = jax.lax.broadcast_in_dim(
+                new_ref[...].astype(out_ref.dtype), sel.shape, (0, 1))
+            out_ref[...] = jnp.where(
+                sel, new, old_ref[pl.ds(r0, _APPEND_ROWS), :])
+
+    @pl.when(j <= jm)
+    def _step():
+        pos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)                       # [1, bk]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_k, d), 0)
+        new_row = rows == (ar - j * block_k)     # nowhere unless j == ja
+        k = jnp.where(new_row, jax.lax.broadcast_in_dim(
+            nk_ref[...].astype(k_ref.dtype), new_row.shape, (0, 1)),
+            k_ref[...])
+        v = jnp.where(new_row, jax.lax.broadcast_in_dim(
+            nv_ref[...].astype(v_ref.dtype), new_row.shape, (0, 1)),
+            v_ref[...])
+        # rows past the live ones were written by no tenant of this slot:
+        # a NaN there would get through 0 * NaN, so select them to zero
+        v = jnp.where(rows + j * block_k < nl, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale    # [G, bk]
+        s = jnp.where(pos < nl, s, _NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=1, keepdims=True)   # [G, 1]
+        l_prev = jnp.max(l_ref[...], axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(pos < nl, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jax.lax.broadcast_in_dim(m_new, m_ref.shape, (0, 1))
+        l_ref[...] = jax.lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        ell = jnp.max(l_ref[...], axis=1, keepdims=True)
+        # l > 0 always: the appended row is live
+        o_ref[...] = (acc_ref[...] / ell).astype(o_ref.dtype)
+
+
+def _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
+                      layer, block_k, interpret):
+    """q [B, H, G, D]; new_k/new_v [B, H, 1, D]; caches [L, B, H, R, D];
+    row/live int32 [B]. Returns (out [B, H, G, D], k_cache', v_cache'),
+    the caches being the operands updated in place."""
+    B, H, G, D = q.shape
+    R = k_cache.shape[3]
+
+    def _last(b, live):
+        return (live[b] - 1) // block_k
+
+    def kv_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, jnp.minimum(j, _last(b, live)), _I0)
+
+    def kv_out_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, row[b] // _APPEND_ROWS, _I0)
+
+    def tok_map(b, h, j, row, live, layer):
+        return (b, h, _I0, _I0)
+
+    kv_spec = pl.BlockSpec((None, None, None, block_k, D), kv_map)
+    out_kv_spec = pl.BlockSpec((None, None, None, _APPEND_ROWS, D),
+                               kv_out_map)
+    q_spec = pl.BlockSpec((None, None, G, D), tok_map)
+    new_spec = pl.BlockSpec((None, None, 1, D), tok_map)
+    n_prefetch = 3                     # row, live, layer
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B, H, R // block_k),
+        in_specs=[q_spec, new_spec, new_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, out_kv_spec, out_kv_spec],
+        scratch_shapes=[pltpu.VMEM((G, D), jnp.float32),
+                        pltpu.VMEM((G, _LANES), jnp.float32),
+                        pltpu.VMEM((G, _LANES), jnp.float32)])
+    kern = functools.partial(_paged_gqa_kernel, block_k=block_k,
+                             sm_scale=float(D) ** -0.5)
+    # cache operands (after the prefetch and the three token operands)
+    # -> outputs 1 and 2
+    aliases = {n_prefetch + 3: 1, n_prefetch + 4: 2}
+    out, ko, vo = _pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        input_output_aliases=aliases, interpret=interpret,
+        name="paged_gqa_decode")(
+        row.astype(jnp.int32), live.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), q, new_k, new_v,
+        k_cache, v_cache)
+    return out, ko, vo
+
+
+def paged_gqa_decode_or_none(q, k_cache, v_cache, row, live, new_k, new_v,
+                             *, layer):
+    """Gate + dispatch of the grouped-query paged decode kernel; None when
+    the caller must take its einsum (flag off, ineligible shape, or the
+    emulator without FLAGS_paged_flash_interpret)."""
+    if not flag("paged_flash_decode") or q.ndim != 4 or k_cache.ndim != 5:
+        return None
+    B, H, G, D = q.shape
+    interpret = jax.default_backend() != "tpu"
+    blk = _gqa_block(k_cache.shape[3], interpret)
+    if blk is None or k_cache.dtype != q.dtype:
+        return None
+    if interpret:
+        if not flag("paged_flash_interpret") or B * H > 64 or D > 128:
+            return None
+    elif D % 128 != 0:
+        return None
+    _note_attn_path("paged_gqa")
+    return _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v,
+                             layer=layer, block_k=blk, interpret=interpret)
+
+
+def _gqa_oracle(q, k, v, ok):
+    """softmax(q.k^T / sqrt(D), masked by ok [.., Tq, Tk]) . v with q
+    [B, H, G, Tq, D] and k, v [B, H, Tk, D], in float32."""
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * (float(q.shape[-1]) ** -0.5)
+    p = jax.nn.softmax(jnp.where(ok, s, _NEG_INF), axis=-1)
+    return jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
+
+
+def _check_paged_gqa():
+    """The grouped-query decode kernel on a full layer (ragged lengths,
+    one slot at the wall) and on a ring that has wrapped, against the
+    einsum; every row the call did not append must come back unchanged."""
+    L, B, H, G, D, R = 2, 3, 2, 8, 128, 2048
+    rs = np.random.RandomState(0)
+    arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
+    q, nk, nv = arr(B, H, G, D), arr(B, H, 1, D), arr(B, H, 1, D)
+    k, v = arr(L, B, H, R, D), arr(L, B, H, R, D)
+    lens = jnp.asarray([0, 1300, 5000], jnp.int32)
+    run = jax.jit(functools.partial(
+        _paged_gqa_decode, layer=1, block_k=_gqa_block(R, False),
+        interpret=False))
+    for ring in (False, True):
+        if ring:
+            row, live = lens % R, jnp.minimum(lens + 1, R)
+        else:
+            row = jnp.minimum(lens, R - 1)
+            live = jnp.minimum(lens + 1, R)
+        out, ko, vo = run(q, k, v, row, live, nk, nv)
+        slots = jnp.arange(B)
+        kb = k.at[1, slots, :, row].set(nk[:, :, 0])
+        vb = v.at[1, slots, :, row].set(nv[:, :, 0])
+        ok = (jnp.arange(R)[None, :] < live[:, None])[:, None, None, None]
+        want = _gqa_oracle(q[:, :, :, None], kb[1], vb[1], ok)[:, :, :, 0]
+        if not (np.allclose(np.asarray(out, np.float32), np.asarray(want),
+                            rtol=2e-2, atol=2e-2)
+                and np.array_equal(np.asarray(ko), np.asarray(kb))
+                and np.array_equal(np.asarray(vo), np.asarray(vb))):
+            raise PallasSelfCheckError(
+                "grouped-query paged decode (ring=%s) disagrees with the "
+                "einsum on %s: max|out-want|=%.3e" % (
+                    ring, jax.devices()[0].device_kind,
+                    _max_err(out, want)))
+
+
+def _band_blocks(T, interpret):
+    """(query rows, key rows) of a block of the band kernel, or None."""
+    if interpret:
+        return (8, 8) if T % 8 == 0 and T <= 64 else None
+    return (128, 512) if T % 512 == 0 else None
+
+
+def _band_range(i, block_q, block_k, window):
+    """First and last K/V block that query block i attends."""
+    lo = jnp.maximum(i * block_q - (window - 1), 0) // block_k \
+        if window else jnp.int32(0) * i
+    return lo, ((i + 1) * block_q - 1) // block_k
+
+
+def _band_flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       block_q, block_k, window, sm_scale):
+    i, j = pl.program_id(1), pl.program_id(2)
+    lo, hi = _band_range(i, block_q, block_k, window)
+    kb = lo + j
+    G, _, d = q_ref.shape
+    rows = G * block_q
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(kb <= hi)
+    def _step():
+        q = q_ref[...].reshape(rows, d)
+        s = jax.lax.dot_general(
+            q, k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [rows, bk]
+        # the G heads lie one after another along the rows
+        q_pos = i * block_q + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
+            jnp.int32(block_q))
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
+        ok = k_pos <= q_pos
+        if window:
+            ok = ok & (q_pos - k_pos < window)
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=1, keepdims=True)
+        l_prev = jnp.max(l_ref[...], axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row may have no key in this block (the window starts later):
+        # exp(-inf - -inf) must not count
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jax.lax.broadcast_in_dim(m_new, m_ref.shape, (0, 1))
+        l_ref[...] = jax.lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        ell = jnp.max(l_ref[...], axis=1, keepdims=True)
+        o_ref[...] = (acc_ref[...] / ell).reshape(G, block_q, d).astype(
+            o_ref.dtype)
+
+
+def _band_flash(q, k, v, window, block_q, block_k, interpret):
+    """q [B, Hq, T, D], k/v [B, Hkv, T, D] -> out [B, Hq, T, D]: causal,
+    keys within `window` of the query (0 = all), grouped heads."""
+    B, Hq, T, D = q.shape
+    Hkv = k.shape[1]
+    G = Hq // Hkv
+    n_k = T // block_k
+    if window:          # the most blocks any query block's band spans
+        n_k = min(n_k, (block_q + window - 2) // block_k + 2)
+
+    def q_map(h, i, j):
+        return (h, _I0, i, _I0)
+
+    def kv_map(h, i, j):
+        lo, hi = _band_range(i, block_q, block_k, window)
+        return (h, jnp.minimum(lo + j, hi), _I0)
+
+    kern = functools.partial(_band_flash_kernel, block_q=block_q,
+                             block_k=block_k, window=window,
+                             sm_scale=float(D) ** -0.5)
+    q_spec = pl.BlockSpec((None, G, block_q, D), q_map)
+    kv_spec = pl.BlockSpec((None, block_k, D), kv_map)
+    rows = G * block_q
+    out = _pallas_call(
+        kern, grid=(B * Hkv, T // block_q, n_k),
+        in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                        pltpu.VMEM((rows, _LANES), jnp.float32),
+                        pltpu.VMEM((rows, _LANES), jnp.float32)],
+        interpret=interpret, name="prefill_band_flash")(
+        q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, T, D),
+        v.reshape(B * Hkv, T, D))
+    return out.reshape(B, Hq, T, D)
+
+
+def band_flash_attention_or_none(q, k, v, window):
+    """Gate + dispatch of the band prefill kernel; None when the caller
+    must take its masked einsum (flag off or ineligible shape; off the
+    TPU the emulator takes the small shapes of `_band_blocks` alone, as
+    the flash forward's `_shapes_ok` does)."""
+    if not flag("use_flash_attention") or q.ndim != 4:
+        return None
+    T, D = q.shape[2], q.shape[3]
+    interpret = jax.default_backend() != "tpu"
+    blocks = _band_blocks(T, interpret)
+    if blocks is None or k.shape[2] != T or q.shape[1] % k.shape[1]:
+        return None
+    if not interpret and D % 128 != 0:
+        return None
+    _note_attn_path("band_flash")
+    return _band_flash(q, k, v, int(window or 0), *blocks,
+                       interpret=interpret)
+
+
+def _check_band_flash():
+    """The band kernel, windowed and not, at two key-value heads of four
+    query heads each over 1024 positions, against the masked einsum."""
+    B, Hq, Hkv, T, D, W = 1, 8, 2, 1024, 128, 256
+    rs = np.random.RandomState(0)
+    arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
+    q, k, v = arr(B, Hq, T, D), arr(B, Hkv, T, D), arr(B, Hkv, T, D)
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    run = jax.jit(functools.partial(
+        _band_flash, block_q=128, block_k=512, interpret=False),
+        static_argnames=("window",))
+    for window in (0, W):
+        got = run(q, k, v, window=window)
+        ok = (j <= i) & ((i - j < window) if window else True)
+        want = _gqa_oracle(q.reshape(B, Hkv, Hq // Hkv, T, D), k, v,
+                           ok).reshape(B, Hq, T, D)
+        if not np.allclose(np.asarray(got, np.float32), np.asarray(want),
+                           rtol=2e-2, atol=2e-2):
+            raise PallasSelfCheckError(
+                "band flash attention (window=%d) disagrees with the "
+                "einsum on %s: max|out-want|=%.3e" % (
+                    window, jax.devices()[0].device_kind,
+                    _max_err(got, want)))

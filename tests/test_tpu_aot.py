@@ -66,3 +66,66 @@ def test_paged_decode_chain_updates_the_cache_in_place(one_chip, kv_dtype):
     assert ma.alias_size_in_bytes >= cache_bytes + scale_bytes
     # a copy XLA had to insert would be a layer of K or V at the least
     assert ma.temp_size_in_bytes < cache_bytes // (2 * L) // 2
+
+
+# -- the decoder family's decode executable (models/decoder.py) -------------
+
+#: Trinity-Mini's widths, cut to one window layer and one full layer
+WIDE = {
+    "hidden_size": 2048, "num_hidden_layers": 2, "num_dense_layers": 1,
+    "layer_types": ["sliding_attention", "full_attention"],
+    "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+    "sliding_window": 2048, "rope_theta": 10000, "rms_norm_eps": 1e-05,
+    "intermediate_size": 6144, "num_experts": 128, "num_experts_per_tok": 8,
+    "moe_intermediate_size": 1024, "num_shared_experts": 1,
+    "route_norm": True, "route_scale": 2.826, "mup_enabled": True,
+    "vocab_size": 200192, "max_position_embeddings": 131072}
+SLOTS, DEPTH = 48, 4096
+
+
+def test_decoder_decode_step_updates_both_cache_stacks_in_place(
+        one_chip, monkeypatch):
+    """`GenerationEngine._decode_fn` of a dense-window layer and an
+    expert-full layer at the published widths, compiled for the v5e: the
+    two grouped-query kernel calls and the grouped expert products are
+    there, every byte of both cache stacks is aliased through, the
+    program's temporaries are a few megabytes, and no XLA op reads or
+    writes an array of a cache stack's size (PR 27's property, kept for
+    two stacks)."""
+    import re
+    from paddle_tpu.framework.random import RNG
+    from paddle_tpu.inference.serving.engine import GenerationEngine
+    from paddle_tpu.models.decoder import DecoderConfig, DecoderLM
+    net = DecoderLM(DecoderConfig.from_hf(WIDE), "bfloat16", abstract=True)
+    eng = GenerationEngine(net, max_batch=SLOTS, max_seq_len=DEPTH,
+                           prefill_buckets=(1024,), kv_dtype="bfloat16")
+    # the gates ask the backend; the compile below is for the chip
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    compiled = eng._jit_decode.lower(
+        [sds(p._data) for p in eng._weights],
+        [sds(b._data) for b in eng._buffers], sds(RNG.key),
+        tuple(sds(a) for a in eng.kv.state()), sds(eng._last)).compile()
+    text = compiled.as_text()
+    assert text.count('name="paged_gqa_decode"') >= 1 or \
+        "paged_gqa_decode" in text
+    assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 3
+    ma = compiled.memory_analysis()
+    by_kind = eng.kv.nbytes_by_kind()
+    assert by_kind == {"full": 2 * SLOTS * 4 * DEPTH * 128 * 2,
+                       "window": 2 * SLOTS * 4 * 2048 * 128 * 2}
+    assert ma.alias_size_in_bytes >= sum(by_kind.values())
+    assert ma.temp_size_in_bytes < 32 << 20
+    # every instruction whose result has a cache stack's shape is one of
+    # the two kernels (or the parameter / tuple plumbing round them)
+    stacks = ("bf16[1,%d,4,%d,128]" % (SLOTS, DEPTH),
+              "bf16[1,%d,4,2048,128]" % SLOTS)
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) "
+                     r"([\w\-]+)\(", line)
+        if m and any(s in m.group(2) for s in stacks):
+            assert m.group(3) in ("custom-call", "parameter", "tuple",
+                                  "get-tuple-element", "bitcast"), line
