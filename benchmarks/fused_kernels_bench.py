@@ -162,11 +162,11 @@ def bench_paged_decode(B=8, H=12, T=2048, D=64, live=256, quantized=True,
     [L, B, H, T, D] cache (updated in place, length pinned at `live`)
     against the same steps as write + dequant + masked einsum over all T
     positions of one layer. The speedup is the HBM-traffic ratio the
-    clamped BlockSpec buys (reads scale with `live`, not T)."""
+    kernel's work list buys (steps and reads scale with `live`, not T)."""
     from paddle_tpu.ops import pallas_kernels as pk
     from paddle_tpu.inference.serving.cache import quantize_kv
     interp = jax.default_backend() != "tpu"
-    blk = pk._paged_block(T, interp)
+    blk = pk._paged_block(T, H, D, jnp.int8 if quantized else dtype, interp)
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(B, H, 1, D), dtype)
     nk = jnp.asarray(rs.randn(B, H, 1, D), dtype)

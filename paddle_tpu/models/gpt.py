@@ -95,10 +95,11 @@ def _paged_decode_attention(q, k, v, view):
 
     Fast path — the fused Pallas megakernel
     (ops/pallas_kernels.paged_decode_attention_or_none): one launch per
-    layer doing length-masked flash attention over only the LIVE cache
-    blocks, with the new-token append (incl. int8 quantize) and the
-    k_scale/v_scale dequant folded in, so per-token HBM traffic scales
-    with live length rather than cache capacity. Counter
+    layer whose grid walks the (slot, key block) pairs that hold a live
+    row and no others, every head of a slot in one step, with the
+    new-token append (incl. int8 quantize) and the k_scale/v_scale
+    dequant folded in, so a call's steps and its HBM traffic scale with
+    the live lengths rather than with cache capacity. Counter
     pt_attn_path_total{path=paged_flash}.
 
     Fallback (flag off / ineligible shape / unhealthy Mosaic / CPU) —

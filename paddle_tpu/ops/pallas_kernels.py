@@ -1499,30 +1499,39 @@ def flash_attention_or_none(query, key, value, attn_mask, is_causal,
 # ---------------------------------------------------------------------------
 # Fused paged-decode attention (the serving megakernel)
 #
-# One Pallas program family per decode step over grid (slot, head, k-block),
-# called once a layer on the STACKED cache [L, B, H, T, D] with the layer
-# index as a second scalar-prefetch operand: length-masked flash-style
-# attention over the paged KV cache that READS only the live blocks of each
-# slot (the k/v BlockSpec index map clamps the block index to
-# lens[slot]//block_k, so Mosaic's revisiting optimization never fetches the
-# empty tail — per-token HBM traffic scales with live length, not T_max).
-# Folded into the same pass:
-#   * the new-token KV append: the incoming k/v row is substituted into the
-#     fetched append block in-register (and, for int8 caches, quantized
-#     in-kernel with quantize_kv's exact absmax rule) and that ONE block is
-#     written back through the cache outputs — the einsum path's separate
-#     quantize + scatter round trip disappears;
+# One Pallas call a layer on the STACKED cache [L, B, H, T, D], with the
+# layer index as a scalar-prefetch operand: length-masked flash-style
+# attention of one new token a slot over the paged KV cache.
+#
+# The grid is (H // heads, n): a grid step carries `heads` heads of one slot
+# (all of them, where a VMEM budget allows: `_paged_block`) for one block of
+# `block_k` key rows, and the second axis walks a WORK LIST of the (slot,
+# block) pairs that hold a live row — slot 0's blocks 0 .. lens[0] //
+# block_k, then slot 1's, and so on (`_paged_work`, computed from `lens` in
+# the jitted step). Its length n is the grid's dynamic bound, so a call is
+# sum_b (lens[b] // block_k + 1) steps — B for an empty cache, at most
+# B * T // block_k — and never a step that fetches or computes nothing; the
+# executable is still compiled once. Folded into the same pass:
+#   * the new token: its score and its value start the running softmax at
+#     a slot's first block (for an int8 cache after quantize_kv's exact
+#     absmax rule, as the einsum path attends the row it has just
+#     written), so no block has the row substituted; at the slot's last
+#     block the 16-row (int8: 32-row) tile group that holds the append row
+#     is read from the fetched block, the row put in, and that group alone
+#     written back through the cache outputs;
 #   * int8 dequantization: k_scale multiplies the QK scores and v_scale the
 #     softmax probabilities (per-key scalars commute with the row dot
-#     products), so the f32 dequantized cache is never materialised.
+#     products), so the dequantized cache is never materialised.
+# The products are head-batched `dot_general`s in the query's dtype when
+# that is bfloat16 (K and V are bfloat16 or int8, which it holds exactly;
+# the probabilities are rounded to it, as in `_paged_gqa_kernel`), else in
+# float32; scores, running max and sum, and the accumulator are float32.
 # The cache operands (k, v, and both scales when quantized) are aliased to
 # their outputs (input_output_aliases): the call updates the stacked buffer
 # in place, and L chained calls thread one HBM buffer through a decode step.
-# The cache output's block is the append block (layer, b, h, jm) for every
-# grid step j, written at j == jm alone; every other row of every layer
-# keeps its contents. Rows past a slot's length are whatever was there
-# before (zeros, or a previous tenant's rows) and are masked out of every
-# read.
+# Every row but the appended one, of every layer, keeps its contents. Rows
+# from a slot's length on are whatever was there before (zeros, or a
+# previous tenant's rows) and are masked out of every read.
 #
 # Dispatch: paged_decode_attention_or_none (flag, shape legality,
 # FLAGS_paged_flash_interpret for the CPU emulator). Flag off or an
@@ -1531,151 +1540,182 @@ def flash_attention_or_none(query, key, value, attn_mask, is_causal,
 # ---------------------------------------------------------------------------
 
 _KV_QUANT_EPS = 1e-8  # quantize_kv's zero-row guard (cache.py)
+_PAGED_BLOCK_BYTES = 512 << 10  # a K or V block; twice two are in flight
 
 
-def _paged_block(T, interpret):
-    """k-block size for a T_max-deep paged cache, or None when the shape
-    is ineligible (the caller takes the einsum path). Smaller blocks read
-    less dead tail past lens (reads round up to one block); larger blocks
-    amortize grid steps.
+def _paged_heads(H, block_k, D, itemsize):
+    """Heads a grid step carries: the most, of H's divisors, whose block of
+    `block_k` key rows stays within _PAGED_BLOCK_BYTES."""
+    fit = max(1, _PAGED_BLOCK_BYTES // (block_k * D * itemsize))
+    return max(h for h in range(1, min(H, fit) + 1) if H % h == 0)
 
-    Compiled for the TPU the block is 128 (T a multiple of 128) or the
-    whole of a short T: the scale rows of an int8 cache are blocked along
-    their LANE axis, where Mosaic takes a multiple of 128 or the full
-    axis, and int8 tiles want 32 sublanes. The emulator has no tiling, so
-    CPU tests may cross blocks at small T."""
-    if not interpret:
-        if T % 128 == 0:
-            return 128
-        return T if T < 128 and T % 32 == 0 else None
-    for b in (128, 64, 32, 16, 8):
-        if b <= T and T % b == 0:
+
+def _paged_block(T, H, D, dtype, interpret):
+    """Key rows a grid step reads from a T-deep cache of H heads of D in
+    `dtype`, or None when the shape is ineligible (the caller takes the
+    einsum path): the largest block that divides T and holds EVERY head
+    within _PAGED_BLOCK_BYTES — K and V blocks, double-buffered, are then
+    2 MB in flight — and, where not even the smallest holds them all, the
+    smallest (`_paged_heads` then cuts the heads). Fewer, fatter steps
+    amortize a step's fixed cost; reads round up to one block.
+
+    Compiled for the TPU a block is a multiple of 128 rows or the whole of
+    a short T: the scale rows of an int8 cache are blocked along their
+    LANE axis, where Mosaic takes a multiple of 128 or the full axis, and
+    int8 tiles want 32 sublanes. The emulator has no tiling, so CPU tests
+    may cross blocks at small T."""
+    if interpret:
+        blocks = [b for b in (128, 64, 32, 16, 8) if T % b == 0]
+    elif T % 128 == 0:
+        blocks = [b for b in (1024, 512, 256, 128) if T % b == 0]
+    else:
+        blocks = [T] if T < 128 and T % 32 == 0 else []
+    if not blocks:
+        return None
+    itemsize = jnp.dtype(dtype).itemsize
+    for b in blocks:
+        if H * b * D * itemsize <= _PAGED_BLOCK_BYTES:
             return b
-    return None
+    return blocks[-1]
+
+
+def _paged_work(lens, T, block_k):
+    """The work list of a call: (slot, block, n), int32 [B * T // block_k]
+    twice and a scalar. Entry i < n is the i-th (slot, block) pair in slot
+    order, a slot's blocks 0 .. min(lens, T - 1) // block_k in turn; the
+    entries from n on are never visited."""
+    nblk = jnp.minimum(lens, T - 1) // block_k + 1          # [B]
+    end = jnp.cumsum(nblk)
+    i = jnp.arange(lens.shape[0] * (T // block_k), dtype=jnp.int32)
+    before = end[None, :] <= i[:, None]       # slots wholly before entry i
+    slot = jnp.minimum(jnp.sum(before, axis=1), lens.shape[0] - 1)
+    blk = i - jnp.sum(jnp.where(before, nblk[None, :], 0), axis=1)
+    return slot.astype(jnp.int32), blk.astype(jnp.int32), end[-1]
 
 
 def _kernel_quantize_row(x):
     """In-kernel int8 row quantization — MUST mirror
     inference.serving.cache.quantize_kv exactly (same absmax, eps floor,
     /127.0, round-to-nearest-even) or fused vs einsum engines lose greedy
-    parity. x: [1, d] f32 → ([1, d] int8, [1, 1] f32 scale)."""
-    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    parity. x: [.., d] f32 → ([.., d] int8, [.., 1] f32 scale)."""
+    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, _KV_QUANT_EPS) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127.0, 127.0).astype(jnp.int8)
     return q, scale
 
 
-def _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
-                vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref, acc_ref,
-                m_ref, l_ref, *, block_k, t_max, sm_scale):
-    """Grid (B, H, T//block_k); this body runs once per k-block of one
-    (slot, head). State (acc/m/l) lives in VMEM scratch across the j steps
-    of a (slot, head) and is reset at j == 0. Steps past the append block
-    (j > jm) do nothing — their k/v fetch was clamped to block jm by the
-    index map, so they cost neither HBM traffic nor compute. The cache
-    outputs hold the append block for every j (their index map does not
-    move with j) and are written at j == jm alone."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nblk = pl.num_programs(2)
-    ln = lens_ref[b]                          # live length, pre-append
-    cl = jnp.minimum(ln, t_max - 1)           # append row (the einsum path's
-    jm = cl // block_k                        # index clamp)
+def _paged_core(lens_ref, slot_ref, blk_ref, q_ref, nk_ref, nv_ref, k_ref,
+                v_ref, ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref,
+                vso_ref, acc_ref, m_ref, l_ref, *, block_k, t_max,
+                sm_scale):
+    """Grid (H // heads, n); this body runs once for entry i of the work
+    list: block j of slot b, `heads` heads at once (every ref has them as
+    its leading axis: q/new k/new v/out [heads, 1, D], K/V [heads, block_k,
+    D], scales [heads, 1, block_k]). State (acc/m/l) lives in VMEM scratch
+    across a slot's blocks: the new token starts it at j == 0, every block
+    with a live row adds to it, and the slot's last block — the one that
+    holds the append row — writes the row's tile group back and the
+    output out. The cache outputs hold that group for every step of the
+    slot (their index map does not move with j)."""
+    i = pl.program_id(1)
+    b, j = slot_ref[i], blk_ref[i]
+    cl = jnp.minimum(lens_ref[b], t_max - 1)  # append row (the einsum path's
+    ja = cl // block_k                        # index clamp); its block
+    off = cl - j * block_k
     quantized = ks_ref is not None
+    hb, _, d = k_ref.shape
+    group = ko_ref.shape[1]
+    q = q_ref[...]                                            # [hb, 1, d]
+    cd = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
+
+    def new_rows():
+        if quantized:
+            nkq, nks = _kernel_quantize_row(nk_ref[...].astype(jnp.float32))
+            nvq, nvs = _kernel_quantize_row(nv_ref[...].astype(jnp.float32))
+            return nkq, nvq, nks, nvs
+        return (nk_ref[...].astype(ko_ref.dtype),
+                nv_ref[...].astype(vo_ref.dtype), None, None)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        nkq, nvq, nks, nvs = new_rows()
+        s = jnp.sum(q.astype(jnp.float32) * nkq.astype(jnp.float32),
+                    axis=-1, keepdims=True) * sm_scale        # [hb, 1, 1]
+        v = nvq.astype(jnp.float32)
+        if quantized:
+            s, v = s * nks, v * nvs
+        m_ref[...] = jnp.broadcast_to(s, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = v
 
-    @pl.when(j <= jm)
+    @pl.when(off > 0)          # a row before the append row: a live one
     def _step():
-        d = q_ref.shape[1]
-        # global key positions of this block; the append column/row masks
-        # are exact because cl lands in block jm and nowhere else
-        pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)                       # [1, bk]
-        app_lane = pos == cl
-        row_sel = jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, d), 0) == (cl - j * block_k)  # [bk, d]
+        live = jax.lax.broadcasted_iota(
+            jnp.int32, (hb, 1, block_k), 2) < off
+        # rows from cl on were written by no tenant of this slot (stale
+        # rows; garbage in a test): a NaN there would get through 0 * NaN,
+        # so select them to zero rather than relying on p == 0
+        rows = jax.lax.broadcasted_iota(jnp.int32, (hb, block_k, d), 1)
+        v = v_ref[...].astype(cd)
+        v = jnp.where(rows < off, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q.astype(cd), k_ref[...].astype(cd),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale  # [hb, 1, bk]
         if quantized:
-            nkq, nks = _kernel_quantize_row(
-                nk_ref[...].astype(jnp.float32))
-            nvq, nvs = _kernel_quantize_row(
-                nv_ref[...].astype(jnp.float32))
-            ks = jnp.where(app_lane, nks, ks_ref[...])         # [1, bk]
-            vs = jnp.where(app_lane, nvs, vs_ref[...])
-        else:
-            nkq = nk_ref[...].astype(ko_ref.dtype)
-            nvq = nv_ref[...].astype(vo_ref.dtype)
-            ks = vs = None
-        kq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-            nkq, row_sel.shape, (0, 1)), k_ref[...])
-        vq = jnp.where(row_sel, jax.lax.broadcast_in_dim(
-            nvq, row_sel.shape, (0, 1)), v_ref[...])
-
-        @pl.when(j == jm)
-        def _append():
-            ko_ref[...] = kq
-            vo_ref[...] = vq
-            if quantized:
-                kso_ref[...] = ks
-                vso_ref[...] = vs
-
-        q = q_ref[...].astype(jnp.float32) * sm_scale          # [1, d]
-        s = jax.lax.dot_general(q, kq.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if quantized:
-            s = s * ks     # per-key k_scale commutes with the D-dot
-        s = jnp.where(pos <= ln, s, _NEG_INF)
-        m_prev = jnp.max(m_ref[...], axis=1, keepdims=True)    # [1, 1]
-        l_prev = jnp.max(l_ref[...], axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                                 # [1, bk]
+            s = s * ks_ref[...]   # per-key k_scale commutes with the D-dot
+        s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_ref[:, :, :1]
+        l_prev = l_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pd = p * vs if quantized else p  # fold v_scale into the probs
-        # The append block's rows past ln were not written by this slot's
-        # tenant (stale rows; garbage in a test) — a NaN row there would
-        # poison the PV dot through 0*NaN, so hard-select both factors to
-        # zero rather than relying on p == 0.
-        pd = jnp.where(pos <= ln, pd, 0.0)
-        vrow = (j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, d), 0)) <= ln
-        vf = jnp.where(vrow, vq.astype(jnp.float32), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[...]   # fold v_scale into the probabilities
+        p = jnp.where(live, p, 0.0)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pd, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jax.lax.broadcast_in_dim(m_new, m_ref.shape, (0, 1))
-        l_ref[...] = jax.lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1))
+            p.astype(cd), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)              # [hb, 1, d]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nblk - 1)
-    def _finish():
-        ell = jnp.max(l_ref[...], axis=1, keepdims=True)
-        # l > 0 always: the appended token (pos == cl <= ln) is live
-        o_ref[...] = (acc_ref[...] / ell).astype(o_ref.dtype)
+    @pl.when(j == ja)
+    def _append_and_finish():
+        nkq, nvq, nks, nvs = new_rows()
+        r0 = pl.multiple_of((off // group) * group, group)
+        sel = jax.lax.broadcasted_iota(
+            jnp.int32, (hb, group, d), 1) == (off - r0)
+        for new, old_ref, out_ref in ((nkq, k_ref, ko_ref),
+                                      (nvq, v_ref, vo_ref)):
+            out_ref[...] = jnp.where(
+                sel, jnp.broadcast_to(new, sel.shape),
+                old_ref[:, pl.ds(r0, group), :])
+        if quantized:
+            lane = jax.lax.broadcasted_iota(
+                jnp.int32, (hb, 1, block_k), 2) == off
+            kso_ref[...] = jnp.where(lane, nks, ks_ref[...])
+            vso_ref[...] = jnp.where(lane, nvs, vs_ref[...])
+        # l >= 1 always: the new token is in it
+        o_ref[...] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
-def _paged_f_kernel(lens_ref, layer_ref, q_ref, nk_ref, nv_ref, k_ref,
-                    v_ref, o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref, *,
-                    block_k, t_max, sm_scale):
+def _paged_f_kernel(lens_ref, layer_ref, slot_ref, blk_ref, q_ref, nk_ref,
+                    nv_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, acc_ref,
+                    m_ref, l_ref, **kw):
     del layer_ref  # read by the index maps only
-    _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, None, None,
-                o_ref, ko_ref, vo_ref, None, None, acc_ref, m_ref, l_ref,
-                block_k=block_k, t_max=t_max, sm_scale=sm_scale)
+    _paged_core(lens_ref, slot_ref, blk_ref, q_ref, nk_ref, nv_ref, k_ref,
+                v_ref, None, None, o_ref, ko_ref, vo_ref, None, None,
+                acc_ref, m_ref, l_ref, **kw)
 
 
-def _paged_q_kernel(lens_ref, layer_ref, q_ref, nk_ref, nv_ref, k_ref,
-                    v_ref, ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref,
-                    vso_ref, acc_ref, m_ref, l_ref, *, block_k, t_max,
-                    sm_scale):
+def _paged_q_kernel(lens_ref, layer_ref, slot_ref, blk_ref, q_ref, nk_ref,
+                    nv_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, ko_ref,
+                    vo_ref, kso_ref, vso_ref, acc_ref, m_ref, l_ref, **kw):
     del layer_ref
-    _paged_core(lens_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref, ks_ref,
-                vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref, acc_ref,
-                m_ref, l_ref, block_k=block_k, t_max=t_max,
-                sm_scale=sm_scale)
+    _paged_core(lens_ref, slot_ref, blk_ref, q_ref, nk_ref, nv_ref, k_ref,
+                v_ref, ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref,
+                vso_ref, acc_ref, m_ref, l_ref, **kw)
 
 
 def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
@@ -1686,43 +1726,49 @@ def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
     (out, k_cache', v_cache', k_scale'|None, v_scale'|None): the caches
     are the operands updated in place (aliased), one row a (slot, head).
 
-    The layer rides as a second scalar-prefetch operand, not as a
-    constant folded into the index maps: every layer of a decode step is
-    then the same kernel, traced and lowered once."""
+    The layer rides as a scalar-prefetch operand, not as a constant folded
+    into the index maps, and the work list's length as the grid's dynamic
+    bound: every layer of every decode step is then the same kernel,
+    traced and lowered once."""
     B, H, _, D = q.shape
     T = k_cache.shape[3]
     quantized = k_scale is not None
-    sm_scale = float(D) ** -0.5
+    hb = _paged_heads(H, block_k, D, k_cache.dtype.itemsize)
+    # the tile group written back: 16 sublanes hold a bf16 tile (and two
+    # of float32), 32 an int8 one; the emulator's small blocks are one group
+    group = min(32 if quantized else 16, block_k)
+    lens = lens.astype(jnp.int32)
+    slot, blk, n_work = _paged_work(lens, T, block_k)
 
-    def _jm(b, lens):
-        return jnp.minimum(lens[b], T - 1) // block_k
+    def _row(b, lens):
+        return jnp.minimum(lens[b], T - 1)
 
-    def kv_map(b, h, j, lens, layer):
-        return (layer[0], b, h, jnp.minimum(j, _jm(b, lens)), _I0)
+    def kv_map(h, i, lens, layer, slot, blk):
+        return (layer[0], slot[i], h, blk[i], _I0)
 
-    def kv_out_map(b, h, j, lens, layer):
-        return (layer[0], b, h, _jm(b, lens), _I0)
+    def kv_out_map(h, i, lens, layer, slot, blk):
+        return (layer[0], slot[i], h, _row(slot[i], lens) // group, _I0)
 
-    def sc_map(b, h, j, lens, layer):
-        return (layer[0], b, h, _I0, jnp.minimum(j, _jm(b, lens)))
+    def sc_map(h, i, lens, layer, slot, blk):
+        return (layer[0], slot[i], h, _I0, blk[i])
 
-    def sc_out_map(b, h, j, lens, layer):
-        return (layer[0], b, h, _I0, _jm(b, lens))
+    def sc_out_map(h, i, lens, layer, slot, blk):
+        return (layer[0], slot[i], h, _I0, _row(slot[i], lens) // block_k)
 
-    def tok_map(b, h, j, lens, layer):
-        return (b, h, _I0, _I0)
+    def tok_map(h, i, lens, layer, slot, blk):
+        return (slot[i], h, _I0, _I0)
 
-    kv_block = (None, None, None, block_k, D)
-    # scales ride as [L, B, H, 1, T]: a (1, block_k) block is then "the
-    # full second-minor axis x a lane-aligned slice", which Mosaic
-    # accepts; a block of 1 row out of H over [B, H, T] is neither
-    sc_block = (None, None, None, 1, block_k)
-    tok_spec = pl.BlockSpec((None, None, 1, D), tok_map)
+    kv_block = (None, None, hb, block_k, D)
+    grp_block = (None, None, hb, group, D)
+    # scales ride as [L, B, H, 1, T]: a block [hb, 1, block_k] then has the
+    # heads as its leading axis, like the scores it multiplies
+    sc_block = (None, None, hb, 1, block_k)
+    tok_spec = pl.BlockSpec((None, hb, 1, D), tok_map)
     in_specs = [tok_spec, tok_spec, tok_spec,
                 pl.BlockSpec(kv_block, kv_map),
                 pl.BlockSpec(kv_block, kv_map)]
-    out_specs = [tok_spec, pl.BlockSpec(kv_block, kv_out_map),
-                 pl.BlockSpec(kv_block, kv_out_map)]
+    out_specs = [tok_spec, pl.BlockSpec(grp_block, kv_out_map),
+                 pl.BlockSpec(grp_block, kv_out_map)]
     out_shape = [jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
                  jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                  jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
@@ -1737,26 +1783,24 @@ def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
     else:
         kernel = _paged_f_kernel
     kern = functools.partial(kernel, block_k=block_k, t_max=T,
-                             sm_scale=sm_scale)
-    n_prefetch = 2                     # lens, layer
+                             sm_scale=float(D) ** -0.5)
+    prefetch = [lens, jnp.asarray(layer, jnp.int32).reshape(1), slot, blk]
     n_tok = 3                          # q, new_k, new_v
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, H, T // block_k),
+        num_scalar_prefetch=len(prefetch),
+        grid=(H // hb, n_work),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32),
-                        pltpu.VMEM((1, _LANES), jnp.float32),
-                        pltpu.VMEM((1, _LANES), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((hb, 1, D), jnp.float32),
+                        pltpu.VMEM((hb, 1, _LANES), jnp.float32),
+                        pltpu.VMEM((hb, 1, _LANES), jnp.float32)])
     # cache operand i (after the prefetch and token operands) -> output
     # 1 + i (after `out`); the alias indices count the prefetch operands
-    aliases = {n_prefetch + n_tok + i: 1 + i
+    aliases = {len(prefetch) + n_tok + i: 1 + i
                for i in range(len(operands) - n_tok)}
     outs = _pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                         input_output_aliases=aliases,
-                        interpret=interpret)(
-        lens.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+                        interpret=interpret)(*prefetch, *operands)
     if quantized:
         out, ko, vo, kso, vso = outs
         return (out, ko, vo, kso.reshape(k_scale.shape),
@@ -1767,21 +1811,22 @@ def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
 
 def _check_paged():
     """The float paged-decode kernel at a small but representative shape
-    (stacked cache of 3 layers, multi-block, ragged lens incl. an idle
-    slot), called for the middle layer: the output and that layer's cache
-    are value-checked against the einsum oracle, and every row the call
-    did not append — the other layers, and the named layer's rows past
-    each slot's length — must come back bit-identical."""
-    L, B, H, T, D = 3, 2, 2, 256, 64
+    (stacked cache of 3 layers, eight heads a block, two blocks a slot;
+    an idle slot, one whose append row opens the second block and one that
+    has crossed into it), called for the middle layer: the output and that
+    layer's cache are value-checked against the einsum oracle, and every
+    row the call did not append — the other layers, and the named layer's
+    rows past each slot's length — must come back bit-identical."""
+    L, B, H, T, D = 3, 3, 8, 256, 128
     layer = 1
-    blk = _paged_block(T, interpret=False)
+    blk = _paged_block(T, H, D, jnp.float32, interpret=False)
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
     nk = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
     nv = jnp.asarray(rs.randn(B, H, 1, D), jnp.float32)
     k = jnp.asarray(rs.randn(L, B, H, T, D), jnp.float32)
     v = jnp.asarray(rs.randn(L, B, H, T, D), jnp.float32)
-    lens = jnp.asarray([0, 130], jnp.int32)
+    lens = jnp.asarray([0, 128, 130], jnp.int32)
 
     @jax.jit
     def run(q):
@@ -1840,7 +1885,7 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
     B, H, _, D = q.shape
     T = k_cache.shape[3]
     interpret = jax.default_backend() != "tpu"
-    blk = _paged_block(T, interpret)
+    blk = _paged_block(T, H, D, k_cache.dtype, interpret)
     if blk is None or D % 8 != 0 or D > 256:
         return None
     if interpret:

@@ -119,7 +119,10 @@ def _layer_args(args, layer=LAYER):
 
 
 def _run(args, T=96, layer=LAYER):
-    blk = pk._paged_block(T, interpret=True)
+    q, kc = args[0], args[1]
+    assert kc.shape[3] == T
+    blk = pk._paged_block(T, q.shape[1], q.shape[3], kc.dtype,
+                          interpret=True)
     return pk._paged_decode(*args, layer=layer, block_k=blk,
                             interpret=True)
 
@@ -231,16 +234,111 @@ class TestPagedDecodeKernel:
                     np.asarray(got)[1, b, :, lens[b]],
                     np.asarray(want)[b, :, lens[b]], rtol=2e-5, atol=0)
 
-    def test_paged_block_chooser(self):
-        assert pk._paged_block(2048, interpret=True) == 128
-        assert pk._paged_block(96, interpret=True) == 32
-        assert pk._paged_block(64, interpret=True) == 64
-        assert pk._paged_block(7, interpret=True) is None
-        # compiled for the TPU: 128, or the whole of a short cache
-        assert pk._paged_block(2048, interpret=False) == 128
-        assert pk._paged_block(64, interpret=False) == 64
-        assert pk._paged_block(192, interpret=False) is None
-        assert pk._paged_block(24, interpret=False) is None
+    # many heads a grid step, H not a power of two; 32-row blocks at T = 96
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    @pytest.mark.parametrize("lens", [
+        (0, 31, 32, 95),      # empty; one short of a block edge; on it; wall
+        (0, 95, 0, 64),       # idle slots beside full ones
+        (33, 63, 64, 65),     # round the second edge
+    ], ids=["edges", "idle-beside-full", "second-edge"])
+    def test_all_heads_of_a_slot_in_one_block(self, lens, quantized):
+        # output against the einsum oracle; every row the call did not
+        # append bit-identical in all three layers (`_check` compares the
+        # whole stacked arrays)
+        args = _mk(B=4, H=12, lens=lens, L=3, quantized=quantized)
+        assert pk._paged_heads(12, 32, 16, 1 if quantized else 4) == 12
+        _check(args, atol=1e-4 if quantized else 1e-5)
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    def test_heads_cut_to_a_budget_walk_two_head_groups(
+            self, quantized, monkeypatch):
+        # a budget that holds 6 of the 12 heads: grid (2, n), the work
+        # list walked once a head group
+        itemsize = 1 if quantized else 4
+        monkeypatch.setattr(pk, "_PAGED_BLOCK_BYTES", 6 * 32 * 16 * itemsize)
+        assert pk._paged_heads(12, 32, 16, itemsize) == 6
+        _check(_mk(B=3, H=12, lens=(0, 40, 95), quantized=quantized),
+               atol=1e-4 if quantized else 1e-5)
+
+    @pytest.mark.parametrize("lens,n", [
+        ((0, 0, 0), 3),                    # a step a slot, even when empty
+        ((31, 32, 95), 1 + 2 + 3),
+        ((200, 95, 94), 3 + 3 + 3),        # past the wall: clamped
+    ])
+    def test_work_list_holds_the_live_blocks_in_slot_order(self, lens, n):
+        slot, blk, got = pk._paged_work(jnp.asarray(lens, jnp.int32), 96, 32)
+        assert int(got) == n and slot.shape == blk.shape == (9,)
+        want = [(b, j) for b, ln in enumerate(lens)
+                for j in range(min(ln, 95) // 32 + 1)]
+        assert list(zip(np.asarray(slot)[:n].tolist(),
+                        np.asarray(blk)[:n].tolist())) == want
+
+    @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+    def test_grid_of_the_served_shape_is_the_work_list(self, kv_dtype):
+        # GPT-3 XL as the benchmark serves it: the lowered call's grid is
+        # (head groups = 1, the work list's length), which is at most
+        # B * T // block_k — the (B, H, T // 128) = 3 072-step grid cannot
+        # come back unnoticed
+        L, B, H, T, D = 2, 24, 16, 1024, 128
+        quantized = kv_dtype == "int8"
+        blk = pk._paged_block(T, H, D, kv_dtype, interpret=False)
+        tok = jax.ShapeDtypeStruct((B, H, 1, D), jnp.bfloat16)
+        cache = jax.ShapeDtypeStruct((L, B, H, T, D), jnp.dtype(kv_dtype))
+        scale = jax.ShapeDtypeStruct((L, B, H, T), jnp.float32) \
+            if quantized else None
+        closed = jax.make_jaxpr(lambda *a: pk._paged_decode(
+            *a, layer=1, block_k=blk, interpret=False))(
+            tok, cache, cache, jax.ShapeDtypeStruct((B,), jnp.int32), tok,
+            tok, scale, scale)
+        (call,) = [e for e in closed.jaxpr.eqns
+                   if e.primitive.name == "pallas_call"]
+        gm = call.params["grid_mapping"]
+        assert gm.num_dynamic_grid_bounds == 1 and len(gm.grid) == 2
+        assert gm.grid[0] == H // pk._paged_heads(
+            H, blk, D, jnp.dtype(kv_dtype).itemsize) == 1
+        # the dynamic bound is the work list's length: the sum of the
+        # slots' block counts, B * T // block_k when every slot is full
+        for lens, n in ((np.zeros(B), B), (np.full(B, T), B * T // blk),
+                        (np.arange(B) * 40, None)):
+            slot, _, got = pk._paged_work(jnp.asarray(lens, jnp.int32), T,
+                                          blk)
+            assert slot.shape == (B * T // blk,)
+            assert B <= int(got) <= B * T // blk <= 192
+            assert n is None or int(got) == n
+
+    @pytest.mark.parametrize("args,want", [
+        # the emulator: the largest block that divides T
+        ((2048, 2, 16, "float32", True), 128),
+        ((96, 2, 16, "float32", True), 32),
+        ((64, 12, 16, "int8", True), 64),
+        ((7, 2, 16, "float32", True), None),
+        # compiled for the TPU: every head of a slot within 512 KB a
+        # block — GPT-3 XL 128 rows in bf16 and 256 in int8, gpt2-small
+        # 256 and 512 — or the whole of a short cache
+        ((1024, 16, 128, "bfloat16", False), 128),
+        ((1024, 16, 128, "int8", False), 256),
+        ((1024, 12, 64, "bfloat16", False), 256),
+        ((2048, 12, 64, "int8", False), 512),
+        ((2048, 2, 64, "float32", False), 1024),
+        ((64, 4, 64, "bfloat16", False), 64),
+        ((192, 4, 64, "bfloat16", False), None),
+        ((24, 4, 64, "bfloat16", False), None),
+        # float32 at GPT-3 XL's width: no block holds 16 heads, so the
+        # smallest, and the heads are cut
+        ((1024, 16, 128, "float32", False), 128),
+    ])
+    def test_paged_block_chooser(self, args, want):
+        assert pk._paged_block(*args) == want
+
+    @pytest.mark.parametrize("args,want", [
+        ((16, 128, 128, 2), 16), ((16, 256, 128, 1), 16),
+        ((16, 128, 128, 4), 8), ((12, 256, 64, 2), 12),
+        ((12, 1024, 128, 4), 1), ((12, 128, 128, 4), 6),
+    ])
+    def test_paged_heads_chooser(self, args, want):
+        assert pk._paged_heads(*args) == want
 
 
 class TestDispatchGate:
@@ -411,18 +509,22 @@ class TestEngineFusedPath:
         self._assert_no_restack(eqns, shapes)
         calls = [e for e in eqns if e.primitive.name == "pallas_call"]
         assert len(calls) == 2                      # one a layer
-        # the kernel sees the scales as [L, B, H, 1, T]
+        # the kernel sees the scales as [L, B, H, 1, T]: the heads lead
         shapes |= {s[:3] + (1, s[3]) for s in shapes if len(s) == 4}
         for eqn in calls:
             aliases = dict(eqn.params["input_output_aliases"])
-            cache_ops = [i for i, v in enumerate(eqn.invars)
+            # the grid's dynamic bound leads the equation's operands and
+            # is not counted by the alias indices
+            n_dyn = eqn.params["grid_mapping"].num_dynamic_grid_bounds
+            invars = eqn.invars[n_dyn:]
+            cache_ops = [i for i, v in enumerate(invars)
                          if tuple(v.aval.shape) in shapes]
             assert len(cache_ops) == (4 if kv_dtype == "int8" else 2)
             for i in cache_ops:
                 assert i in aliases, (i, aliases)
                 out = eqn.outvars[aliases[i]].aval
                 assert (out.shape, out.dtype) == (
-                    eqn.invars[i].aval.shape, eqn.invars[i].aval.dtype)
+                    invars[i].aval.shape, invars[i].aval.dtype)
 
     @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
     def test_einsum_fallback_jaxpr_never_restacks(self, kv_dtype):

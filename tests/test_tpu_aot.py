@@ -12,6 +12,7 @@ xdist worker loads the TPU's library and every worker collects the same
 tests. Keep further such compiles in this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas_kernels as pk
 
-# gpt3-1.3b as the served cells run it, cut to three layers
-L, B, H, T, D = 3, 24, 16, 1024, 128
+# gpt3-1.3b as the served cells run it, all 24 layers: every head of a slot
+# in one block (128 key rows in bf16, 256 in int8)
+L, B, H, T, D = 24, 24, 16, 1024, 128
 
 
 @pytest.fixture(scope="module")
@@ -39,10 +41,12 @@ def one_chip():
 def _decode_chain(q, kc, vc, ks, vs, lens, nk, nv):
     """What `_decode_fn` does to the cache: every layer's kernel call in
     turn on the one stacked buffer."""
+    blk = pk._paged_block(T, H, D, kc.dtype, interpret=False)
+    assert pk._paged_heads(H, blk, D, kc.dtype.itemsize) == H
     for layer in range(L):
         q, kc, vc, ks, vs = pk._paged_decode(
-            q, kc, vc, lens, nk, nv, ks, vs, layer=layer,
-            block_k=pk._paged_block(T, interpret=False), interpret=False)
+            q, kc, vc, lens, nk, nv, ks, vs, layer=layer, block_k=blk,
+            interpret=False)
     return q, kc, vc, ks, vs
 
 
@@ -58,7 +62,19 @@ def test_paged_decode_chain_updates_the_cache_in_place(one_chip, kv_dtype):
     compiled = jax.jit(_decode_chain, donate_argnums=(1, 2, 3, 4)).lower(
         tok, cache, cache, scale, scale, sds((B,), jnp.int32), tok, tok
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") == L
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == L
+    # no instruction but the kernels (and the plumbing round them) has a
+    # cache stack as its result; a copy of the scales' stack would show in
+    # the temporaries below
+    stack = "%s[%d,%d,%d,%d,%d]" % ("s8" if quantized else "bf16",
+                                    L, B, H, T, D)
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) "
+                     r"([\w\-]+)\(", line)
+        if m and stack in m.group(2):
+            assert m.group(3) in ("custom-call", "parameter", "tuple",
+                                  "get-tuple-element", "bitcast"), line
     ma = compiled.memory_analysis()
     cache_bytes = 2 * L * B * H * T * D * jnp.dtype(kv_dtype).itemsize
     scale_bytes = 2 * L * B * H * T * 4 if quantized else 0
@@ -92,7 +108,6 @@ def test_decoder_decode_step_updates_both_cache_stacks_in_place(
     program's temporaries are a few megabytes, and no XLA op reads or
     writes an array of a cache stack's size (PR 27's property, kept for
     two stacks)."""
-    import re
     from paddle_tpu.framework.random import RNG
     from paddle_tpu.inference.serving.engine import GenerationEngine
     from paddle_tpu.models.decoder import DecoderConfig, DecoderLM
