@@ -7,9 +7,8 @@ the reference publishes no in-repo numbers (BASELINE.md: "published: {}").
 
 One process: whoever touches jax holds the chip, so the bench body runs
 here and not in a child. Without a TPU, or when any part of the bench
-raises, the exit code is non-zero and no metric line is printed. The gate
-helpers (`serving_gates`, `_budget_gates`) are imported by
-benchmarks/inference_bench.py."""
+raises, the exit code is non-zero and no metric line is printed. The
+serving benchmark is BENCHMARK.json's (`benchmarks/perf/run.py`)."""
 from __future__ import annotations
 
 import json
@@ -107,109 +106,6 @@ def _budget_gates(row):
             gates=gates, compile_s=row.get("compile_s"),
             retraces=row.get("retraces"),
             compile_cache=row.get("compile_cache"))
-    return gates
-
-
-def serving_gates(row):
-    """Serving acceptance gates (ISSUE 10 + ISSUE 13), computed on the
-    `inference_bench.py` serving rows (which import this helper).
-    Every check is keyed on the fields the
-    row actually carries, so the classic `gpt2_generate` row gets the
-    compile-once + continuous-beats-static gates and the
-    `gpt2_prefix_int8` row additionally gets the shared-prefix reuse
-    and int8-quantization contracts:
-
-      * prefix_hit_ttft_le_0.6x_miss — a prefix-cache hit's TTFT p50
-        must be <= 0.6x the miss TTFT p50 (reuse actually skips work)
-      * prefix_reuse_tps_ge_noreuse — reuse must never cost throughput
-      * int8_greedy_parity_ge_64 — >= 64 greedy tokens, all equal to
-        the float-cache engine's (the EQuARX-style accuracy contract)
-      * int8_nbytes_le_0.55x_bf16 — quantized cache bytes (payload +
-        scales) vs a bf16 cache of identical geometry
-      * int8_decode_compile_once — quantize-on-append must not break
-        the compile-once contract
-      * fused_decode_tps_ge_einsum — the fused paged-decode megakernel
-        engine (ISSUE 15) must not be slower than the windowed-einsum
-        fallback engine on the same workload (TPU evidence only; rows
-        carry both fields only when the paths actually diverge)
-
-    Same contract as the budget gates: a miss is recorded in the row and
-    emits a `bench_gate_failed` journal event."""
-    gates = {}
-    if isinstance(row.get("decode_compiles"), (int, float)):
-        gates["decode_compile_once"] = row["decode_compiles"] == 1
-    if isinstance(row.get("prefill_compiles"), (int, float)) and \
-            isinstance(row.get("n_buckets"), (int, float)):
-        gates["prefill_le_buckets"] = \
-            row["prefill_compiles"] <= row["n_buckets"]
-    if isinstance(row.get("speedup_x"), (int, float)):
-        gates["continuous_beats_static"] = row["speedup_x"] > 1.0
-    if isinstance(row.get("prefix_ttft_ratio"), (int, float)):
-        gates["prefix_hit_ttft_le_0.6x_miss"] = \
-            row["prefix_ttft_ratio"] <= 0.6
-    if isinstance(row.get("tokens_per_s"), (int, float)) and \
-            isinstance(row.get("noreuse_tokens_per_s"), (int, float)):
-        gates["prefix_reuse_tps_ge_noreuse"] = \
-            row["tokens_per_s"] >= row["noreuse_tokens_per_s"]
-    if isinstance(row.get("int8_parity_tokens"), (int, float)):
-        gates["int8_greedy_parity_ge_64"] = \
-            row["int8_parity_tokens"] >= 64 and \
-            bool(row.get("int8_parity_ok"))
-    if isinstance(row.get("int8_nbytes_ratio"), (int, float)):
-        gates["int8_nbytes_le_0.55x_bf16"] = \
-            row["int8_nbytes_ratio"] <= 0.55
-    if isinstance(row.get("int8_decode_compiles"), (int, float)):
-        gates["int8_decode_compile_once"] = \
-            row["int8_decode_compiles"] == 1
-    if isinstance(row.get("fused_decode_tps"), (int, float)) and \
-            isinstance(row.get("einsum_decode_tps"), (int, float)):
-        gates["fused_decode_tps_ge_einsum"] = \
-            row["fused_decode_tps"] >= row["einsum_decode_tps"]
-    # SLO overload gates (ISSUE 17), keyed on the gpt2_overload row's
-    # fields: at 3x offered load the admission-controlled engine must
-    # keep goodput >= 90% of measured capacity while the p99 TTFT of
-    # ADMITTED requests holds the budget; the shedding-disabled arm
-    # must demonstrably collapse (p99 past the budget, TTFT growing
-    # with the queue); and the chaos-drilled brownout arm proves
-    # shed-never-crash (zero crash bundles, every request resolved).
-    if isinstance(row.get("overload_goodput_ratio"), (int, float)):
-        gates["overload_goodput_ge_0.9x_capacity"] = \
-            row["overload_goodput_ratio"] >= 0.9
-    if isinstance(row.get("overload_admitted_p99_ms"), (int, float)) and \
-            isinstance(row.get("slo_budget_ms"), (int, float)):
-        gates["overload_admitted_p99_le_budget"] = \
-            row["overload_admitted_p99_ms"] <= row["slo_budget_ms"]
-    if isinstance(row.get("noshed_ttft_p99_ms"), (int, float)) and \
-            isinstance(row.get("slo_budget_ms"), (int, float)):
-        collapse = row["noshed_ttft_p99_ms"] > row["slo_budget_ms"]
-        if isinstance(row.get("noshed_growth_x"), (int, float)):
-            collapse = collapse and row["noshed_growth_x"] > 1.0
-        gates["noshed_collapses"] = collapse
-    if isinstance(row.get("overload_shed"), (int, float)):
-        gates["overload_sheds_fired"] = row["overload_shed"] >= 1
-    if isinstance(row.get("crash_bundles"), (int, float)):
-        gates["overload_zero_crash_bundles"] = row["crash_bundles"] == 0
-    if isinstance(row.get("brownout_shed"), (int, float)):
-        gates["brownout_shed_never_crash"] = \
-            row["brownout_shed"] >= 1 and \
-            bool(row.get("brownout_all_resolved")) and \
-            row.get("crash_bundles") == 0
-    if len(gates) < 3 or not all(gates.values()):
-        _emit_bench_event(
-            "bench_gate_failed", config=row.get("config"), gates=gates,
-            decode_compiles=row.get("decode_compiles"),
-            prefill_compiles=row.get("prefill_compiles"),
-            speedup_x=row.get("speedup_x"),
-            prefix_ttft_ratio=row.get("prefix_ttft_ratio"),
-            int8_parity_tokens=row.get("int8_parity_tokens"),
-            int8_nbytes_ratio=row.get("int8_nbytes_ratio"),
-            fused_decode_tps=row.get("fused_decode_tps"),
-            einsum_decode_tps=row.get("einsum_decode_tps"),
-            overload_goodput_ratio=row.get("overload_goodput_ratio"),
-            overload_admitted_p99_ms=row.get("overload_admitted_p99_ms"),
-            slo_budget_ms=row.get("slo_budget_ms"),
-            noshed_ttft_p99_ms=row.get("noshed_ttft_p99_ms"),
-            crash_bundles=row.get("crash_bundles"))
     return gates
 
 

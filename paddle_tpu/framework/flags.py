@@ -127,16 +127,11 @@ define_flag("use_fused_dropout_ln", False,
             "vs XLA's own fusion of this chain on v5e at GPT-2 shapes "
             "(benchmarks/fused_kernels_bench.py r3) — XLA wins; the kernel "
             "stays available for shapes/backends where it does not")
-define_flag("paged_flash_decode", True,
-            "route serving paged-decode attention to the fused Pallas "
-            "kernel (length-masked flash over live cache blocks with the "
-            "KV append and int8 dequant folded in) when shapes/backend "
-            "allow; off or ineligible shapes fall back to the windowed "
-            "XLA einsum path (pt_attn_path_total{path=xla_paged})")
 define_flag("paged_flash_interpret", False,
-            "allow the paged-decode kernel in Pallas interpret mode off "
-            "TPU (CPU parity tests and MEGAKERNEL_SMOKE only — the "
-            "emulator is far too slow for real serving)")
+            "allow the paged-decode kernels in Pallas interpret mode off "
+            "TPU (CPU parity tests only — the emulator is far too slow "
+            "for real serving); without it the CPU takes the einsum of "
+            "serving/cache.py (pt_attn_path_total{path=xla_paged})")
 define_flag("fused_block", False,
             "decoder-block fusion: GPTDecoderLayer runs the attention "
             "epilogue (residual dropout-add) and the following ln_2 as ONE "

@@ -22,19 +22,28 @@ Two throughput multipliers live here (ROADMAP item 3c):
     (int8 payload + scales side-buffer, dequantized next to the
     matmul). Bytes/slot roughly halve vs bf16, so `max_batch` doubles
     under the same HBM budget; the accuracy contract (greedy token
-    parity vs the float cache) is gated in `inference_bench.py`.
+    parity vs the float cache) is `tests/test_serving.py::TestInt8KV`'s.
   * **`PrefixCache`**: LRU store of bucket-aligned prompt-prefix K/V
     keyed on the token ids themselves. Requests sharing a system
     prompt skip recomputing it — the engine copies the cached K/V into
     the slot and prefills only the suffix.
 
-`LayerCacheView` is what `GPTAttention` is handed for one layer inside a
-traced decode step: a layer index into ONE `StackedKV` carrier that all
-the step's views share. The attention layer appends the step's K/V row
-to its layer of the stacked buffers, in place (the paged kernel aliases
-the cache to its output; the einsum fallback scatters one row a slot),
-and REPLACES the carrier's arrays with the updated ones. After the model
-has run, the carrier's arrays are the cache state the jitted function
+This module is the one place that knows the cache's layout: which arrays
+a state holds and in what order (`_state_fields`), where a ring keeps a
+position, how a prompt's K/V enters a slot (`StackedKV.insert`: split by
+kind, ring rows, int8 quantise, a stored head verbatim), what a stored
+head is (`PagedKVCache.head`, `head_kv`) and the attention of one new
+token over a layer (`LayerCacheView.attend`). The engine threads the
+state through its executables and a model hands `attend` its q, k, v;
+neither names an array of the cache.
+
+`LayerCacheView` is what a model's attention is handed for one layer
+inside a traced decode step: a layer index into ONE `StackedKV` carrier
+that all the step's views share. `attend` appends the step's K/V row to
+its layer of the stacked buffers, in place (a paged kernel aliases the
+cache to its output; the einsum fallback scatters one row a slot), and
+REPLACES the carrier's arrays with the updated ones. After the model has
+run, the carrier's arrays are the cache state the jitted function
 returns: nothing is sliced out of the stacked cache and nothing is
 stacked back. The carrier is a plain python holder of traced arrays
 scoped to one trace — nothing escapes it.
@@ -49,6 +58,7 @@ stack and its state is the three (or five) arrays it always was.
 """
 from __future__ import annotations
 
+import math
 import os
 from collections import OrderedDict
 from typing import Optional, Sequence, Tuple
@@ -56,7 +66,7 @@ from typing import Optional, Sequence, Tuple
 from ...observability import metrics
 
 __all__ = ["LayerCacheView", "PagedKVCache", "PrefixCache", "StackedKV",
-           "bucket_for", "dequantize_kv", "quantize_kv"]
+           "bucket_for", "dequantize_kv", "head_kv", "quantize_kv"]
 
 PREFIX_HITS = metrics.counter(
     "pt_prefix_cache_hits_total",
@@ -85,22 +95,35 @@ PREFIX_CACHE_BYTES_ENV = "PADDLE_TPU_PREFIX_CACHE_BYTES"
 _PREFIX_CACHE_DEFAULT = 256 << 20
 
 
-class StackedKV:
-    """The stacked cache arrays of one traced decode step.
+def _state_fields(quantized, ring):
+    """The arrays of a cache state, in the order the jitted steps thread
+    them (their parameter numbers): the scales MUST travel with the
+    values they decode, and a model with no window layer has the three
+    (or five) arrays it always had."""
+    if quantized:
+        return "k", "v", "k_scale", "v_scale", "lens"
+    if ring:
+        return "k", "v", "wk", "wv", "lens"
+    return "k", "v", "lens"
 
-    k/v: [n_layers, B, n_heads, max_seq_len, head_dim] (traced); lens:
-    int32 [B], each slot's length BEFORE this step's token. For a
+
+class StackedKV:
+    """The stacked cache arrays of one traced step.
+
+    k/v: [n_full_layers, B, n_heads, max_seq_len, head_dim] (traced);
+    lens: int32 [B], each slot's length BEFORE this step's token. For a
     quantized cache k/v are int8 and k_scale/v_scale carry the float32
     per-(layer, slot, head, token) scales [n_layers, B, n_heads,
     max_seq_len] (None otherwise). wk/wv: the window layers' rings
     [n_window_layers, B, n_heads, window, head_dim], None when the model
-    has none. Each layer's attention replaces the arrays with its
+    has none; `kinds` then says which of the model's layers they are.
+    `insert` and each layer's `attend` replace the arrays with their
     updated ones."""
 
-    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv")
+    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv", "kinds")
 
     def __init__(self, k, v, lens, k_scale=None, v_scale=None, wk=None,
-                 wv=None):
+                 wv=None, kinds=None):
         self.k = k
         self.v = v
         self.lens = lens
@@ -108,38 +131,169 @@ class StackedKV:
         self.v_scale = v_scale
         self.wk = wk
         self.wv = wv
+        self.kinds = kinds
+
+    def state(self, lens=None) -> Tuple:
+        """The flat tuple `PagedKVCache.carrier` took apart, holding the
+        arrays as they are now, with `lens` for the lengths if given."""
+        if lens is not None:
+            self.lens = lens
+        return tuple(getattr(self, f) for f in _state_fields(
+            self.k_scale is not None, self.wk is not None))
+
+    def insert(self, ks, vs, true_len, slot, offset=0, prefix=None):
+        """A prompt enters `slot`: ks/vs, a layer's fresh float K/V
+        [1, H, T', hd] each as `serving().prefill` returns them, are
+        written from row `offset` of the slot (quantized first when the
+        cache is int8), behind a stored head (`PagedKVCache.head`) put
+        back VERBATIM at row 0 when `prefix` is given, and the slot's
+        length becomes `true_len`. A window layer keeps the prompt's
+        last `window` rows, position p at row p mod window. Runs inside
+        a trace."""
+        import jax
+        import jax.numpy as jnp
+        kinds = self.kinds or ("full",) * len(ks)
+        upd = jax.lax.dynamic_update_slice
+        # the scope travels in the HLO's `op_name` metadata (HLO text,
+        # xprof's op profile) whatever XLA fuses the insert into; the
+        # fusions' instruction names do not change
+        with jax.named_scope("insert_kv"):
+            full = [i for i, kind in enumerate(kinds) if kind == "full"]
+            ring = [i for i, kind in enumerate(kinds) if kind == "window"]
+            fk = jnp.stack([ks[i] for i in full])      # [L_f,1,H,T',hd]
+            fv = jnp.stack([vs[i] for i in full])
+            if ring:
+                wk = jnp.stack([ks[i] for i in ring])
+                wv = jnp.stack([vs[i] for i in ring])
+                W, tb = self.wk.shape[3], wk.shape[3]
+                if tb > W:
+                    r = jnp.arange(W, dtype=jnp.int32)
+                    src = jnp.clip(r + W * ((true_len - 1 - r) // W),
+                                   0, tb - 1)
+                    wk = jnp.take(wk, src, axis=3)
+                    wv = jnp.take(wv, src, axis=3)
+            s, z = slot.astype(jnp.int32), jnp.int32(0)
+            o = jnp.int32(offset)
+            if self.k_scale is not None:
+                fk, k_sc = quantize_kv(fk)
+                fv, v_sc = quantize_kv(fv)
+                if prefix is not None:
+                    self.k_scale = upd(self.k_scale, prefix[2], (z, s, z, z))
+                    self.v_scale = upd(self.v_scale, prefix[3], (z, s, z, z))
+                self.k_scale = upd(self.k_scale, k_sc, (z, s, z, o))
+                self.v_scale = upd(self.v_scale, v_sc, (z, s, z, o))
+            if prefix is not None:
+                self.k = upd(self.k, prefix[0].astype(self.k.dtype),
+                             (z, s, z, z, z))
+                self.v = upd(self.v, prefix[1].astype(self.v.dtype),
+                             (z, s, z, z, z))
+            self.k = upd(self.k, fk.astype(self.k.dtype), (z, s, z, o, z))
+            self.v = upd(self.v, fv.astype(self.v.dtype), (z, s, z, o, z))
+            self.lens = upd(self.lens, jnp.reshape(true_len, (1,)), (s,))
+            if ring:
+                self.wk = upd(self.wk, wk.astype(self.wk.dtype),
+                              (z, s, z, z, z))
+                self.wv = upd(self.wv, wv.astype(self.wv.dtype),
+                              (z, s, z, z, z))
 
 
 class LayerCacheView:
     """One layer of the paged cache during a traced step: `layer` (a
     static int) into the `StackedKV` carrier `kv` that every view of the
-    step shares. `GPTAttention.forward` detects this type (duck-typed on
-    `.lens`), writes the incoming K/V at `(layer, slot, :, lens[slot])`
-    (quantizing on append), attends that layer over positions `<= lens`,
-    and stores the updated stacked buffers back on the carrier.
+    step shares. `kind`: "full" (rows of `kv.k/v`) or "window" (the ring
+    `kv.wk/wv`); `layer` counts within the stack of its kind. A model's
+    attention hands `attend` the step's q, k, v."""
 
-    `windows`: optional static tuple of attend-window lengths (the
-    engine passes its prefill buckets + max_seq_len, sorted). The
-    einsum fallback in models/gpt.py uses it to `lax.switch` onto the
-    smallest window covering max(lens)+1 instead of attending (and,
-    for int8, dequantizing) the full T_max buffer every step. None →
-    full-depth attention (legacy callers). Shapes stay static either
-    way — the traced lens picks a branch, never a shape.
+    __slots__ = ("kv", "layer", "kind")
 
-    `kind`: "full" (rows of `kv.k/v`) or "window" (the ring `kv.wk/wv`);
-    `layer` counts within the stack of its kind."""
-
-    __slots__ = ("kv", "layer", "windows", "kind")
-
-    def __init__(self, kv, layer, windows=None, kind="full"):
+    def __init__(self, kv, layer, kind="full"):
         self.kv = kv
         self.layer = int(layer)
-        self.windows = windows
         self.kind = kind
 
     @property
     def lens(self):
         return self.kv.lens
+
+    def attend(self, q, k, v):
+        """One new token a slot against this layer of the cache: q
+        [B, H_kv, G, hd] (G query heads share a key-value head; GPT has
+        G = 1), k, v [B, H_kv, 1, hd]; arrays in, array [B, H_kv, G, hd]
+        out. A full layer appends at row `lens` (a slot that hit the
+        wall rewrites its last row) and attends rows <= lens; a window
+        layer appends at `lens mod W` of its ring and attends the ring's
+        live rows, which are in no order and need none under a softmax.
+        The carrier's arrays are replaced by the updated ones.
+
+        The kernel is chosen from what can be seen here: G = 1 on a full
+        layer takes the work-list kernel (int8 rows too), anything else
+        the grouped-query kernel. Where the gate answers None (the CPU
+        without FLAGS_paged_flash_interpret, an ineligible shape), one
+        einsum over all the layer's rows, counter
+        pt_attn_path_total{path=xla_paged}. Either way shapes never
+        depend on traced values: decode compiles once."""
+        import jax.numpy as jnp
+        from ...ops import pallas_kernels as pk
+        kv, layer = self.kv, self.layer
+        ring = self.kind == "window"
+        kc, vc = (kv.wk, kv.wv) if ring else (kv.k, kv.v)
+        rows, lens = kc.shape[3], kv.lens
+        work_list = q.shape[2] == 1 and not ring
+        if work_list:
+            fused = pk.paged_decode_attention_or_none(
+                q, kc, vc, lens, k, v, kv.k_scale, kv.v_scale, layer=layer)
+            if fused is not None:
+                out, kv.k, kv.v, kv.k_scale, kv.v_scale = fused
+                return out.astype(q.dtype)
+        row = lens % rows if ring else jnp.minimum(lens, rows - 1)
+        live = jnp.minimum(lens + 1, rows)
+        fused = None if work_list else pk.paged_gqa_decode_or_none(
+            q, kc, vc, row, live, k, v, layer=layer)
+        if fused is None:
+            fused = self._einsum(q, k, v, kc, vc, row, live)
+        out, kc, vc = fused
+        if ring:
+            kv.wk, kv.wv = kc, vc
+        else:
+            kv.k, kv.v = kc, vc
+        return out
+
+    def _einsum(self, q, k, v, kc, vc, row, live):
+        """`attend` where no kernel runs: scatter the new row (and its
+        scales), then one masked float32 einsum over every row of the
+        layer. -> (out, kc', vc'); updated scales go to the carrier."""
+        import jax
+        import jax.numpy as jnp
+        from ...ops import pallas_kernels as pk
+        pk._note_attn_path("xla_paged")
+        kv, layer = self.kv, self.layer
+        slots = jnp.arange(row.shape[0])
+
+        def append(buf, new):
+            """buf[layer, b, :, row[b]] = new[b, :, 0] for every slot b."""
+            return buf.at[layer, slots, :, row].set(
+                new[:, :, 0].astype(buf.dtype))
+
+        quantized = kc.dtype == jnp.int8
+        if quantized:
+            k, k_sc = quantize_kv(k)       # int8 [B,H,1,hd] + f32 [B,H,1]
+            v, v_sc = quantize_kv(v)
+            kv.k_scale = append(kv.k_scale, k_sc)
+            kv.v_scale = append(kv.v_scale, v_sc)
+        kc, vc = append(kc, k), append(vc, v)
+        kf = kc[layer].astype(jnp.float32)
+        vf = vc[layer].astype(jnp.float32)
+        if quantized:
+            kf = kf * kv.k_scale[layer][..., None]
+            vf = vf * kv.v_scale[layer][..., None]
+        scores = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
+                            kf) * (1.0 / math.sqrt(q.shape[-1]))
+        ok = jnp.arange(kc.shape[3])[None, :] < live[:, None]   # [B, rows]
+        probs = jax.nn.softmax(
+            jnp.where(ok[:, None, None, :], scores, jnp.float32(-1e30)),
+            axis=-1)
+        out = jnp.einsum("bhgk,bhkd->bhgd", probs, vf)
+        return out.astype(q.dtype), kc, vc
 
 
 def bucket_for(length: int, buckets: Sequence[int]) -> int:
@@ -235,6 +389,7 @@ class PagedKVCache:
             self.wv = jnp.zeros(ring, store)
         else:
             self.wk = self.wv = None
+        self._fields = _state_fields(self.quantized, bool(n_window))
         for kind, n in self.nbytes_by_kind().items():
             KV_BYTES.labels(kind).set(n)
 
@@ -265,40 +420,69 @@ class PagedKVCache:
                 float(sum(min(n, self.window) for n in lengths)))
 
     def state(self) -> Tuple:
-        """Flat state tuple the jitted steps thread (and donate).
+        """Flat state tuple the jitted steps thread (and donate), in
+        `_state_fields`' order."""
+        return tuple(getattr(self, f) for f in self._fields)
 
-        Float: (k, v, lens). Quantized: (k, v, k_scale, v_scale, lens)
-        — the scales MUST travel with the values they decode. With
-        window layers: (k, v, wk, wv, lens)."""
-        if self.quantized:
-            return self.k, self.v, self.k_scale, self.v_scale, self.lens
-        if self.wk is not None:
-            return self.k, self.v, self.wk, self.wv, self.lens
-        return self.k, self.v, self.lens
-
-    def set_state(self, *state) -> None:
-        want = 5 if self.quantized or self.wk is not None else 3
-        if len(state) == 1 and isinstance(state[0], (tuple, list)):
-            state = tuple(state[0])
-        if len(state) != want:
+    def _checked(self, state) -> Tuple:
+        """`state` as a tuple, if it is a state of this cache: its
+        arity, and the type its values are stored in."""
+        state = tuple(state)
+        if len(state) != len(self._fields):
             raise ValueError(
-                "set_state expects %d arrays for kv_dtype=%s, got %d "
-                "(a quantized cache's scales must round-trip with it)"
-                % (want, self.kv_dtype, len(state)))
-        k, v = state[0], state[1]
-        for name, arr, ref in (("k", k, self.k), ("v", v, self.v)):
-            if str(arr.dtype) != str(ref.dtype):
+                "a state of this cache has %d arrays for kv_dtype=%s, got "
+                "%d (a quantized cache's scales must round-trip with it)"
+                % (len(self._fields), self.kv_dtype, len(state)))
+        for name, arr in zip("kv", state):
+            if str(arr.dtype) != str(self.k.dtype):
                 raise ValueError(
-                    "set_state %s dtype %s does not match this cache's "
+                    "state %s dtype %s does not match this cache's "
                     "kv_dtype=%s storage (%s); rebuild the cache instead "
                     "of mixing quantized and float states"
-                    % (name, arr.dtype, self.kv_dtype, ref.dtype))
+                    % (name, arr.dtype, self.kv_dtype, self.k.dtype))
+        return state
+
+    def carrier(self, state) -> StackedKV:
+        """A state tuple (this cache's own, or the traced one a jitted
+        step received) as one `StackedKV`."""
+        return StackedKV(kinds=self.layer_kinds,
+                         **dict(zip(self._fields, self._checked(state))))
+
+    def set_state(self, *state) -> None:
+        if len(state) == 1 and isinstance(state[0], (tuple, list)):
+            state = state[0]
+        for f, arr in zip(self._fields, self._checked(state)):
+            setattr(self, f, arr)
+
+    def views(self, carrier):
+        """A `LayerCacheView` a model layer, all on `carrier`."""
+        views = []
+        for i in range(self.n_layers):
+            kind, index = self.layer_index(i)
+            views.append(LayerCacheView(carrier, index, kind))
+        return views
+
+    def head(self, slot: int, n: int):
+        """The first `n` rows of `slot` in every layer, as new device
+        buffers (a later donation of the cache cannot invalidate them):
+        [k, v] of [n_layers, 1, n_heads, n, head_dim], then the two
+        scales when quantized. What `StackedKV.insert(prefix=)` puts
+        back and `head_kv` reads."""
+        s = int(slot)
+        arrays = [self.k[:, s:s + 1, :, :n, :], self.v[:, s:s + 1, :, :n, :]]
         if self.quantized:
-            self.k, self.v, self.k_scale, self.v_scale, self.lens = state
-        elif self.wk is not None:
-            self.k, self.v, self.wk, self.wv, self.lens = state
-        else:
-            self.k, self.v, self.lens = state
+            arrays += [self.k_scale[:, s:s + 1, :, :n],
+                       self.v_scale[:, s:s + 1, :, :n]]
+        return arrays
+
+
+def head_kv(head):
+    """(k, v) in float of a stored head (`PagedKVCache.head`): as they
+    are, or dequantized when the head carries its scales."""
+    if len(head) == 2:
+        return tuple(head)
+    k, v, k_scale, v_scale = head
+    return dequantize_kv(k, k_scale), dequantize_kv(v, v_scale)
 
 
 def prefix_cache_budget(explicit: Optional[int] = None) -> int:

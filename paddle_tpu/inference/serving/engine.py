@@ -22,24 +22,22 @@ loop. Three executable families cover all of decoding:
     the same [max_batch, 1] program; per-slot progress lives in the
     `lens` index vector (cache.py), never in shapes.
 
-With `kv_dtype="int8"` the quantize-on-append folds into the SAME
-executables: prefill/suffix quantize the freshly-computed K/V before
-the slot insert, decode quantizes the step's K/V inside
-`_paged_decode_attention` and dequantizes next to the matmul. The
-cache state a jitted step threads is then the 5-tuple
-(k, v, k_scale, v_scale, lens) instead of (k, v, lens) — shapes still
-never change, so decode still compiles exactly once. A cached prefix
-is re-inserted VERBATIM (int8 payload + its original scales), never
-dequantized-and-requantized, so a prefix hit is bit-identical to the
-cold path's cache contents.
+What the cache holds is `cache.py`'s alone: the engine threads
+`PagedKVCache.state()` through its executables as an opaque tuple (its
+shapes never change, so decode still compiles exactly once), turns it into a
+`StackedKV` carrier inside a trace, and asks the carrier to `insert` a
+prompt's K/V and the cache for the `views` a model's layers attend
+through (`LayerCacheView.attend`). A cached prefix is re-inserted
+VERBATIM (an int8 payload with its original scales), so a prefix hit is
+bit-identical to the cold path's cache contents.
 
 All executables are wrapped in `StepTelemetry`
 ("serve_prefill"/"serve_suffix"/"serve_decode") so
 `pt_jit_retraces_total` accounts the compile-once contract, and the
 engine additionally counts REAL jax traces (the python body runs once
 per trace) in `prefill_compiles`/`suffix_prefill_compiles`/
-`decode_compiles` — the numbers the tests and the SERVING_SMOKE gate
-assert on, immune to the telemetry kill-switch.
+`decode_compiles` — the numbers the tests assert on, immune to the
+telemetry kill-switch.
 
 The engine reads no attribute of any particular model. It calls
 `model.serving()` and asks the answer for what it needs:
@@ -65,12 +63,14 @@ of a GPT are what they were before the question was asked.
 
 Weights are functionalized exactly like jit/engine.py's eval step:
 parameter `_data` is swapped for traced inputs during the trace and
-restored in `finally`; at dispatch time weights pass as arguments, so
-many engines (server workers) can share one loaded model read-only.
-Cache buffers are donated — XLA updates the paged KV in place in HBM.
+restored in `finally` (`_traced`, the one place); at dispatch time
+weights pass as arguments (`_run`, the one place), so many engines
+(server workers) can share one loaded model read-only. Cache buffers are
+donated — XLA updates the paged KV in place in HBM.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
@@ -120,7 +120,7 @@ class GenerationEngine:
     into the compiled program's shape. The scheduler simply ignores
     tokens from slots it has not admitted.
 
-    `kv_dtype="int8"` swaps the paged cache for the quantized layout
+    `kv_dtype="int8"` swaps the paged cache for the int8 layout
     (~0.53x bf16 bytes at head_dim 64 — see cache.py); `prefix_cache`
     is the shared-prefix store (None disables reuse; byte budget from
     the `prefix_cache_bytes` arg or PADDLE_TPU_PREFIX_CACHE_BYTES).
@@ -184,14 +184,7 @@ class GenerationEngine:
             self._n_layers, self.max_batch, self._n_heads,
             self.max_seq_len, self._head_dim, kv_dtype=kv_dtype,
             layer_kinds=sv.layer_kinds, window=sv.window)
-        self._layer_index = [self.kv.layer_index(i)
-                             for i in range(self._n_layers)]
         self._last = jnp.zeros((self.max_batch, 1), jnp.int32)
-        # static attend windows for the einsum decode fallback: the
-        # prefill buckets + full depth, so short conversations pay for
-        # their bucket, not for max_seq_len (models/gpt.py lax.switch)
-        self._decode_windows = tuple(sorted(
-            set(self.buckets) | {self.max_seq_len}))
 
         budget = cache_mod.prefix_cache_budget(prefix_cache_bytes) \
             if sv.prefix_cache else 0
@@ -212,105 +205,13 @@ class GenerationEngine:
         # shape pair — counted in _traces["suffix"], never in "prefill"
         self._jit_suffix = jax.jit(self._suffix_fn, donate_argnums=(3, 4))
 
-    # -- cache-state plumbing ----------------------------------------------
-
-    def _carrier(self, cache):
-        """The flat state tuple a jitted step received (see
-        PagedKVCache.state) as one StackedKV."""
-        if self.kv.quantized:
-            kc, vc, ksc, vsc, lens = cache
-            return cache_mod.StackedKV(kc, vc, lens, ksc, vsc)
-        if self.kv.wk is not None:
-            kc, vc, wk, wv, lens = cache
-            return cache_mod.StackedKV(kc, vc, lens, wk=wk, wv=wv)
-        kc, vc, lens = cache
-        return cache_mod.StackedKV(kc, vc, lens)
-
-    def _state_of(self, kv, lens):
-        if self.kv.quantized:
-            return kv.k, kv.v, kv.k_scale, kv.v_scale, lens
-        if self.kv.wk is not None:
-            return kv.k, kv.v, kv.wk, kv.wv, lens
-        return kv.k, kv.v, lens
-
-    def _split_kinds(self, ks, vs, tl):
-        """A layer's fresh K/V [1, H, Tb, hd] each -> (full layers
-        stacked [L_f, 1, H, Tb, hd] x 2, ring rows of the window layers
-        [L_w, 1, H, min(Tb, W), hd] x 2 or None). A prompt longer than
-        the window leaves its last W rows, position p at row p mod W."""
-        import jax.numpy as jnp
-        kinds = self.kv.layer_kinds
-        full = [i for i, k in enumerate(kinds) if k == "full"]
-        ring = [i for i, k in enumerate(kinds) if k == "window"]
-        fk = jnp.stack([ks[i] for i in full])
-        fv = jnp.stack([vs[i] for i in full])
-        if not ring:
-            return fk, fv, None
-        wk = jnp.stack([ks[i] for i in ring])
-        wv = jnp.stack([vs[i] for i in ring])
-        W, tb = self.kv.window, wk.shape[3]
-        if tb > W:
-            r = jnp.arange(W, dtype=jnp.int32)
-            src = jnp.clip(r + W * ((tl - 1 - r) // W), 0, tb - 1)
-            wk, wv = jnp.take(wk, src, axis=3), jnp.take(wv, src, axis=3)
-        return fk, fv, (wk, wv)
-
-    def _insert_kv(self, cache, ks, vs, tl, slot, offset=0,
-                   prefix=None, ring=None):
-        """Write freshly-computed float K/V [L,1,nh,T',hd] (quantizing
-        first when the cache is int8) into `cache` at (slot, offset),
-        optionally preceded by a VERBATIM stored prefix at offset 0,
-        and set the slot's length to `tl`. `ring`: the window layers'
-        rows (`_split_kinds`), written from row 0 of the slot's rings.
-        Runs inside a trace."""
-        import jax
-        import jax.numpy as jnp
-        # the scope travels in the HLO's `op_name` metadata (HLO text,
-        # xprof's op profile) whatever XLA fuses the insert into; the
-        # fusions' instruction names do not change
-        with jax.named_scope("insert_kv"):
-            kv = self._carrier(cache)
-            kc, vc, ksc, vsc, lens = kv.k, kv.v, kv.k_scale, kv.v_scale, \
-                kv.lens
-            s, z = slot.astype(jnp.int32), jnp.int32(0)
-            o = jnp.int32(offset)
-            if self.kv.quantized:
-                ks, ks_sc = cache_mod.quantize_kv(ks)
-                vs, vs_sc = cache_mod.quantize_kv(vs)
-                if prefix is not None:
-                    pk, pv, pks, pvs = prefix
-                    ksc = jax.lax.dynamic_update_slice(ksc, pks, (z, s, z, z))
-                    vsc = jax.lax.dynamic_update_slice(vsc, pvs, (z, s, z, z))
-                ksc = jax.lax.dynamic_update_slice(ksc, ks_sc, (z, s, z, o))
-                vsc = jax.lax.dynamic_update_slice(vsc, vs_sc, (z, s, z, o))
-            elif prefix is not None:
-                pk, pv = prefix
-            if prefix is not None:
-                kc = jax.lax.dynamic_update_slice(
-                    kc, pk.astype(kc.dtype), (z, s, z, z, z))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, pv.astype(vc.dtype), (z, s, z, z, z))
-            kc = jax.lax.dynamic_update_slice(
-                kc, ks.astype(kc.dtype), (z, s, z, o, z))
-            vc = jax.lax.dynamic_update_slice(
-                vc, vs.astype(vc.dtype), (z, s, z, o, z))
-            lens = jax.lax.dynamic_update_slice(
-                lens, jnp.reshape(tl, (1,)), (s,))
-            kv.k, kv.v, kv.k_scale, kv.v_scale = kc, vc, ksc, vsc
-            if ring is not None:
-                kv.wk = jax.lax.dynamic_update_slice(
-                    kv.wk, ring[0].astype(kv.wk.dtype), (z, s, z, z, z))
-                kv.wv = jax.lax.dynamic_update_slice(
-                    kv.wv, ring[1].astype(kv.wv.dtype), (z, s, z, z, z))
-            return self._state_of(kv, lens)
-
     # -- traced bodies ----------------------------------------------------
 
-    def _prefill_fn(self, arrs, buf_arrs, key, cache, last,
-                    ids, true_len, slot):
-        import jax
-        import jax.numpy as jnp
-        self._traces["prefill"] += 1
+    @contextlib.contextmanager
+    def _traced(self, arrs, buf_arrs, key):
+        """The inside of a trace: the model's weights, its buffers and
+        the RNG key are the traced arguments and the three guards are
+        entered; what was there comes back on the way out."""
         saved = [m._data for m in self._mutable]
         saved_key = RNG.key
         try:
@@ -319,20 +220,37 @@ class GenerationEngine:
             for b, a in zip(self._buffers, buf_arrs):
                 b._data = a
             RNG.key = key
-            tl = true_len.astype(jnp.int32)
             with state.trace_guard(), state.no_grad_guard(), \
                     state.mesh_guard(None):
-                logits, ks, vs, stats = self._sv.prefill(ids, tl)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            ks, vs, ring = self._split_kinds(ks, vs, tl)  # [L,1,nh,Tb,hd]
-            cache = self._insert_kv(cache, ks, vs, tl, slot, ring=ring)
-            s, z = slot.astype(jnp.int32), jnp.int32(0)
-            last = jax.lax.dynamic_update_slice(last, tok, (s, z))
-            return (cache, last, tok, RNG.key) + self._packed(tok, stats)
+                yield
         finally:
             for m, a in zip(self._mutable, saved):
                 m._data = a
             RNG.key = saved_key
+
+    def _admitted(self, cache, last, logits, ks, vs, tl, slot, offset=0,
+                  prefix=None):
+        """The tail of both prefills: the first token, the prompt's K/V
+        into the slot (`StackedKV.insert`), the token into `last`."""
+        import jax
+        import jax.numpy as jnp
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        kv = self.kv.carrier(cache)
+        kv.insert(ks, vs, tl, slot, offset=offset, prefix=prefix)
+        s, z = slot.astype(jnp.int32), jnp.int32(0)
+        last = jax.lax.dynamic_update_slice(last, tok, (s, z))
+        return kv.state(), last, tok, RNG.key
+
+    def _prefill_fn(self, arrs, buf_arrs, key, cache, last,
+                    ids, true_len, slot):
+        import jax.numpy as jnp
+        self._traces["prefill"] += 1
+        with self._traced(arrs, buf_arrs, key):
+            tl = true_len.astype(jnp.int32)
+            logits, ks, vs, stats = self._sv.prefill(ids, tl)
+            cache, last, tok, rng = self._admitted(
+                cache, last, logits, ks, vs, tl, slot)
+            return (cache, last, tok, rng) + self._packed(tok, stats)
 
     def _suffix_fn(self, arrs, buf_arrs, key, cache, last, prefix,
                    ids, true_len, slot):
@@ -344,76 +262,35 @@ class GenerationEngine:
         prefix_len is static (baked from the prefix arrays' shape), so
         each (prefix bucket, suffix bucket) pair is its own executable.
         """
-        import jax
         import jax.numpy as jnp
         self._traces["suffix"] += 1
         p = int(prefix[0].shape[3])
-        saved = [m._data for m in self._mutable]
-        saved_key = RNG.key
-        try:
-            for m, a in zip(self._weights, arrs):
-                m._data = a
-            for b, a in zip(self._buffers, buf_arrs):
-                b._data = a
-            RNG.key = key
-            if self.kv.quantized:
-                pk, pv, pks, pvs = prefix
-                pkf = cache_mod.dequantize_kv(pk, pks)
-                pvf = cache_mod.dequantize_kv(pv, pvs)
-            else:
-                pk, pv = prefix
-                pkf, pvf = pk, pv
+        with self._traced(arrs, buf_arrs, key):
+            stored = cache_mod.head_kv(prefix)
             tl = true_len.astype(jnp.int32)
-            with state.trace_guard(), state.no_grad_guard(), \
-                    state.mesh_guard(None):
-                # the model runs ONLY the suffix (its true last row sits
-                # at total_len - prefix_len - 1) over the stored prefix
-                logits, ks, vs, _ = self._sv.prefill(
-                    ids, tl - jnp.int32(p), prefix=(pkf, pvf))
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # the model runs ONLY the suffix (its true last row sits
+            # at total_len - prefix_len - 1) over the stored prefix
+            logits, ks, vs, _ = self._sv.prefill(
+                ids, tl - jnp.int32(p), prefix=stored)
             # ks/vs are prefix+suffix concats; keep only the fresh suffix —
             # the stored prefix is re-inserted untouched (for int8 that
             # means NO dequantize->requantize round trip on a hit)
-            ks = jnp.stack([k[:, :, p:, :] for k in ks])
-            vs = jnp.stack([v[:, :, p:, :] for v in vs])
-            cache = self._insert_kv(cache, ks, vs, tl, slot,
-                                    offset=p, prefix=prefix)
-            s, z = slot.astype(jnp.int32), jnp.int32(0)
-            last = jax.lax.dynamic_update_slice(last, tok, (s, z))
-            return cache, last, tok, RNG.key
-        finally:
-            for m, a in zip(self._mutable, saved):
-                m._data = a
-            RNG.key = saved_key
+            return self._admitted(
+                cache, last, logits, [k[:, :, p:, :] for k in ks],
+                [v[:, :, p:, :] for v in vs], tl, slot, offset=p,
+                prefix=prefix)
 
     def _decode_fn(self, arrs, buf_arrs, key, cache, last):
         import jax.numpy as jnp
         self._traces["decode"] += 1
-        saved = [m._data for m in self._mutable]
-        saved_key = RNG.key
-        try:
-            for m, a in zip(self._weights, arrs):
-                m._data = a
-            for b, a in zip(self._buffers, buf_arrs):
-                b._data = a
-            RNG.key = key
+        with self._traced(arrs, buf_arrs, key):
             # one carrier of the stacked arrays; each layer's attention
             # appends its row in place and leaves the updated arrays here
-            kv = self._carrier(cache)
-            views = [cache_mod.LayerCacheView(
-                        kv, i, windows=self._decode_windows, kind=kind)
-                     for kind, i in self._layer_index]
-            with state.trace_guard(), state.no_grad_guard(), \
-                    state.mesh_guard(None):
-                logits, stats = self._sv.decode(last, views)
+            kv = self.kv.carrier(cache)
+            logits, stats = self._sv.decode(last, self.kv.views(kv))
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             lens = jnp.minimum(kv.lens + 1, jnp.int32(self.max_seq_len))
-            return (self._state_of(kv, lens), tok, RNG.key) \
-                + self._packed(tok, stats)
-        finally:
-            for m, a in zip(self._mutable, saved):
-                m._data = a
-            RNG.key = saved_key
+            return (kv.state(lens), tok, RNG.key) + self._packed(tok, stats)
 
     @staticmethod
     def _packed(tok, stats):
@@ -446,6 +323,33 @@ class GenerationEngine:
                 return b
         return None
 
+    def _run(self, jitted, tel, key, gap, *args):
+        """Dispatch one program: the weights, the buffers, the RNG key,
+        the cache state and `last` as arguments, then `args`. Under the
+        dispatch lock the program is enqueued inside `tel`'s step `key`
+        (an out-of-memory error leaves its forensics), the host's `gap`
+        span is closed, and the key, the cache state and `last` are the
+        program's. -> (the token array, the packed array or None)."""
+        with _DISPATCH_LOCK:
+            try:
+                with tel.step(key):
+                    kvstate, *out = jitted(
+                        [p._data for p in self._weights],
+                        [b._data for b in self._buffers], RNG.key,
+                        self.kv.state(), self._last, *args)
+            except Exception as e:
+                if memprof.is_oom(e):
+                    memprof.on_oom(tel.engine, e)
+                raise
+            self._enqueued(gap)
+            if jitted is self._jit_decode:   # its tokens ARE the next `last`
+                tok, RNG.key, *packed = out
+                self._last = tok
+            else:
+                self._last, tok, RNG.key, *packed = out
+            self.kv.set_state(kvstate)
+        return tok, (packed[0] if packed else None)
+
     def prefill(self, slot: int, prompt) -> int:
         """Admit a prompt into `slot`; returns its first generated token.
 
@@ -460,109 +364,54 @@ class GenerationEngine:
             raise ValueError("empty prompt")
         if not 0 <= slot < self.max_batch:
             raise ValueError("slot %d out of range" % slot)
-        reused, entry, sb = 0, None, None
+        reused, entry, b = 0, None, None
         if self.prefix_cache is not None:
             reused, entry = self.prefix_cache.lookup(prompt)
             if entry is not None:
-                sb = self._suffix_bucket(n - reused, reused)
-                if sb is None:
+                b = self._suffix_bucket(n - reused, reused)
+                if b is None:
                     reused, entry = 0, None
-        if entry is not None:
-            tok = self._suffix_prefill(slot, prompt, n, reused, entry, sb)
-            self.admit_info = {"prefix_len": reused, "bucket": sb}
-            return tok
-        b = self.bucket_for(n)
+        if entry is None:
+            b = self.bucket_for(n)
         padded = np.full((1, b), self.pad_id, np.int32)
-        padded[0, :n] = prompt
+        padded[0, :n - reused] = prompt[reused:]
         self.bucket_hits[b] += 1
         PREFILL_BUCKET_HITS.labels(str(b)).inc()
-        with _DISPATCH_LOCK:
-            try:
-                with self._prefill_tel.step(("prefill", b)):
-                    kvstate, last, tok, key, *packed = self._jit_prefill(
-                        [p._data for p in self._weights],
-                        [bf._data for bf in self._buffers], RNG.key,
-                        self.kv.state(), self._last,
-                        padded, np.int32(n), np.int32(slot))
-            except Exception as e:
-                if memprof.is_oom(e):
-                    memprof.on_oom("serve_prefill", e)
-                raise
-            self._enqueued("host_gap_prefill")
-            RNG.key = key
-            self.kv.set_state(kvstate)
-            self._last = last
+        args = (padded, np.int32(n), np.int32(slot))
+        if entry is not None:
+            tok, packed = self._run(
+                self._jit_suffix, self._suffix_tel, ("suffix", reused, b),
+                "host_gap_prefill", entry, *args)
+        else:
+            tok, packed = self._run(
+                self._jit_prefill, self._prefill_tel, ("prefill", b),
+                "host_gap_prefill", *args)
             if self.prefix_cache is not None:
                 self._store_prefix(prompt, n, slot)
-        self.admit_info = {"prefix_len": 0, "bucket": b}
-        if packed:
-            out = self._fetch(packed[0])
+        self.admit_info = {"prefix_len": reused, "bucket": b}
+        if packed is not None:
+            out = self._fetch(packed)
             self._observe_moe(out[1:], b)
             return int(out[0])
         return int(self._fetch(tok)[0, 0])
 
-    def _suffix_prefill(self, slot, prompt, n, p, entry, sb) -> int:
-        padded = np.full((1, sb), self.pad_id, np.int32)
-        padded[0, :n - p] = prompt[p:]
-        self.bucket_hits[sb] += 1
-        PREFILL_BUCKET_HITS.labels(str(sb)).inc()
-        with _DISPATCH_LOCK:
-            try:
-                with self._suffix_tel.step(("suffix", p, sb)):
-                    kvstate, last, tok, key = self._jit_suffix(
-                        [w._data for w in self._weights],
-                        [bf._data for bf in self._buffers], RNG.key,
-                        self.kv.state(), self._last, entry,
-                        padded, np.int32(n), np.int32(slot))
-            except Exception as e:
-                if memprof.is_oom(e):
-                    memprof.on_oom("serve_suffix", e)
-                raise
-            self._enqueued("host_gap_prefill")
-            RNG.key = key
-            self.kv.set_state(kvstate)
-            self._last = last
-        return int(self._fetch(tok)[0, 0])
-
     def _store_prefix(self, prompt, n: int, slot: int) -> None:
         """Harvest the slot's freshly-prefilled K/V head (largest bucket
-        <= prompt length) and admit it to the PrefixCache. The slices
-        materialize NEW device buffers, so later donations of the paged
-        cache can't invalidate a stored prefix. Called under the
-        dispatch lock, right after set_state."""
+        <= prompt length) and admit it to the PrefixCache."""
         p_store = 0
         for b in self.buckets:
             if b <= n:
                 p_store = b
-        if not p_store:
-            return
-        s = int(slot)
-        arrays = [self.kv.k[:, s:s + 1, :, :p_store, :],
-                  self.kv.v[:, s:s + 1, :, :p_store, :]]
-        if self.kv.quantized:
-            arrays += [self.kv.k_scale[:, s:s + 1, :, :p_store],
-                       self.kv.v_scale[:, s:s + 1, :, :p_store]]
-        self.prefix_cache.store(prompt[:p_store], arrays)
+        if p_store:
+            self.prefix_cache.store(prompt[:p_store],
+                                    self.kv.head(slot, p_store))
 
     def decode(self) -> np.ndarray:
         """One decode step for the whole batch; next token per slot."""
-        with _DISPATCH_LOCK:
-            try:
-                with self._decode_tel.step("decode"):
-                    kvstate, tok, key, *packed = self._jit_decode(
-                        [p._data for p in self._weights],
-                        [bf._data for bf in self._buffers], RNG.key,
-                        self.kv.state(), self._last)
-            except Exception as e:
-                if memprof.is_oom(e):
-                    memprof.on_oom("serve_decode", e)
-                raise
-            self._enqueued("host_gap_decode")
-            RNG.key = key
-            self.kv.set_state(kvstate)
-            self._last = tok
-        if packed:
-            out = self._fetch(packed[0])
+        tok, packed = self._run(self._jit_decode, self._decode_tel, "decode",
+                                "host_gap_decode")
+        if packed is not None:
+            out = self._fetch(packed)
             self._observe_moe(out[self.max_batch:], self.max_batch)
             return out[:self.max_batch]
         return self._fetch(tok).reshape(-1)

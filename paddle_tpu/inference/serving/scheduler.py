@@ -5,8 +5,8 @@ of `max_batch` slots; a finished sequence frees its slot at the end of
 the step and a queued request is admitted into it on the next step via
 one bucketed prefill — the batch stays full instead of draining to the
 slowest straggler. `admit_mid_flight=False` degrades to classic static
-batching (fill the batch, run it to empty, repeat), kept as the
-baseline arm of the bench comparison in benchmarks/inference_bench.py.
+batching (fill the batch, run it to empty, repeat), the baseline
+tests/test_serving.py compares against.
 
 All decode dispatches cost the same wall time regardless of how many
 slots are live (the compiled program is shape-fixed), so throughput is

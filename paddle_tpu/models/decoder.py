@@ -197,42 +197,9 @@ def band_attention(q, k, v, window):
 
 def paged_attention(q, k, v, view):
     """One new token a slot against the paged cache: q [B, Hkv, G, hd],
-    k, v [B, Hkv, 1, hd]; `view` (serving/cache.LayerCacheView) names a
-    layer of the step's carrier. A full layer appends at row `lens` and
-    attends rows <= lens; a window layer appends at `lens mod W` of its
-    ring and attends the ring's live rows. The carrier's arrays are
-    replaced by the updated ones (the kernel aliases them; the fallback
-    scatters one row a slot)."""
-    from ..ops import pallas_kernels as pk
-    kv, layer = view.kv, view.layer
-    ring = view.kind == "window"
-    kc, vc = (kv.wk, kv.wv) if ring else (kv.k, kv.v)
-    rows = kc.shape[3]
-    lens = kv.lens
-    if ring:
-        row, live = lens % rows, jnp.minimum(lens + 1, rows)
-    else:
-        row = jnp.minimum(lens, rows - 1)
-        live = jnp.minimum(lens + 1, rows)
-    fused = pk.paged_gqa_decode_or_none(q, kc, vc, row, live, k, v,
-                                        layer=layer)
-    if fused is not None:
-        out, kc, vc = fused
-    else:
-        slots = jnp.arange(lens.shape[0])
-        kc = kc.at[layer, slots, :, row].set(k[:, :, 0].astype(kc.dtype))
-        vc = vc.at[layer, slots, :, row].set(v[:, :, 0].astype(vc.dtype))
-        s = jnp.einsum("bkgd,bksd->bkgs", q.astype(F32),
-                       kc[layer].astype(F32)) / math.sqrt(q.shape[-1])
-        ok = jnp.arange(rows)[None, :] < live[:, None]          # [B, rows]
-        p = jax.nn.softmax(jnp.where(ok[:, None, None, :], s, _NEG), -1)
-        out = jnp.einsum("bkgs,bksd->bkgd", p,
-                         vc[layer].astype(F32)).astype(q.dtype)
-    if ring:
-        kv.wk, kv.wv = kc, vc
-    else:
-        kv.k, kv.v = kc, vc
-    return out
+    k, v [B, Hkv, 1, hd] through `view` (serving/cache.LayerCacheView),
+    which appends, attends and leaves the carrier updated."""
+    return view.attend(q, k, v)
 
 
 def _attention(cfg, i, p, h, pos, view=None):
@@ -477,6 +444,10 @@ class _Serving:
         self.max_positions = cfg.max_positions
         self.moe_layers = cfg.mlp_kinds.count("moe")
         self.selfchecks = ("paged_gqa", "band_flash")
+        if cfg.num_heads == cfg.num_kv_heads and "full" in cfg.layer_kinds:
+            # one query head a key-value head: a full layer's decode
+            # takes GPT's kernel (serving/cache.LayerCacheView.attend)
+            self.selfchecks += ("paged",)
         self.moe_top_k = cfg.moe.top_k if cfg.moe else 0
         self.moe_experts = cfg.moe.num_experts if cfg.moe else 0
 

@@ -84,102 +84,6 @@ class GPTEmbeddings(Layer):
         return constrain(self.dropout(x), _seq_spec())
 
 
-def _paged_decode_attention(q, k, v, view):
-    """Single-token attention against a static-shape paged KV cache.
-
-    q/k/v: [B, nh, 1, hd]; view (inference/serving/cache.LayerCacheView)
-    names a layer of the carrier `view.kv`, whose stacked k/v buffers
-    [L, B, nh, T_max, hd] + per-slot lengths int32 [B] every layer of the
-    step shares. Either path appends this layer's row to the stacked
-    buffers in place and leaves the updated buffers on the carrier.
-
-    Fast path — the fused Pallas megakernel
-    (ops/pallas_kernels.paged_decode_attention_or_none): one launch per
-    layer whose grid walks the (slot, key block) pairs that hold a live
-    row and no others, every head of a slot in one step, with the
-    new-token append (incl. int8 quantize) and the k_scale/v_scale
-    dequant folded in, so a call's steps and its HBM traffic scale with
-    the live lengths rather than with cache capacity. Counter
-    pt_attn_path_total{path=paged_flash}.
-
-    Fallback (flag off / ineligible shape / unhealthy Mosaic / CPU) —
-    the windowed XLA einsum, counter {path=xla_paged}: the new K/V is
-    scattered to `(layer, slot, :, lens[slot], :)` of the stacked
-    buffer, then attention runs over a STATIC window of that layer
-    chosen by `lax.switch` from view.windows (the serving prefill
-    buckets + T_max): the smallest bucket covering max(lens)+1. Each
-    branch slices, dequantizes (int8) and attends that window only, so
-    even the non-Pallas path stops paying O(T_max) dequant+attend per
-    token while remaining one compiled program. A view without
-    `windows` attends full T_max (legacy callers). Both paths keep the
-    decode-compiles-once contract: shapes never depend on traced values.
-    """
-    import jax
-    import jax.numpy as jnp
-    qa, ka, va = q._data, k._data, v._data
-    kv, layer = view.kv, view.layer
-    lens = kv.lens
-    from ..ops import pallas_kernels as pk
-    fused = pk.paged_decode_attention_or_none(
-        qa, kv.k, kv.v, lens, ka, va, kv.k_scale, kv.v_scale, layer=layer)
-    if fused is not None:
-        out, kv.k, kv.v, kv.k_scale, kv.v_scale = fused
-        return Tensor(out.astype(qa.dtype), _internal=True)
-    pk._note_attn_path("xla_paged")
-
-    t_max = kv.k.shape[3]
-    slots = jnp.arange(lens.shape[0])
-    # a slot that hit the wall rewrites its last row, as the kernel does
-    row = jnp.minimum(lens, t_max - 1).astype(jnp.int32)
-
-    def _append(buf, new):
-        """buf[layer, b, :, row[b]] = new[b, :, 0] for every slot b."""
-        return buf.at[layer, slots, :, row].set(new[:, :, 0].astype(
-            buf.dtype))
-
-    quantized = kv.k_scale is not None
-    if quantized:
-        from ..inference.serving.cache import quantize_kv
-        ka, k_sc = quantize_kv(ka)      # int8 [B,nh,1,hd] + f32 [B,nh,1]
-        va, v_sc = quantize_kv(va)
-        kv.k_scale = _append(kv.k_scale, k_sc)
-        kv.v_scale = _append(kv.v_scale, v_sc)
-    kv.k = _append(kv.k, ka)
-    kv.v = _append(kv.v, va)
-    kc, vc, ksc, vsc = kv.k, kv.v, kv.k_scale, kv.v_scale
-    scale = 1.0 / math.sqrt(qa.shape[-1])
-
-    def _attend(w):
-        """Attend the first `w` (static) cache positions of the layer."""
-        kf = kc[layer, :, :, :w].astype(jnp.float32)
-        vf = vc[layer, :, :, :w].astype(jnp.float32)
-        if quantized:
-            kf = kf * ksc[layer, :, :, :w, None]
-            vf = vf * vsc[layer, :, :, :w, None]
-        scores = jnp.einsum("bhqd,bhkd->bhqk", qa.astype(jnp.float32),
-                            kf) * scale
-        # freshly written token sits AT index lens -> keep pos <= lens
-        valid = (jnp.arange(w)[None, None, None, :]
-                 <= lens[:, None, None, None])
-        scores = jnp.where(valid, scores, jnp.float32(-1e30))
-        probs = jax.nn.softmax(scores, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs, vf)
-
-    windows = getattr(view, "windows", None)
-    if not windows or tuple(windows) == (t_max,):
-        out = _attend(t_max)
-    else:
-        windows = tuple(int(w) for w in windows)
-        # smallest window covering every live slot + the appended token;
-        # traced value selects a branch, never a shape
-        need = jnp.minimum(jnp.max(lens) + 1, t_max)
-        idx = jnp.searchsorted(jnp.asarray(windows, jnp.int32), need,
-                               side="left")
-        out = jax.lax.switch(
-            idx, [lambda w=w: _attend(w) for w in windows])
-    return Tensor(out.astype(qa.dtype), _internal=True)
-
-
 def _prefix_concat_attention(q, k, v, prefix_len):
     """Suffix-prefill attention: Tq suffix queries over prefix+suffix keys.
 
@@ -236,10 +140,10 @@ class GPTAttention(Layer):
         qkv = qkv.transpose((2, 0, 3, 1, 4))        # [3, B, nh, T, hd]
         q, k, v = qkv[0], qkv[1], qkv[2]
         if cache is not None and hasattr(cache, "lens"):
-            # serving path: static-shape paged KV cache (LayerCacheView,
-            # inference/serving/cache.py). T == 1; the write lands at each
-            # slot's length index, so the step's shapes never change.
-            out = _paged_decode_attention(q, k, v, cache)
+            # serving path: one new token a slot through the paged cache
+            # (inference/serving/cache.LayerCacheView.attend; T == 1)
+            out = Tensor(cache.attend(q._data, k._data, v._data),
+                         _internal=True)
             out = out.transpose((0, 2, 1, 3)).reshape(
                 (B, T, self.hidden_size))
             return self.out_proj(out), cache

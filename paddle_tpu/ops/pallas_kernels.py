@@ -45,9 +45,9 @@ _LANES = 128
 # representative shape and are compared with their dense XLA oracles. A
 # kernel that fails to lower, compile or match RAISES — nothing here turns
 # such an error into an XLA path. The only way to turn a kernel off is its
-# flag (FLAGS_use_flash_attention, FLAGS_paged_flash_decode,
+# flag, where it has one (FLAGS_use_flash_attention,
 # FLAGS_use_fused_optimizer, FLAGS_fused_block); a kernel whose flag is off
-# is not checked.
+# is not checked. The paged-decode kernels have none: on the TPU they run.
 # ---------------------------------------------------------------------------
 
 
@@ -144,9 +144,9 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
             checks.append(("flash_dropout", _check_flash_dropout))
         if "band_flash" in needs:
             checks.append(("band_flash", _check_band_flash))
-    if needs_paged and flag("paged_flash_decode"):
+    if needs_paged:
         checks.append(("paged", _check_paged))
-    if "paged_gqa" in needs and flag("paged_flash_decode"):
+    if "paged_gqa" in needs:
         checks.append(("paged_gqa", _check_paged_gqa))
     for name, check in checks:
         if name not in _SELFCHECKED:
@@ -1533,9 +1533,10 @@ def flash_attention_or_none(query, key, value, attn_mask, is_causal,
 # from a slot's length on are whatever was there before (zeros, or a
 # previous tenant's rows) and are masked out of every read.
 #
-# Dispatch: paged_decode_attention_or_none (flag, shape legality,
-# FLAGS_paged_flash_interpret for the CPU emulator). Flag off or an
-# ineligible shape takes models/gpt.py's windowed einsum
+# Dispatch: paged_decode_attention_or_none (shape legality,
+# FLAGS_paged_flash_interpret for the CPU emulator). An ineligible shape,
+# or the CPU without the emulator, takes the einsum of
+# inference/serving/cache.LayerCacheView.attend
 # (pt_attn_path_total{path=xla_paged}).
 # ---------------------------------------------------------------------------
 
@@ -1868,18 +1869,16 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
                                    layer):
     """Gate + dispatch for the fused paged-decode attention kernel.
 
-    Arrays only (the Tensor-level caller is models/gpt.py's
-    _paged_decode_attention): q/new_k/new_v [B, H, 1, D], the STACKED
+    Arrays only (the caller is inference/serving/cache.LayerCacheView.
+    attend): q/new_k/new_v [B, H, 1, D], the STACKED
     caches [L, B, H, T, D] (+ scales [L, B, H, T] for int8), `layer` the
     layer this call attends and appends to, lens [B] int32 = live length
     per slot BEFORE this token. Returns (out, k_cache', v_cache',
     k_scale', v_scale') — the stacked cache updated in place, carrying
-    the appended token — or None when the caller must take the windowed
-    einsum (flag off, ineligible shape, or interpret mode without
+    the appended token — or None when the caller must take its einsum
+    (ineligible shape, or interpret mode without
     FLAGS_paged_flash_interpret). Bumps
     pt_attn_path_total{path=paged_flash} at trace time when it fires."""
-    if not flag("paged_flash_decode"):
-        return None
     if q.ndim != 4 or q.shape[2] != 1 or k_cache.ndim != 5:
         return None
     B, H, _, D = q.shape
@@ -2057,9 +2056,9 @@ def _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
 def paged_gqa_decode_or_none(q, k_cache, v_cache, row, live, new_k, new_v,
                              *, layer):
     """Gate + dispatch of the grouped-query paged decode kernel; None when
-    the caller must take its einsum (flag off, ineligible shape, or the
-    emulator without FLAGS_paged_flash_interpret)."""
-    if not flag("paged_flash_decode") or q.ndim != 4 or k_cache.ndim != 5:
+    the caller must take its einsum (ineligible shape, or the emulator
+    without FLAGS_paged_flash_interpret)."""
+    if q.ndim != 4 or k_cache.ndim != 5:
         return None
     B, H, G, D = q.shape
     interpret = jax.default_backend() != "tpu"

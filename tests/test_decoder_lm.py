@@ -21,8 +21,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks", "perf"))
 import reference_afmoe as ref                                  # noqa: E402
 from paddle_tpu.framework.flags import set_flags               # noqa: E402
 from paddle_tpu.incubate import moe as moe_ops                 # noqa: E402
-from paddle_tpu.inference.serving.cache import (              # noqa: E402
-    LayerCacheView, PagedKVCache)
+from paddle_tpu.inference.serving.cache import PagedKVCache    # noqa: E402
 from paddle_tpu.inference.serving.engine import GenerationEngine  # noqa: E402
 from paddle_tpu.models import decoder as dec                   # noqa: E402
 from paddle_tpu.ops import pallas_kernels as pk                # noqa: E402
@@ -117,21 +116,19 @@ def test_prefill_then_decode_gives_the_reference_logits_at_every_position(
         ids[0, :n] = seqs[slot, :n]
         logits, ks, vs, _ = net.run(jnp.asarray(ids))
         assert np.abs(np.asarray(logits)[0, :n] - want[slot][:n]).max() < TOL
-        tl = jnp.int32(n)
-        fk, fv, ring = e._split_kinds(ks, vs, tl)
-        cache = e._insert_kv(cache, fk, fv, tl, jnp.int32(slot), ring=ring)
+        kv = e.kv.carrier(cache)
+        kv.insert(ks, vs, jnp.int32(n), jnp.int32(slot))
+        cache = kv.state()
     for step in range(40 - max(n_prompt)):
         last = jnp.asarray([[seqs[s, n + step]] for s, n in
                             enumerate(n_prompt)], jnp.int32)
-        kv = e._carrier(cache)
-        views = [LayerCacheView(kv, i, kind=kind)
-                 for kind, i in e._layer_index]
-        logits, stats = net.step(last, views)
+        kv = e.kv.carrier(cache)
+        logits, stats = net.step(last, e.kv.views(kv))
         assert stats.shape == (2,)
         for s, n in enumerate(n_prompt):
             assert np.abs(np.asarray(logits)[s, 0]
                           - want[s][n + step]).max() < TOL, (s, step)
-        cache = e._state_of(kv, kv.lens + 1)
+        cache = kv.state(kv.lens + 1)
 
 
 @pytest.mark.parametrize("kernels", [False, True])

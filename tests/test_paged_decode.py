@@ -12,10 +12,10 @@ Three layers of evidence:
     tail, and the full-slot clamp; every row the call did not append —
     other layers, the dead tail — comes back bit-identical (the cache
     is aliased to the output and updated in place);
-  * dispatch/engine-level — the gate chain (flag, shape, interpret
-    caps), probe-failure capture (journal event + counter + fallback),
+  * dispatch/engine-level — the gate chain (shape, emulator flag,
+    interpret caps), probe-failure capture (journal event + counter + fallback),
     the compile-once contract, prefix-hit suffix admission through the
-    fused path, token parity against the windowed-einsum engine, and
+    fused path, token parity against the einsum engine, and
     the decode step's jaxpr: no restack of the cache on either path,
     every kernel call aliased;
   * block-fusion level — the (y, z) pair primitive and the
@@ -344,9 +344,8 @@ class TestPagedDecodeKernel:
 class TestDispatchGate:
     @pytest.fixture
     def interp_on(self):
-        saved = get_flags(["paged_flash_decode", "paged_flash_interpret"])
-        set_flags({"paged_flash_decode": True,
-                   "paged_flash_interpret": True})
+        saved = get_flags("paged_flash_interpret")
+        set_flags({"paged_flash_interpret": True})
         yield
         set_flags(saved)
 
@@ -358,8 +357,8 @@ class TestDispatchGate:
         assert out is not None
         assert pk.attention_path_counts()["paged_flash"] == before + 1
 
-    def test_flag_off_returns_none(self, interp_on):
-        set_flags({"paged_flash_decode": False})
+    def test_emulator_flag_off_on_the_cpu_returns_none(self, interp_on):
+        set_flags({"paged_flash_interpret": False})
         q, kc, vc, lens, nk, nv, _, _ = _mk(nan_tail=False)
         assert pk.paged_decode_attention_or_none(
             q, kc, vc, lens, nk, nv, layer=LAYER) is None
@@ -377,6 +376,92 @@ class TestDispatchGate:
             q, kc, vc, lens, nk, nv, layer=LAYER) is None     # D % 8 != 0
 
 
+#: what `LayerCacheView.attend` can be handed: (query heads a key-value
+#: head, kind of layer, cache type, lengths before the token). 64 rows a
+#: full layer (one slot at the wall), a ring of 16 every slot has wrapped.
+ATTEND_CASES = {
+    "gpt_float": (1, "full", "float32", (0, 17, 64)),
+    "gpt_int8": (1, "full", "int8", (0, 17, 64)),
+    "grouped_full": (2, "full", "float32", (0, 17, 64)),
+    "grouped_ring_wrapped": (2, "window", "float32", (16, 37, 95)),
+}
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["einsum", "emulated_kernel"])
+@pytest.mark.parametrize("case", list(ATTEND_CASES))
+def test_attend_is_the_one_decode_attention_over_the_cache(case, kernel):
+    """One new token a slot through `LayerCacheView.attend`, on the
+    einsum and on the emulated kernel it chooses, against the plain
+    grouped-query oracle over the layer as it must be afterwards; the
+    carrier holds that layer, and no other row of the cache moved."""
+    from paddle_tpu.inference.serving.cache import (
+        PagedKVCache, dequantize_kv)
+    G, kind, kv_dtype, lens = ATTEND_CASES[case]
+    B, H, D, T, W = 3, 2, 16, 64, 16
+    ring, quantized = kind == "window", kv_dtype == "int8"
+    cache = PagedKVCache(
+        3 if ring else 2, B, H, T, D, kv_dtype=kv_dtype,
+        layer_kinds=("window", "full", "window") if ring else None,
+        window=W if ring else None)
+    rs = np.random.RandomState(3)
+    state = []
+    for a in cache.state()[:-1]:
+        x = rs.randn(*a.shape)
+        state.append(jnp.asarray(_quantize_np(x)[0] if a.dtype == jnp.int8
+                                 else np.abs(x) / 127 if a.ndim == 4 else x,
+                                 a.dtype))
+    lens = jnp.asarray(lens, jnp.int32)
+    kv = cache.carrier(state + [lens])
+    view = cache.views(kv)[-1]         # the second layer of its kind
+    assert (view.kind, view.layer) == (kind, 1)
+    names = ("wk", "wv") if ring else ("k", "v")
+    was = {n: np.array(getattr(kv, n)) for n in names}
+    if quantized:
+        was.update(k_scale=np.array(kv.k_scale), v_scale=np.array(kv.v_scale))
+    q, nk, nv = (jnp.asarray(rs.randn(B, H, n, D), jnp.float32)
+                 for n in (G, 1, 1))
+    before = pk.attention_path_counts()
+    saved = get_flags("paged_flash_interpret")
+    set_flags({"paged_flash_interpret": kernel})
+    try:
+        out = view.attend(q, nk, nv)
+    finally:
+        set_flags(saved)
+    path = ("xla_paged" if not kernel
+            else "paged_gqa" if G > 1 or ring else "paged_flash")
+    after = pk.attention_path_counts()
+    assert {p for p in after if after[p] != before.get(p, 0)} == {path}
+
+    # the layer as it must be afterwards, in float
+    rows = W if ring else T
+    lens = np.asarray(lens)
+    row = lens % rows if ring else np.minimum(lens, rows - 1)
+    live = np.minimum(lens + 1, rows)
+    slots = np.arange(B)
+    want = []
+    for name, new in zip(names, (nk, nv)):
+        new = np.asarray(new)[:, :, 0]
+        if quantized:
+            new, new_sc = _quantize_np(new)
+            sc = was[name + "_scale"]
+            sc[1, slots, :, row] = new_sc
+            np.testing.assert_allclose(
+                np.asarray(getattr(kv, name + "_scale")), sc, rtol=2e-5)
+        was[name][1, slots, :, row] = new
+        assert np.array_equal(np.asarray(getattr(kv, name)), was[name])
+        want.append(np.asarray(dequantize_kv(was[name][1], sc[1]))
+                    if quantized else was[name][1])
+    ok = (np.arange(rows)[None, :] < live[:, None])[:, None, None, None]
+    ref = pk._gqa_oracle(q[:, :, :, None], want[0], want[1],
+                         jnp.asarray(ok))[:, :, :, 0]
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-4 if quantized else 1e-5)
+    if ring:                # the other kind's stack is the array it was
+        assert kv.k is state[0] and kv.v is state[1]
+
+
 def _tiny(**kw):
     from paddle_tpu.models import gpt_tiny
     m = gpt_tiny(vocab_size=VOCAB, hidden_size=32, num_layers=2,
@@ -389,9 +474,8 @@ def _tiny(**kw):
 class TestEngineFusedPath:
     @pytest.fixture
     def interp_on(self):
-        saved = get_flags(["paged_flash_decode", "paged_flash_interpret"])
-        set_flags({"paged_flash_decode": True,
-                   "paged_flash_interpret": True})
+        saved = get_flags("paged_flash_interpret")
+        set_flags({"paged_flash_interpret": True})
         yield
         set_flags(saved)
 
@@ -420,7 +504,7 @@ class TestEngineFusedPath:
         assert after["xla_paged"] == before["xla_paged"]
         assert fused_eng.decode_compiles == 1
 
-        set_flags({"paged_flash_decode": False})
+        set_flags({"paged_flash_interpret": False})
         plain_toks, plain_eng = self._greedy(model, kv_dtype)
         assert pk.attention_path_counts()["paged_flash"] == \
             after["paged_flash"]
@@ -463,7 +547,7 @@ class TestEngineFusedPath:
         assert fused[1][1] > 0          # second request was a prefix HIT
         assert feng.decode_compiles == 1
 
-        set_flags({"paged_flash_decode": False})
+        set_flags({"paged_flash_interpret": False})
         plain, _ = serve()
         assert [t for t, _ in fused] == [t for t, _ in plain]
 
@@ -534,7 +618,7 @@ class TestEngineFusedPath:
 
     def test_cpu_default_takes_einsum_fallback(self):
         # without FLAGS_paged_flash_interpret the CPU engine must land
-        # on the windowed-einsum path counter, never the kernel
+        # on the einsum path counter, never the kernel
         import paddle_tpu as paddle
         paddle.seed(0)
         before = pk.attention_path_counts()

@@ -337,6 +337,40 @@ class TestCacheState:
             kv.set_state(st8[0], st8[1], st[2])
 
 
+    @pytest.mark.parametrize("kind,fields", [
+        ("float", ("k", "v", "lens")),
+        ("int8", ("k", "v", "k_scale", "v_scale", "lens")),
+        ("ring", ("k", "v", "wk", "wv", "lens"))])
+    def test_carrier_round_trips_a_state_of_each_kind(self, kind, fields):
+        """`carrier(state).state(lens)` is the state with the new lengths,
+        array for array in the one order the jitted steps thread; a state
+        of another kind's arity, or of another type, is refused as
+        `set_state` refuses it."""
+        mk = {"float": lambda: PagedKVCache(2, 2, 2, 8, 4),
+              "int8": lambda: PagedKVCache(2, 2, 2, 8, 4, kv_dtype="int8"),
+              "ring": lambda: PagedKVCache(
+                  2, 2, 2, 8, 4, layer_kinds=("window", "full"), window=4)}
+        kv = mk[kind]()
+        st = kv.state()
+        carrier = kv.carrier(st)
+        assert [getattr(carrier, f) for f in fields] == list(st)
+        assert all(a is b for a, b in zip(carrier.state(), st))
+        lens = st[-1] + 1
+        moved = carrier.state(lens)
+        assert moved[-1] is lens and carrier.lens is lens
+        assert all(a is b for a, b in zip(moved[:-1], st[:-1]))
+        assert [v.kind for v in kv.views(carrier)] == list(kv.layer_kinds)
+        kv.set_state(moved)
+        assert kv.lens is lens
+        other = mk["int8" if kind == "float" else "float"]().state()
+        for bad in (other, moved[:-1],
+                    (moved[0].astype("float16"),) + moved[1:]):
+            with pytest.raises(ValueError):
+                kv.carrier(bad)
+            with pytest.raises(ValueError):
+                kv.set_state(bad)
+
+
 def _prefix_engine():
     """One cached reuse-enabled engine for every TestPrefixReuse test —
     tier-1 wall time is compile-bound, so tests assert counter DELTAS

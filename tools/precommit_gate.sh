@@ -610,59 +610,6 @@ EOF
     fi
 fi
 
-# Serving bench gate: both capture artifact rows must parse and their
-# gates must hold — gpt2_generate (decode_compile_once,
-# prefill_le_buckets, continuous_beats_static) and gpt2_prefix_int8
-# (prefix hit TTFT <= 0.6x miss, reuse tokens/s >= no-reuse, int8
-# greedy parity >= 64 tokens, int8 bytes <= 0.55x bf16, int8 decode
-# compiles once). On the CPU these rows are a FUNCTIONAL gate at toy
-# shapes: each row says so itself (platform, unit), so none can be filed
-# as a chip measurement.
-if [ "$rc" -eq 0 ]; then
-    SERVE_LOG="$(mktemp /tmp/pt_serve_bench_XXXXXX.json)"
-    timeout -k 10 480 env JAX_PLATFORMS=cpu \
-        python benchmarks/inference_bench.py gpt2 > "$SERVE_LOG" 2>&1
-    bench_rc=$?
-    if [ "$bench_rc" -eq 0 ]; then
-        python - "$SERVE_LOG" <<'EOF'
-import json, sys
-rows = [json.loads(l) for l in open(sys.argv[1])
-        if l.strip().startswith("{")]
-assert all(r["platform"] == "cpu" and "cpu" in r.get("unit", "cpu")
-           for r in rows), rows
-row = next(r for r in rows if r.get("config") == "gpt2_generate")
-assert "error" not in row, row
-for k in ("tokens_per_s", "ttft_ms_p50", "ttft_ms_p95", "latency_ms_p50",
-          "latency_ms_p95", "speedup_x", "gates"):
-    assert k in row, (k, sorted(row))
-assert row["gates"] and all(row["gates"].values()), row["gates"]
-print("SERVING_BENCH=ok (%.0f tok/s, ttft p50=%.0fms, "
-      "continuous/static=%.2fx)" % (row["tokens_per_s"],
-                                    row["ttft_ms_p50"], row["speedup_x"]))
-row = next(r for r in rows if r.get("config") == "gpt2_prefix_int8")
-assert "error" not in row, row
-for k in ("tokens_per_s", "noreuse_tokens_per_s", "prefix_ttft_ratio",
-          "int8_parity_tokens", "int8_parity_ok", "int8_nbytes_ratio",
-          "gates"):
-    assert k in row, (k, sorted(row))
-assert row["gates"] and all(row["gates"].values()), row["gates"]
-print("SERVING_BENCH=ok+prefix_int8 (reuse %.0f vs %.0f tok/s, ttft "
-      "hit/miss=%.2fx, int8 parity %d/%d, bytes=%.2fx bf16)"
-      % (row["tokens_per_s"], row["noreuse_tokens_per_s"],
-         row["prefix_ttft_ratio"], row["int8_parity_tokens"],
-         row["int8_parity_total"], row["int8_nbytes_ratio"]))
-EOF
-        bench_rc=$?
-    fi
-    if [ "$bench_rc" -ne 0 ]; then
-        echo "SERVING_BENCH=FAILED (rc=$bench_rc, log in $SERVE_LOG)"
-        tail -5 "$SERVE_LOG"
-        rc=$bench_rc
-    else
-        rm -f "$SERVE_LOG"
-    fi
-fi
-
 # Overload smoke (docs/SERVING.md "SLO admission control"): a burst
 # past max_queue_depth on a tiny single-slot engine must shed with a
 # positive retry_after_s while everything admitted completes in full,
@@ -749,73 +696,6 @@ EOF
         rc=$smoke_rc
     else
         rm -rf "$OV_DIR"
-    fi
-fi
-
-# Megakernel smoke (docs/PERFORMANCE.md "Megakernels"): staggered
-# serving requests with the fused paged-decode kernel forced on in
-# interpret mode must (a) trace the paged_flash path and NEVER fall
-# back to the windowed einsum (xla_paged == 0), (b) keep the
-# decode-compiles-exactly-once contract, and (c) produce token-for-token
-# greedy parity against a second engine with the kernel disabled.
-if [ "$rc" -eq 0 ]; then
-    timeout -k 10 240 env JAX_PLATFORMS=cpu FLAGS_paged_flash_interpret=1 \
-        python - <<'EOF'
-import time
-import numpy as np
-import paddle_tpu as paddle
-from paddle_tpu.framework.flags import set_flags
-from paddle_tpu.inference.serving import InferenceServer
-from paddle_tpu.models import gpt_tiny
-from paddle_tpu.ops.pallas_kernels import attention_path_counts
-
-paddle.seed(0)
-m = gpt_tiny(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
-             intermediate_size=64, max_position_embeddings=64)
-m.eval()
-rs = np.random.RandomState(3)
-prompts = [rs.randint(1, 64, (n,)) for n in (3, 6, 9, 12)]
-
-
-def serve():
-    toks = []
-    with InferenceServer(m, max_batch=4, max_seq_len=64,
-                         prefill_buckets=(8, 16),
-                         kv_dtype="int8") as srv:
-        handles = []
-        for p in prompts:      # staggered -> mid-flight slot admission
-            handles.append(srv.submit(p.copy(), max_new_tokens=6))
-            time.sleep(0.02)
-        toks = [list(h.result(timeout=120)) for h in handles]
-        compiles = srv.engines[0].decode_compiles
-    return toks, compiles
-
-
-before = attention_path_counts()
-fused_toks, fused_compiles = serve()
-after = attention_path_counts()
-paged = after["paged_flash"] - before["paged_flash"]
-fell_back = after["xla_paged"] - before["xla_paged"]
-assert paged > 0, after
-assert fell_back == 0, after
-assert fused_compiles == 1, fused_compiles
-
-set_flags({"paged_flash_decode": False})   # force the einsum fallback
-plain_toks, plain_compiles = serve()
-after2 = attention_path_counts()
-assert after2["paged_flash"] == after["paged_flash"], after2
-assert plain_compiles == 1, plain_compiles
-assert fused_toks == plain_toks, (fused_toks, plain_toks)
-print("MEGAKERNEL_SMOKE=ok (4 staggered requests: %d paged_flash traces, "
-      "0 einsum fallbacks, decode compiled once, %d/%d greedy tokens "
-      "match the unfused engine)"
-      % (paged, sum(len(t) for t in fused_toks),
-         sum(len(t) for t in fused_toks)))
-EOF
-    smoke_rc=$?
-    if [ "$smoke_rc" -ne 0 ]; then
-        echo "MEGAKERNEL_SMOKE=FAILED (rc=$smoke_rc)"
-        rc=$smoke_rc
     fi
 fi
 

@@ -927,7 +927,7 @@ def _bench_rows(directory):
                              "mfu": r.get("mfu"),
                              "compile_s": r.get("compile_s"),
                              "hbm_peak": r.get("hbm_peak")})
-            # serving rows (inference_bench.py via the TPU window) trend
+            # serving rows (the `inference` rows of banked results) trend
             # alongside training: throughput column = tokens_per_s, and
             # ttft p95 gets its own column + regression flag
             for r in data.get("inference") or []:
@@ -971,7 +971,7 @@ def cmd_bench(directory) -> int:
     prior row (not the previous one — a single slow round must not
     reset the bar). Flags: step_ms >110% of best, MFU <90% of best,
     compile_s >110% of best, hbm_peak >110% of best; serving rows
-    (inference_bench) flag tokens_per_s <90% of best and ttft_ms_p95
+    (`inference`) flag tokens_per_s <90% of best and ttft_ms_p95
     >110% of best; fused-kernel rows (fused_kernels_bench) flag
     pallas_ms >110% of best and speedup <90% of best."""
     files = _bench_rows(directory)
