@@ -67,9 +67,25 @@ restored in `finally` (`_traced`, the one place); at dispatch time
 weights pass as arguments (`_run`, the one place), so many engines
 (server workers) can share one loaded model read-only. Cache buffers are
 donated — XLA updates the paged KV in place in HBM.
+
+Enqueue and read are two calls. Nothing a later program needs ever comes
+back to the host: a decode step's tokens ARE the next step's `last`, a
+prefill writes its token into `last` inside its executable, the cache
+state and the RNG key thread from program to program. So a caller may
+enqueue the next program BEFORE it reads the tokens of the one before,
+and the device never waits for the read: `enqueue_decode()` enqueues a
+step and returns, `decode()` reads the OLDEST step in flight (and
+enqueues one first if none is), `prefill()` enqueues and returns a first
+token that blocks only when it is converted (`int(...)`). A caller that
+never calls `enqueue_decode()` and converts a first token at once runs
+one program at a time, as before the split. **The donation rule:** only
+the cache state is donated. `last` is NOT: step n's token array is step
+n + 1's `last`, and donated it would be a deleted buffer by the time
+step n is read behind step n + 1.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
@@ -102,18 +118,44 @@ MOE_ASSIGNMENTS = metrics.counter(
     "Token-expert assignments the expert layers computed (padding rows "
     "and idle slots included: the device computes them)")
 
+AHEAD_PCT = metrics.histogram(
+    "pt_serve_ahead_pct",
+    "100 where another program was already in flight behind the decode "
+    "step whose tokens decode() returns, else 0 (the device then waits "
+    "out the read); one observation a decode() return",
+    buckets=(0.0, 100.0))
+
 # Trace-time weight swapping mutates shared Layer state (`p._data`); one
 # process-wide lock serializes dispatches so server workers sharing a
 # model can never interleave a trace with another engine's dispatch.
 _DISPATCH_LOCK = threading.Lock()
 
 
+class _FirstToken:
+    """A prefill's first token while it is still on its way: `int()`
+    blocks for it, once (the `fetch` span and the routing statistics are
+    observed there), and gives the same number ever after."""
+
+    __slots__ = ("_read", "_value")
+
+    def __init__(self, read):
+        self._read, self._value = read, None
+
+    def __int__(self) -> int:
+        if self._read is not None:
+            self._value, self._read = self._read(), None
+        return self._value
+
+
 class GenerationEngine:
     """Greedy decoding over a static-shape paged KV cache.
 
     Host API (used by the scheduler):
-      prefill(slot, prompt) -> first generated token (admits a request)
-      decode() -> np.int32[max_batch], next token for every slot
+      prefill(slot, prompt) -> first generated token (admits a request);
+                               enqueued at once, read at `int(...)`
+      enqueue_decode()      -> None; one more decode step in flight
+      decode() -> np.int32[max_batch], next token for every slot, of the
+                  OLDEST step in flight (enqueues one if none is)
 
     Inactive slots keep decoding garbage into their (clamped) tail —
     that is by design: masking slots out would put batch composition
@@ -193,17 +235,23 @@ class GenerationEngine:
         self.admit_info = {"prefix_len": 0, "bucket": 0}
 
         self._traces = {"prefill": 0, "decode": 0, "suffix": 0}
-        # instant the previous program's result reached the host; None
-        # before the first program and across an idle wait
+        # decode steps in flight, oldest first: (the array its tokens come
+        # back in, `_programs` once it was enqueued)
+        self._steps = collections.deque()
+        self._programs = 0       # programs this engine has enqueued
+        # instant the latest result reached the host, until the next
+        # enqueue has taken its gap from it; None before the first
+        # program and across an idle wait
         self._fetched_ts = None
         self._prefill_tel = tracing.StepTelemetry("serve_prefill")
         self._suffix_tel = tracing.StepTelemetry("serve_suffix")
         self._decode_tel = tracing.StepTelemetry("serve_decode")
-        self._jit_prefill = jax.jit(self._prefill_fn, donate_argnums=(3, 4))
-        self._jit_decode = jax.jit(self._decode_fn, donate_argnums=(3, 4))
+        # the cache state is donated, `last` is not (the donation rule)
+        self._jit_prefill = jax.jit(self._prefill_fn, donate_argnums=(3,))
+        self._jit_decode = jax.jit(self._decode_fn, donate_argnums=(3,))
         # one jit object; jax retraces per (prefix_len, suffix bucket)
         # shape pair — counted in _traces["suffix"], never in "prefill"
-        self._jit_suffix = jax.jit(self._suffix_fn, donate_argnums=(3, 4))
+        self._jit_suffix = jax.jit(self._suffix_fn, donate_argnums=(3,))
 
     # -- traced bodies ----------------------------------------------------
 
@@ -329,7 +377,8 @@ class GenerationEngine:
         dispatch lock the program is enqueued inside `tel`'s step `key`
         (an out-of-memory error leaves its forensics), the host's `gap`
         span is closed, and the key, the cache state and `last` are the
-        program's. -> (the token array, the packed array or None)."""
+        program's. Nothing is read. -> the array the program's tokens
+        come back in (the packed one for a model with expert layers)."""
         with _DISPATCH_LOCK:
             try:
                 with tel.step(key):
@@ -342,16 +391,19 @@ class GenerationEngine:
                     memprof.on_oom(tel.engine, e)
                 raise
             self._enqueued(gap)
+            self._programs += 1
             if jitted is self._jit_decode:   # its tokens ARE the next `last`
                 tok, RNG.key, *packed = out
                 self._last = tok
             else:
                 self._last, tok, RNG.key, *packed = out
             self.kv.set_state(kvstate)
-        return tok, (packed[0] if packed else None)
+        return packed[0] if packed else tok
 
-    def prefill(self, slot: int, prompt) -> int:
-        """Admit a prompt into `slot`; returns its first generated token.
+    def prefill(self, slot: int, prompt) -> _FirstToken:
+        """Admit a prompt into `slot`: the program is enqueued and the
+        first generated token comes back pending; `int(...)` of it
+        blocks for the token (and observes the routing statistics).
 
         Consults the PrefixCache first: on a hit only the suffix runs
         through the model; on a miss the full bucketed prefill runs and
@@ -379,25 +431,28 @@ class GenerationEngine:
         PREFILL_BUCKET_HITS.labels(str(b)).inc()
         args = (padded, np.int32(n), np.int32(slot))
         if entry is not None:
-            tok, packed = self._run(
+            arr = self._run(
                 self._jit_suffix, self._suffix_tel, ("suffix", reused, b),
                 "host_gap_prefill", entry, *args)
         else:
-            tok, packed = self._run(
+            arr = self._run(
                 self._jit_prefill, self._prefill_tel, ("prefill", b),
                 "host_gap_prefill", *args)
             if self.prefix_cache is not None:
                 self._store_prefix(prompt, n, slot)
         self.admit_info = {"prefix_len": reused, "bucket": b}
-        if packed is not None:
-            out = self._fetch(packed)
-            self._observe_moe(out[1:], b)
+
+        def read() -> int:
+            out = self._fetch(arr, parent="prefill").reshape(-1)
+            if out.size > 1:            # packed: the token, then the stats
+                self._observe_moe(out[1:], b)
             return int(out[0])
-        return int(self._fetch(tok)[0, 0])
+        return _FirstToken(read)
 
     def _store_prefix(self, prompt, n: int, slot: int) -> None:
         """Harvest the slot's freshly-prefilled K/V head (largest bucket
-        <= prompt length) and admit it to the PrefixCache."""
+        <= prompt length) and admit it to the PrefixCache: slices on the
+        device, in program order behind the prefill; nothing is read."""
         p_store = 0
         for b in self.buckets:
             if b <= n:
@@ -406,32 +461,42 @@ class GenerationEngine:
             self.prefix_cache.store(prompt[:p_store],
                                     self.kv.head(slot, p_store))
 
+    def enqueue_decode(self) -> None:
+        """Enqueue one decode step for the whole batch and return; its
+        tokens wait on the device for a later `decode()`."""
+        arr = self._run(self._jit_decode, self._decode_tel, "decode",
+                        "host_gap_decode")
+        self._steps.append((arr, self._programs))
+
     def decode(self) -> np.ndarray:
-        """One decode step for the whole batch; next token per slot."""
-        tok, packed = self._run(self._jit_decode, self._decode_tel, "decode",
-                                "host_gap_decode")
-        if packed is not None:
-            out = self._fetch(packed)
+        """The next token per slot of the OLDEST decode step in flight;
+        with none in flight, one step is enqueued first."""
+        if not self._steps:
+            self.enqueue_decode()
+        arr, programs = self._steps.popleft()
+        out = self._fetch(arr).reshape(-1)
+        AHEAD_PCT.observe(100.0 if self._programs > programs else 0.0)
+        if out.size > self.max_batch:   # packed: the tokens, then the stats
             self._observe_moe(out[self.max_batch:], self.max_batch)
-            return out[:self.max_batch]
-        return self._fetch(tok).reshape(-1)
+        return out[:self.max_batch]
 
     # -- the host's side of the gap between two programs -------------------
 
     def _enqueued(self, gap: str) -> None:
-        """The next program's enqueue has returned: the host's Python
-        since the previous program's tokens were fetched is one `gap`
-        span. The device waits longer than this: its gap also holds the
-        launch after the enqueue and the tokens' way back, which lie
-        under `fetch`."""
-        t0 = self._fetched_ts
+        """A program's enqueue has returned. If tokens were fetched since
+        the enqueue before it, the host's Python from that fetch to here
+        is one `gap` span, named by this program. It bounds the device's
+        wait only where nothing was in flight behind the tokens fetched
+        (`pt_serve_ahead_pct` says how often that is)."""
+        t0, self._fetched_ts = self._fetched_ts, None
         if t0 is not None:
             spans.record(gap, (time.perf_counter() - t0) * 1e3, t0=t0)
 
-    def _fetch(self, tok) -> np.ndarray:
+    def _fetch(self, tok, parent=None) -> np.ndarray:
         """Block for a program's tokens (the `fetch` span, a child of
-        `decode_step` or `prefill`); the next host gap starts here."""
-        with spans.span("fetch") as sp:
+        `decode_step`, or of the `prefill` a first token names); the next
+        host gap starts here."""
+        with spans.span("fetch", parent=parent) as sp:
             out = np.asarray(tok)
             self._fetched_ts = now = time.perf_counter()
             sp.close(now)

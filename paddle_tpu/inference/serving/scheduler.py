@@ -1,17 +1,52 @@
 """Continuous-batching scheduler over the generation engine's slots.
 
 Orca-style iteration-level scheduling: the decode batch is a fixed set
-of `max_batch` slots; a finished sequence frees its slot at the end of
-the step and a queued request is admitted into it on the next step via
-one bucketed prefill — the batch stays full instead of draining to the
-slowest straggler. `admit_mid_flight=False` degrades to classic static
-batching (fill the batch, run it to empty, repeat), the baseline
-tests/test_serving.py compares against.
+of `max_batch` slots; a finished sequence frees its slot and a queued
+request is admitted into it on the next step via one bucketed prefill —
+the batch stays full instead of draining to the slowest straggler.
+`admit_mid_flight=False` degrades to classic static batching (fill the
+batch, run it to empty, repeat), the baseline tests/test_serving.py
+compares against.
 
 All decode dispatches cost the same wall time regardless of how many
 slots are live (the compiled program is shape-fixed), so throughput is
 decided purely by how many useful tokens each step carries — which is
 exactly what `pt_serve_batch_occupancy` measures.
+
+**The loop runs one decode step ahead.** No token is read from the device
+until the program that follows it has been enqueued (engine.py says why
+it can be), so the device does not wait out the read, the host's
+bookkeeping and the next enqueue. One iteration, `step()`:
+
+  1. admit: one prefill a free slot, each enqueued behind whatever is in
+     flight, its first token left pending;
+  2. read the first tokens that are the oldest programs in flight (TTFT
+     is stamped there); a cold start enqueues one decode step behind
+     them first;
+  3. top up: `enqueue_decode()` while fewer than `STEPS_AHEAD` decode
+     steps are in flight and some request still needs a step that is not
+     in flight. A step carries the (slot, request) pairs that were live
+     when it was ENQUEUED: its tokens go to them and to no one else;
+  4. read the OLDEST decode step's tokens (`token_ts`), and harvest.
+
+Everything is read in the order it was enqueued. The serving loop takes
+2 and 3–4 in two calls (`turn()`), and looks at its queue between them.
+
+What running ahead costs is an arrival's wait: its prefill queues behind
+the step already enqueued. So the serving loop does not top up at once
+where it need not: while ONE step is in flight, a slot is free and no one
+waits, it may wait `hold_s()` for an arrival — the first half of the
+running step, measured from the last two steps that ran back to back —
+and an arrival in that time gets its prefill in FRONT of the next step.
+The second half is the room the top-up needs (several enqueues long).
+
+A request is released **by count, at enqueue time**: once the step that
+carries its last token is in flight its slot is free for the next
+prefill, which the device orders after that step, so running ahead costs
+no occupancy. A request with an `eos_id` may stop sooner, and the loop
+learns it one step late: the token the step ahead computed for it is
+dropped and never reaches `Request.tokens`. How deep the loop runs is
+not set anywhere: it follows from what is owed by count and what is live.
 """
 from __future__ import annotations
 
@@ -55,6 +90,10 @@ ITL_MS = metrics.histogram(
 
 _RID = itertools.count(1)
 
+#: decode steps kept in flight: the one the device runs and the one
+#: behind it, so that the device never waits for the host's read
+STEPS_AHEAD = 2
+
 
 @dataclass
 class Request:
@@ -86,12 +125,36 @@ class Request:
                 and self.tokens[-1] == self.eos_id)
 
 
+class _First:
+    """An admission in flight: the prefill is enqueued, its first token
+    pending (`tok`), its `prefill` span open until the token is read."""
+
+    __slots__ = ("req", "tok", "t_pre", "span", "bucket")
+
+    def __init__(self, req, tok, t_pre, span, bucket):
+        self.req, self.tok, self.t_pre = req, tok, t_pre
+        self.span, self.bucket = span, bucket
+
+
+class _Step:
+    """A decode step in flight: the (slot, request) pairs live when it
+    was enqueued, the instant that was, and whether it was enqueued
+    straight behind the step before it (`timed`: then the time between
+    their two reads is one step's run)."""
+
+    __slots__ = ("pairs", "t_enq", "timed")
+
+    def __init__(self, pairs, t_enq, timed):
+        self.pairs, self.t_enq, self.timed = pairs, t_enq, timed
+
+
 class ContinuousBatcher:
     """Slot scheduler driving one GenerationEngine.
 
     step() == admit waiting requests into free slots (one prefill each,
-    which also yields the request's first token / TTFT), then one decode
-    dispatch for the whole batch, then harvest + free finished slots.
+    enqueued; its first token / TTFT comes when it is read), top up the
+    decode steps in flight, read the oldest one's tokens, harvest (the
+    module docstring has the order and why).
     """
 
     def __init__(self, engine, admit_mid_flight: bool = True,
@@ -109,8 +172,21 @@ class ContinuousBatcher:
             slo = AdmissionController(slo, clock=clock)
         self.slo: Optional[AdmissionController] = slo
         self.waiting: deque = deque()
+        # a slot's owner while it still needs a decode step that is not
+        # in flight; `_left` counts those steps
         self.slots: List[Optional[Request]] = [None] * engine.max_batch
-        self.steps = 0
+        self._left = [0] * engine.max_batch
+        # admitted and not complete, by rid: the slots' owners and the
+        # requests released by count whose tokens are still in flight
+        self._live = {}
+        # what is enqueued and not read, oldest first: `_First`s and
+        # `_Step`s
+        self._flight: deque = deque()
+        self._steps_ahead = 0            # the decode steps among them
+        self.steps = 0                   # decode steps read
+        # for `hold_s`: the instant of the last read (about when the
+        # program behind it began) and a step's run as last measured
+        self._read_ts = self._period = None
         self.live_slot_steps = 0
         # what the engine's model adds to the prefill / decode_step spans
         # (expert and window layers); the live rows are counted a step only
@@ -123,11 +199,12 @@ class ContinuousBatcher:
 
     @property
     def active(self) -> int:
-        return sum(1 for r in self.slots if r is not None)
+        """Requests admitted and not complete, tokens in flight or not."""
+        return len(self._live)
 
     @property
     def idle(self) -> bool:
-        return not self.waiting and self.active == 0
+        return not (self.waiting or self._live or self._flight)
 
     @property
     def occupancy_mean(self) -> float:
@@ -135,8 +212,22 @@ class ContinuousBatcher:
             return 0.0
         return self.live_slot_steps / (self.steps * self.engine.max_batch)
 
+    def hold_s(self) -> float:
+        """How long the serving loop may still wait for an arrival before
+        `step()` has to top up (0: do not wait). Only while exactly one
+        decode step is in flight and nothing else, some request still
+        needs another, a slot is free and no one waits: then until half
+        of the running step's measured time is over."""
+        if (self._period is None or self._steps_ahead != 1
+                or len(self._flight) != 1 or self.waiting
+                or not self.admit_mid_flight or None not in self.slots
+                or not any(self.slots)):
+            return 0.0
+        started = max(self._flight[0].t_enq, self._read_ts)
+        return max(started + self._period / 2 - self._clock(), 0.0)
+
     def pending_requests(self) -> List[Request]:
-        return [r for r in self.slots if r is not None] + list(self.waiting)
+        return list(self._live.values()) + list(self.waiting)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -191,6 +282,9 @@ class ContinuousBatcher:
     def _complete(self, req: Request, completed: List[Request]) -> None:
         done = req.token_ts[-1]
         req.latency_s = done - req.submit_ts
+        if self.slots[req.slot] is req:   # stopped by its eos: not by count
+            self.slots[req.slot] = None
+        del self._live[req.rid]
         req.slot = None
         req.outcome = "completed"
         COMPLETED.inc()
@@ -238,85 +332,160 @@ class ContinuousBatcher:
             t_pre = self._clock()
             # `step`: the decode step this admission runs ahead of
             with spans.span("prefill", parent="serve_request", t0=t_pre,
-                            rid=req.rid, step=self.steps + 1,
+                            rid=req.rid,
+                            step=self.steps + self._steps_ahead + 1,
                             **self._span_attrs) as sp:
                 tok = self.engine.prefill(slot, req.prompt)
-                now = self._clock()
-                # what THIS admission actually dispatched: on a prefix
-                # hit the bucket is the (smaller) suffix bucket and
-                # prefix_len counts the reused tokens
-                info = getattr(self.engine, "admit_info", None) or \
-                    {"prefix_len": 0, "bucket": self.engine.bucket_for(n)}
-                sp.close(now, bucket=info["bucket"])
-            req.ttft_s = now - req.submit_ts
+                sp.defer()          # it closes when the token is read
+            # what THIS admission actually dispatched: on a prefix hit
+            # the bucket is the (smaller) suffix bucket and prefix_len
+            # counts the reused tokens
+            info = getattr(self.engine, "admit_info", None) or \
+                {"prefix_len": 0, "bucket": self.engine.bucket_for(n)}
             req.prefix_len = int(info.get("prefix_len", 0))
             # queue_wait + prefill == ttft_s exactly: same clock, same
             # instants — the TTFT decomposition SERVING.md documents
             spans.record("queue_wait", (t_pre - req.submit_ts) * 1e3,
                          parent="serve_request", t0=req.submit_ts,
                          rid=req.rid)
-            if req.prefix_len > 0:
-                # prefix-cache hit: a serve_suffix child over the SAME
-                # interval as prefill (parent="prefill", not a sibling
-                # under serve_request), so queue_wait + prefill == ttft
-                # stays exact while the trace shows which admissions ran
-                # the suffix-only path
-                spans.record("serve_suffix", (now - t_pre) * 1e3,
-                             parent="prefill", t0=t_pre, rid=req.rid,
-                             prefix_len=req.prefix_len,
-                             bucket=info["bucket"])
-            req.token_ts.append(now)
-            req.tokens.append(tok)
             req.slot = slot
+            self._live[req.rid] = req
+            self._flight.append(_First(req, tok, t_pre, sp, info["bucket"]))
+            self._left[slot] = req.max_new_tokens - 1
+            if self._left[slot]:      # else the prefill's token is its last
+                self.slots[slot] = req
             ADMITTED.inc()
-            TOKENS.inc()
-            TTFT.observe(req.ttft_s)
             if self.slo is not None:
-                # the measured TTFT/queue-wait of every admission IS
-                # the control signal — no separate sampling path
                 self.slo.observe_queue_wait(t_pre - req.submit_ts)
-                self.slo.observe_ttft(req.ttft_s)
             journal.emit("serve_admit", rid=req.rid, slot=slot,
                          prompt_len=n, bucket=info["bucket"],
                          prefix_len=req.prefix_len)
-            if req.done:          # max_new_tokens == 1 (or instant eos)
-                self._complete(req, completed)
-            else:
-                self.slots[slot] = req
 
-    def step(self) -> List[Request]:
-        """One scheduler iteration; returns requests completed by it."""
+    def _first_token(self, first: _First, completed: List[Request]) -> None:
+        """Read an admission's first token (it blocks here): TTFT, the
+        end of its `prefill` span, the SLO controller's sample."""
+        req, t_pre = first.req, first.t_pre
+        tok = int(first.tok)
+        now = self._clock()
+        first.span.close(now, bucket=first.bucket)
+        req.ttft_s = now - req.submit_ts
+        if req.prefix_len > 0:
+            # prefix-cache hit: a serve_suffix child over the SAME
+            # interval as prefill (parent="prefill", not a sibling
+            # under serve_request), so queue_wait + prefill == ttft
+            # stays exact while the trace shows which admissions ran
+            # the suffix-only path
+            spans.record("serve_suffix", (now - t_pre) * 1e3,
+                         parent="prefill", t0=t_pre, rid=req.rid,
+                         prefix_len=req.prefix_len,
+                         bucket=first.bucket)
+        req.token_ts.append(now)
+        req.tokens.append(tok)
+        TOKENS.inc()
+        TTFT.observe(req.ttft_s)
+        if self.slo is not None:
+            # the measured TTFT of every admission IS the control
+            # signal — no separate sampling path
+            self.slo.observe_ttft(req.ttft_s)
+        if req.done:              # max_new_tokens == 1 (or instant eos)
+            self._complete(req, completed)
+
+    def _enqueue_step(self) -> None:
+        """One more decode step in flight, for the requests that hold a
+        slot now; one whose last token it carries gives its slot up here
+        (the release by count)."""
+        # straight behind a step in flight: it is read one run after it
+        # (or was enqueued as that one was read, where the loop held late)
+        timed = bool(self._flight) and isinstance(self._flight[-1], _Step)
+        self.engine.enqueue_decode()
+        pairs = []
+        for slot, req in enumerate(self.slots):
+            if req is None:
+                continue
+            pairs.append((slot, req))
+            self._left[slot] -= 1
+            if not self._left[slot]:
+                self.slots[slot] = None
+        self._flight.append(_Step(pairs, self._clock(), timed))
+        self._steps_ahead += 1
+
+    def _top_up(self, ahead: int) -> None:
+        while self._steps_ahead < ahead and any(self.slots):
+            self._enqueue_step()
+
+    def _read_firsts(self, completed: List[Request]) -> bool:
+        """Read the first tokens that are the oldest programs in flight
+        (-> whether there were any). Each has its follower enqueued by
+        then: the program behind it in flight, else one decode step
+        enqueued here — or it will never have one (its request needs no
+        step)."""
+        if not (self._flight and isinstance(self._flight[0], _First)):
+            return False
+        with spans.span("first_tokens", step=self.steps + 1):
+            self._top_up(1)
+            while self._flight and isinstance(self._flight[0], _First):
+                self._first_token(self._flight.popleft(), completed)
+        self._read_ts = self._clock()
+        return True
+
+    def _read_step(self, completed: List[Request]) -> None:
+        """Top up, then read the oldest decode step in flight (which is
+        the oldest program in flight) and harvest its tokens."""
+        n = self.steps + 1
+        with spans.span("decode_step", t0=self._clock(), step=n,
+                        **self._span_attrs) as sp:
+            self._top_up(STEPS_AHEAD)
+            if not self._flight:
+                sp.cancel()
+                return
+            step = self._flight.popleft()
+            self._steps_ahead -= 1
+            toks = self.engine.decode()
+            now = self._clock()         # the step's tokens are fetched
+            sp.close(now)
+        if step.timed:
+            self._period = now - self._read_ts
+        self._read_ts = now
+        self.steps = n
+        with spans.span("harvest", t0=now, step=n) as sp:
+            # a request its eos stopped a step ago is in this step too:
+            # the token computed for it is dropped
+            pairs = [(slot, req) for slot, req in step.pairs
+                     if not req.done]
+            if self._kv is not None:
+                self._kv.observe_live_rows(
+                    [len(r.prompt) + len(r.tokens) + 1 for _, r in pairs])
+            for slot, req in pairs:
+                req.tokens.append(int(toks[slot]))
+                ITL_MS.observe((now - req.token_ts[-1]) * 1e3)
+                req.token_ts.append(now)
+                if req.done:
+                    self._complete(req, completed)
+            self.live_slot_steps += len(pairs)
+            TOKENS.inc(len(pairs))
+            OCCUPANCY_PCT.observe(100.0 * len(pairs) / len(self.slots))
+            sp.close(self._clock())
+
+    def _iterate(self, pause: bool) -> List[Request]:
         completed: List[Request] = []
         self._admit(completed)
-        live = self.active
-        if live:
-            n = self.steps + 1
-            with spans.span("decode_step", t0=self._clock(), step=n,
-                            **self._span_attrs) as sp:
-                toks = self.engine.decode()
-                now = self._clock()     # the step's tokens are fetched
-                sp.close(now)
-            self.steps = n
-            self.live_slot_steps += live
-            with spans.span("harvest", t0=now, step=n) as sp:
-                if self._kv is not None:
-                    self._kv.observe_live_rows(
-                        [len(r.prompt) + len(r.tokens) + 1
-                         for r in self.slots if r is not None])
-                for slot, req in enumerate(self.slots):
-                    if req is None:
-                        continue
-                    req.tokens.append(int(toks[slot]))
-                    ITL_MS.observe((now - req.token_ts[-1]) * 1e3)
-                    req.token_ts.append(now)
-                    if req.done:
-                        self.slots[slot] = None
-                        self._complete(req, completed)
-                TOKENS.inc(live)
-                OCCUPANCY_PCT.observe(100.0 * live / len(self.slots))
-                sp.close(self._clock())
+        if not (self._read_firsts(completed) and pause):
+            self._read_step(completed)
         OCCUPANCY.set(self.active)
         return completed
+
+    def step(self) -> List[Request]:
+        """One scheduler iteration: admit, read the first tokens that
+        are due, top up, read one decode step; returns the requests
+        completed by it."""
+        return self._iterate(pause=False)
+
+    def turn(self) -> List[Request]:
+        """`step()` for a caller that watches a queue of its own between
+        two reads (the serving loop): it stops once first tokens were
+        read, before the next decode step is topped up and read, so that
+        a request that came meanwhile is admitted in FRONT of that step."""
+        return self._iterate(pause=True)
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> List[Request]:
         completed: List[Request] = []

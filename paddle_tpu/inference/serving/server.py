@@ -1,6 +1,8 @@
 """Minimal multi-worker serving front-end over the generation engine.
 
-A thread-per-worker serving loop fed by one shared request queue. Each
+A thread-per-worker serving loop fed by one shared request queue: drain
+the queue, wait on it for as long as `ContinuousBatcher.hold_s()` lets
+the next decode step wait, `turn()` (scheduler.py has the order). Each
 worker owns a GenerationEngine (its own paged KV cache and slots) but
 all workers share the SAME loaded model — weights are read-only at
 serve time and pass into the jitted steps as arguments (engine.py), so
@@ -246,7 +248,19 @@ class InferenceServer:
                         return
                     self._submit_or_fail(batcher, handle)
                     continue
-                batcher.step()
+                hold = batcher.hold_s()
+                if hold > 0:
+                    # a step is running and the next need not be enqueued
+                    # yet: an arrival now gets its prefill in front of it
+                    with spans.span("hold", step=batcher.steps + 1):
+                        try:
+                            handle = self._queue.get(timeout=hold)
+                        except queue.Empty:
+                            handle = None
+                    engine.note_idle()      # a wait, not the host's work
+                    if handle is not None:
+                        self._submit_or_fail(batcher, handle)
+                batcher.turn()
         except BaseException as exc:
             flight.dump_crash_bundle("serve_loop", exc)
             self._fail_pending(batcher, exc)

@@ -4,8 +4,9 @@ PR 2's StepTelemetry says *that* a step was slow; spans say *where the
 time went*. A span is one named wall-clock interval with an optional
 parent, so a train step decomposes into `feed` / `compile` / `dispatch`
 / `host` children, a serving request into `queue_wait` / `prefill` /
-`decode_steps`, and the serving loop into `drain` / `prefill` /
-`decode_step` (`dispatch` + `fetch`) / `harvest` / `loop_idle` — the
+`decode_steps`, and the serving loop into `drain` / `hold` / `prefill` /
+`first_tokens` / `decode_step` (`dispatch` + `fetch`) / `harvest` /
+`loop_idle` — the
 breakdown `ptdoctor profile` renders and the benchmark's `program_span`
 metrics read.
 
@@ -178,7 +179,7 @@ class Span:
     """One open interval; context manager (stacked) or begin/end handle."""
 
     __slots__ = ("name", "parent", "attrs", "t0", "_stacked", "_done",
-                 "_ann")
+                 "_ann", "_deferred")
 
     def __init__(self, name: str, parent: Optional[str], attrs: dict,
                  stacked: bool, t0: Optional[float] = None,
@@ -187,7 +188,7 @@ class Span:
         self.parent = parent
         self.attrs = attrs
         self._stacked = stacked
-        self._done = False
+        self._done = self._deferred = False
         # a handle that travels between threads is not annotated: the
         # profiler pairs an annotation's two ends on one thread's line
         self._ann = _annotate(label or name, attrs) if stacked else None
@@ -196,6 +197,12 @@ class Span:
     def cancel(self) -> None:
         """Abandon without recording (e.g. the feed-exhausted last step)."""
         self._done = True
+
+    def defer(self) -> None:
+        """Leaving the `with` block will only unwind the nesting; the
+        caller keeps the span and records it with a later `close(t1)`
+        (a program enqueued in the block, its result read after it)."""
+        self._deferred = True
 
     def close(self, t1: Optional[float] = None, **attrs) -> None:
         """Record the interval now, ending at the caller's instant `t1`
@@ -215,9 +222,10 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         # an exception unwinding through the block is not a measured
         # interval (mirrors StepTelemetry's _Span)
-        if exc_type is None:
+        if exc_type is not None:
+            self._done = True
+        elif not self._deferred:
             self.close()
-        self._done = True
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         if self._stacked:
@@ -239,6 +247,9 @@ class _NullSpan:
         return False
 
     def cancel(self) -> None:
+        pass
+
+    def defer(self) -> None:
         pass
 
     def close(self, t1=None, **attrs) -> None:

@@ -146,7 +146,7 @@ def test_the_server_path_decodes_the_reference_greedy_tokens(
         e = _engine(net, max_seq_len=64, prefill_buckets=(16, 32))
         prompts = ref.tokens(7, 3, 30, CFG["vocab_size"])
         n_prompt = [30, 5, 19]
-        seqs = [list(prompts[s, :n]) + [e.prefill(s, prompts[s, :n])]
+        seqs = [list(prompts[s, :n]) + [int(e.prefill(s, prompts[s, :n]))]
                 for s, n in enumerate(n_prompt)]
         for _ in range(14):
             toks = e.decode()
@@ -161,13 +161,32 @@ def test_the_server_path_decodes_the_reference_greedy_tokens(
     assert max(gaps) == 0.0
 
 
+@pytest.mark.parametrize("kernels", [False, True])
+def test_the_run_ahead_loop_gives_the_depth_0_tokens_through_the_rings(
+        net, kernels):
+    """The churned schedule of tests/test_serving.py on the decoder
+    family: contexts of up to 21 rows wrap the rings of 8, the tokens
+    come back packed with the routing statistics, slots are refilled
+    behind the step that carries their last owner's last token."""
+    from test_serving import run_ahead_matches_depth0
+    set_flags({"FLAGS_paged_flash_interpret": kernels,
+               "FLAGS_use_flash_attention": kernels})
+    try:
+        b, refills = run_ahead_matches_depth0(_engine(net),
+                                              CFG["vocab_size"])
+    finally:
+        set_flags({"FLAGS_paged_flash_interpret": False,
+                   "FLAGS_use_flash_attention": True})
+    assert refills >= 3 and b.steps > 0
+
+
 def test_span_attributes_and_counters_of_a_served_model(net):
     from paddle_tpu.inference.serving import cache as cache_mod
     from paddle_tpu.inference.serving import engine as engine_mod
     e = _engine(net)
     assert e.span_attrs == {"moe_layers": 4, "window_layers": 4}
     n0 = engine_mod.MOE_ASSIGNMENTS.value
-    e.prefill(0, np.arange(1, 12))
+    int(e.prefill(0, np.arange(1, 12)))    # observed where it is read
     e.decode()
     # a bucket of 16 rows, then 3 slots: 2 experts a token, 4 layers
     assert engine_mod.MOE_ASSIGNMENTS.value - n0 == (16 + 3) * 2 * 4

@@ -524,6 +524,261 @@ class TestInt8KV:
         assert eng.decode_compiles == 1
 
 
+# ---- the loop runs one decode step ahead ------------------------------
+
+#: (prompt length, max_new_tokens) in submit order; `EOS_AT` names the
+#: request that gets an `eos_id` and stops early. Twelve requests churn
+#: through three or four slots: `max_new_tokens` 1 and 2, long and short
+CHURN = [(5, 1), (7, 2), (4, 6), (9, 2), (3, 1), (6, 9), (8, 3), (5, 2),
+         (4, 12), (7, 4), (6, 1), (5, 5)]
+EOS_AT = 8
+
+
+def depth0_tokens(eng, prompt, max_new, eos_id=None):
+    """One request alone, a program at a time — `prefill()` converted at
+    once, `decode()` with no `enqueue_decode()` before it: the engine as
+    it was before enqueue and read were split."""
+    toks = [int(eng.prefill(0, prompt))]
+    while len(toks) < max_new and toks[-1] != eos_id:
+        toks.append(int(eng.decode()[0]))
+    return toks
+
+
+def churned_requests(eng, vocab, seed=21):
+    """(requests, the depth-0 tokens of each) of the churned schedule on
+    `eng`; the `EOS_AT`-th request stops at the first token, from its
+    third on, that it had not given before."""
+    rs = np.random.RandomState(seed)
+    reqs, want = [], []
+    for i, (n, max_new) in enumerate(CHURN):
+        prompt = rs.randint(1, vocab, (n,)).astype(np.int64)
+        toks, eos = depth0_tokens(eng, prompt, max_new), None
+        if i == EOS_AT:
+            stop = next(k for k in range(2, max_new - 2)
+                        if toks[k] not in toks[:k])
+            eos, toks = toks[stop], toks[:stop + 1]
+            assert depth0_tokens(eng, prompt, max_new, eos) == toks
+        reqs.append(Request(prompt=prompt, max_new_tokens=max_new,
+                            eos_id=eos))
+        want.append(toks)
+    return reqs, want
+
+
+def run_ahead_matches_depth0(eng, vocab, admit_mid_flight=True):
+    """The run-ahead batcher gives every request of the churned schedule
+    the tokens the same engine gives it at depth 0; -> the batcher and
+    how many prefills refilled a slot whose last owner's tokens were
+    still in flight."""
+    reqs, want = churned_requests(eng, vocab)
+    compiles = eng.decode_compiles
+    b = ContinuousBatcher(eng, admit_mid_flight=admit_mid_flight)
+    enqueued, refills = [], []
+    enqueue_decode, prefill = eng.enqueue_decode, eng.prefill
+
+    def counted_enqueue():
+        # no step for a batch whose every request is complete by count:
+        # someone's tokens read or in flight (the first token's prefill,
+        # the steps that carry the request) fall short of what it asked
+        def owed(r):
+            coming = sum(any(q is r for _, q in f.pairs)
+                         if hasattr(f, "pairs") else f.req is r
+                         for f in b._flight)
+            return r.max_new_tokens - len(r.tokens) - coming
+        assert any(owed(r) > 0 for r in b.pending_requests()
+                   if r.slot is not None)
+        enqueued.append(b.steps)
+        return enqueue_decode()
+
+    def watched_prefill(slot, prompt):
+        refills.append(any(r.slot == slot and not r.done
+                           for r in b.pending_requests()))
+        return prefill(slot, prompt)
+
+    eng.enqueue_decode, eng.prefill = counted_enqueue, watched_prefill
+    try:
+        for r in reqs:
+            b.submit(r)
+        done = b.run_until_idle()
+    finally:
+        del eng.enqueue_decode, eng.prefill
+    assert len(done) == len(reqs) and b.idle and not b.pending_requests()
+    for i, (r, w) in enumerate(zip(reqs, want)):
+        assert r.tokens == w, i          # a dropped token never got here
+        assert len(r.token_ts) == len(w) and r.outcome == "completed"
+    assert len(reqs[EOS_AT].tokens) < reqs[EOS_AT].max_new_tokens
+    assert eng.decode_compiles == max(compiles, 1) == 1
+    # every step enqueued was read, none is left in flight
+    assert len(enqueued) == b.steps and not eng._steps
+    return b, sum(refills)
+
+
+class TestRunAhead:
+    @pytest.mark.parametrize("mode", ["continuous", "static"])
+    def test_churned_schedule_token_for_token_with_depth_0(self, mode):
+        eng = _shared_engine()
+        b, refills = run_ahead_matches_depth0(
+            eng, VOCAB, admit_mid_flight=(mode == "continuous"))
+        if mode == "continuous":
+            # a prefill went into a slot released by count, behind the
+            # step that carries its last owner's last token
+            assert refills >= 3
+            assert b.occupancy_mean > 0.6
+        else:
+            assert refills == 0
+
+    def test_churned_schedule_with_an_int8_cache(self):
+        eng = GenerationEngine(_tiny(), max_batch=3, max_seq_len=32,
+                               prefill_buckets=(8, 16), kv_dtype="int8")
+        run_ahead_matches_depth0(eng, VOCAB)
+
+    def test_a_step_is_read_after_the_step_behind_it_was_enqueued(self):
+        """Step n's token array is step n + 1's `last`: donated, reading
+        it behind step n + 1 would raise a deleted-buffer error."""
+        eng = _shared_engine()
+        rs = np.random.RandomState(22)
+        prompt = _prompt(rs, 6)
+        want = depth0_tokens(eng, prompt, 6)
+        first = eng.prefill(0, prompt)       # pending: nothing read yet
+        for _ in range(3):
+            eng.enqueue_decode()
+        got = [int(first), int(first)]       # read once, the same twice
+        got += [int(eng.decode()[0]) for _ in range(3)]
+        eng.enqueue_decode()
+        eng.enqueue_decode()
+        got += [int(eng.decode()[0]), int(eng.decode()[0])]
+        assert got[1:] == want and not eng._steps
+        assert eng.decode_compiles == 1
+
+    @pytest.mark.parametrize("max_new,steps", [(1, 0), (2, 1), (5, 4)])
+    def test_no_step_is_enqueued_that_no_request_needs(self, max_new,
+                                                       steps):
+        eng = _shared_engine()
+        b = ContinuousBatcher(eng)
+        rs = np.random.RandomState(23)
+        r = b.submit(Request(prompt=_prompt(rs, 5), max_new_tokens=max_new))
+        n = []
+        enqueue_decode = eng.enqueue_decode
+        eng.enqueue_decode = lambda: (n.append(1), enqueue_decode())[1]
+        try:
+            b.step()
+            # by count, all it needs (up to two steps) is in flight or read
+            assert len(n) == min(steps, 2)
+            b.run_until_idle()
+        finally:
+            del eng.enqueue_decode
+        assert len(n) == b.steps == steps and len(r.tokens) == max_new
+
+    def test_turn_takes_the_two_reads_in_two_calls(self):
+        """The serving loop's iteration: a cold start enqueues ONE step
+        behind its prefill and reads the first token; a request that came
+        meanwhile is admitted before the second step is enqueued, and gets
+        its second token from that step."""
+        eng = _shared_engine()
+        rs = np.random.RandomState(26)
+        pa, pl = _prompt(rs, 5), _prompt(rs, 7)
+        want_a, want_l = depth0_tokens(eng, pa, 4), depth0_tokens(eng, pl, 3)
+        b = ContinuousBatcher(eng)
+        a = b.submit(Request(prompt=pa, max_new_tokens=4))
+        b.turn()
+        assert a.tokens == want_a[:1] and b.steps == 0
+        assert [type(f).__name__ for f in b._flight] == ["_Step"]
+        late = b.submit(Request(prompt=pl, max_new_tokens=3))
+        b.turn()                   # late's prefill, the second step; D1 read
+        assert a.tokens == want_a[:2] and b.steps == 1 and not late.tokens
+        first, second = b._flight
+        assert first.req is late
+        assert [q is a or q is late for _, q in second.pairs] == [True, True]
+        b.turn()                   # late's first token: a call of its own
+        assert late.tokens == want_l[:1] and b.steps == 1
+        while not b.idle:
+            b.turn()
+        assert a.tokens == want_a and late.tokens == want_l
+
+    def test_the_loop_may_hold_the_top_up_for_half_a_running_step(self):
+        """`hold_s()`: while ONE step is in flight, a slot is free and no
+        one waits, the serving loop may wait for an arrival until half of
+        the running step's measured time is over; the measure is the time
+        between the reads of two steps enqueued back to back."""
+        from paddle_tpu.inference.serving import VirtualClock
+        eng, clk = _shared_engine(), VirtualClock(start=50.0)
+        enqueue_decode, decode = eng.enqueue_decode, eng.decode
+        free, ends = [clk()], []
+
+        def enqueue_8ms():       # the device on this clock: 8 ms a step,
+            free[0] = max(free[0], clk()) + 0.008    # one after another
+            ends.append(free[0])
+            return enqueue_decode()
+
+        def decode_when_done():
+            clk.now = max(clk(), ends.pop(0))
+            return decode()
+        eng.enqueue_decode, eng.decode = enqueue_8ms, decode_when_done
+        try:
+            b = ContinuousBatcher(eng, clock=clk)
+            rs = np.random.RandomState(25)
+            r = b.submit(Request(prompt=_prompt(rs, 5), max_new_tokens=12))
+            assert b.hold_s() == 0.0     # nothing measured, nothing flying
+            b.step()                     # P, D1, D2 enqueued; P, D1 read
+            assert b.hold_s() == 0.0     # D2's run is not measured yet
+            b.step()                     # D3 behind D2; D2 read: 8 ms
+            assert b._period == pytest.approx(0.008)
+            assert b.hold_s() == pytest.approx(0.004)
+            clk.advance(0.003)
+            assert b.hold_s() == pytest.approx(0.001)
+            late = b.submit(Request(prompt=_prompt(rs, 4),
+                                    max_new_tokens=2))
+            assert b.hold_s() == 0.0     # someone waits: admit, top up
+            b.step()                     # late's prefill, D4; D3 read
+            assert b.hold_s() == 0.0     # a prefill is in flight
+            b.step()                     # D5 behind D4; D4 read
+            assert b._period == pytest.approx(0.008)
+            # held too long (the estimate was off, the host was late): the
+            # next step is enqueued as the finished one is read, and the
+            # time between the two reads is still one step's run
+            assert b.hold_s() == pytest.approx(0.004)
+            clk.advance(0.02)
+            assert b.hold_s() == 0.0     # the running step is past half
+            b.step()                     # D6 enqueued late; D5 read
+            b.step()                     # D6 read, 8 ms after its enqueue
+            assert b._period == pytest.approx(0.008)
+            b.run_until_idle()
+            assert b.hold_s() == 0.0
+            assert len(r.tokens) == 12 and len(late.tokens) == 2
+            # every slot taken: an arrival could not be admitted anyway
+            full = [b.submit(Request(prompt=_prompt(rs, 4),
+                                     max_new_tokens=9))
+                    for _ in range(eng.max_batch)]
+            b.step()
+            b.step()
+            assert None not in b.slots and b.hold_s() == 0.0
+            b.run_until_idle()
+            assert all(len(q.tokens) == 9 for q in full)
+            # static batching admits nothing mid-flight: it never holds
+            s = ContinuousBatcher(eng, clock=clk, admit_mid_flight=False)
+            s.submit(Request(prompt=_prompt(rs, 5), max_new_tokens=8))
+            s.step()
+            s.step()
+            assert s._period is not None and s.hold_s() == 0.0
+            s.run_until_idle()
+        finally:
+            del eng.enqueue_decode, eng.decode
+
+    def test_a_failing_loop_answers_requests_whose_tokens_are_in_flight(
+            self):
+        """`pending_requests()` (what `_fail_pending` walks) counts a
+        request released by count until its tokens have come."""
+        eng = _shared_engine()
+        b = ContinuousBatcher(eng)
+        rs = np.random.RandomState(24)
+        r = b.submit(Request(prompt=_prompt(rs, 5), max_new_tokens=3))
+        b.step()                 # P, D1, D2 enqueued; P and D1 read
+        assert b.slots == [None] * eng.max_batch and len(r.tokens) == 2
+        assert b.pending_requests() == [r] and b.active == 1
+        assert not b.idle
+        b.run_until_idle()
+        assert r.done and b.idle and b.active == 0
+
+
 class TestPredictorPoolSharing:
     def test_pool_members_share_program_and_executables(self, tmp_path):
         import paddle_tpu.inference as infer
