@@ -5,7 +5,8 @@ KV cache by `concat` every token, so each step has a NEW shape — an
 un-jittable host loop that retraces per token. Here the cache is
 preallocated at engine construction:
 
-    k/v: [n_layers, max_batch, n_heads, max_seq_len, head_dim]
+    k: [n_layers, max_batch, n_heads, max_seq_len, key size]
+    v: [n_layers, max_batch, n_heads, max_seq_len, value size]
     lens: int32 [max_batch]   (tokens already resident per slot)
 
 and every update is a `jax.lax.dynamic_update_slice` at a traced
@@ -55,6 +56,19 @@ Two kinds of layer live side by side in one manager (`layer_kinds`): a
 one stack: full `k/v [L_f, B, H_kv, max_seq_len, hd]`, window `wk/wv
 [L_w, B, H_kv, window, hd]`; a model with no window layer has no window
 stack and its state is the three (or five) arrays it always was.
+
+The two stacks need not agree on anything but the slots: each kind has its
+own number of key-value heads, and K and V each their own row size
+(`PagedKVCache(kv_geometry=)`: a key row 192 wide beside a value row of
+128, four heads on the full layers and eight on the rings). Nothing below
+assumes that a K array and a V array have one shape, or that the full
+stack's heads are the rings'. A kind whose key size is not its value size
+keeps its K BY COLUMN, `[L, B, H_kv, key size, rows]`: a 192-wide bfloat16
+row is no whole number of the chip's 128-lane tiles, XLA therefore stores
+`[.., rows, 192]` with the rows minor, and a kernel that asks for it
+row-major gets the whole stack copied in and out of every call
+(`ops/pallas_kernels._paged_kv_decode`). `StackedKV.k_cols` names those
+kinds; `insert`, `attend` and the einsum fallback are the only readers.
 """
 from __future__ import annotations
 
@@ -110,20 +124,22 @@ def _state_fields(quantized, ring):
 class StackedKV:
     """The stacked cache arrays of one traced step.
 
-    k/v: [n_full_layers, B, n_heads, max_seq_len, head_dim] (traced);
-    lens: int32 [B], each slot's length BEFORE this step's token. For a
-    quantized cache k/v are int8 and k_scale/v_scale carry the float32
-    per-(layer, slot, head, token) scales [n_layers, B, n_heads,
-    max_seq_len] (None otherwise). wk/wv: the window layers' rings
-    [n_window_layers, B, n_heads, window, head_dim], None when the model
-    has none; `kinds` then says which of the model's layers they are.
+    k/v: [n_full_layers, B, n_heads, max_seq_len, key | value size]
+    (traced); lens: int32 [B], each slot's length BEFORE this step's
+    token. For a quantized cache k/v are int8 and k_scale/v_scale carry
+    the float32 per-(layer, slot, head, token) scales [n_layers, B,
+    n_heads, max_seq_len] (None otherwise). wk/wv: the window layers'
+    rings [n_window_layers, B, ring heads, window, key | value size], None
+    when the model has none; `kinds` then says which of the model's layers
+    they are.
     `insert` and each layer's `attend` replace the arrays with their
     updated ones."""
 
-    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv", "kinds")
+    __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv", "kinds",
+                 "k_cols")
 
     def __init__(self, k, v, lens, k_scale=None, v_scale=None, wk=None,
-                 wv=None, kinds=None):
+                 wv=None, kinds=None, k_cols=()):
         self.k = k
         self.v = v
         self.lens = lens
@@ -132,6 +148,7 @@ class StackedKV:
         self.wk = wk
         self.wv = wv
         self.kinds = kinds
+        self.k_cols = frozenset(k_cols)   # kinds whose K is [.., dk, rows]
 
     def state(self, lens=None) -> Tuple:
         """The flat tuple `PagedKVCache.carrier` took apart, holding the
@@ -143,7 +160,8 @@ class StackedKV:
 
     def insert(self, ks, vs, true_len, slot, offset=0, prefix=None):
         """A prompt enters `slot`: ks/vs, a layer's fresh float K/V
-        [1, H, T', hd] each as `serving().prefill` returns them, are
+        [1, H, T', size] each as `serving().prefill` returns them (H and
+        the two sizes its kind's), are
         written from row `offset` of the slot (quantized first when the
         cache is int8), behind a stored head (`PagedKVCache.head`) put
         back VERBATIM at row 0 when `prefix` is given, and the slot's
@@ -165,7 +183,7 @@ class StackedKV:
             if ring:
                 wk = jnp.stack([ks[i] for i in ring])
                 wv = jnp.stack([vs[i] for i in ring])
-                W, tb = self.wk.shape[3], wk.shape[3]
+                W, tb = self.wv.shape[3], wk.shape[3]
                 if tb > W:
                     r = jnp.arange(W, dtype=jnp.int32)
                     src = jnp.clip(r + W * ((true_len - 1 - r) // W),
@@ -174,6 +192,11 @@ class StackedKV:
                     wv = jnp.take(wv, src, axis=3)
             s, z = slot.astype(jnp.int32), jnp.int32(0)
             o = jnp.int32(offset)
+            at_k = (z, s, z, o, z)
+            if "full" in self.k_cols:       # K by column: rows are last
+                fk, at_k = jnp.swapaxes(fk, 3, 4), (z, s, z, z, o)
+            if ring and "window" in self.k_cols:
+                wk = jnp.swapaxes(wk, 3, 4)
             if self.k_scale is not None:
                 fk, k_sc = quantize_kv(fk)
                 fv, v_sc = quantize_kv(fv)
@@ -187,7 +210,7 @@ class StackedKV:
                              (z, s, z, z, z))
                 self.v = upd(self.v, prefix[1].astype(self.v.dtype),
                              (z, s, z, z, z))
-            self.k = upd(self.k, fk.astype(self.k.dtype), (z, s, z, o, z))
+            self.k = upd(self.k, fk.astype(self.k.dtype), at_k)
             self.v = upd(self.v, fv.astype(self.v.dtype), (z, s, z, o, z))
             self.lens = upd(self.lens, jnp.reshape(true_len, (1,)), (s,))
             if ring:
@@ -215,19 +238,22 @@ class LayerCacheView:
     def lens(self):
         return self.kv.lens
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, sink=None):
         """One new token a slot against this layer of the cache: q
-        [B, H_kv, G, hd] (G query heads share a key-value head; GPT has
-        G = 1), k, v [B, H_kv, 1, hd]; arrays in, array [B, H_kv, G, hd]
-        out. A full layer appends at row `lens` (a slot that hit the
+        [B, H_kv, G, dk] (G query heads share a key-value head; GPT has
+        G = 1), k [B, H_kv, 1, dk], v [B, H_kv, 1, dv]; arrays in, array
+        [B, H_kv, G, dv] out. `sink` (float32 [H_kv * G], or None) is one
+        more logit a query head in the softmax's denominator, which takes
+        no value. A full layer appends at row `lens` (a slot that hit the
         wall rewrites its last row) and attends rows <= lens; a window
         layer appends at `lens mod W` of its ring and attends the ring's
         live rows, which are in no order and need none under a softmax.
         The carrier's arrays are replaced by the updated ones.
 
         The kernel is chosen from what can be seen here: G = 1 on a full
-        layer takes the work-list kernel (int8 rows too), anything else
-        the grouped-query kernel. Where the gate answers None (the CPU
+        layer with K and V rows of one size and no sink takes the
+        work-list kernel (int8 rows too), anything else the grouped-query
+        kernel. Where the gate answers None (the CPU
         without FLAGS_paged_flash_interpret, an ineligible shape), one
         einsum over all the layer's rows, counter
         pt_attn_path_total{path=xla_paged}. Either way shapes never
@@ -237,8 +263,10 @@ class LayerCacheView:
         kv, layer = self.kv, self.layer
         ring = self.kind == "window"
         kc, vc = (kv.wk, kv.wv) if ring else (kv.k, kv.v)
-        rows, lens = kc.shape[3], kv.lens
-        work_list = q.shape[2] == 1 and not ring
+        k_cols = self.kind in kv.k_cols
+        rows, lens = vc.shape[3], kv.lens
+        work_list = q.shape[2] == 1 and not ring and sink is None \
+            and k.shape[-1] == v.shape[-1]
         if work_list:
             fused = pk.paged_decode_attention_or_none(
                 q, kc, vc, lens, k, v, kv.k_scale, kv.v_scale, layer=layer)
@@ -248,9 +276,10 @@ class LayerCacheView:
         row = lens % rows if ring else jnp.minimum(lens, rows - 1)
         live = jnp.minimum(lens + 1, rows)
         fused = None if work_list else pk.paged_gqa_decode_or_none(
-            q, kc, vc, row, live, k, v, layer=layer)
+            q, kc, vc, row, live, k, v, layer=layer, sink=sink, ring=ring,
+            k_cols=k_cols)
         if fused is None:
-            fused = self._einsum(q, k, v, kc, vc, row, live)
+            fused = self._einsum(q, k, v, kc, vc, row, live, sink, k_cols)
         out, kc, vc = fused
         if ring:
             kv.wk, kv.wv = kc, vc
@@ -258,10 +287,12 @@ class LayerCacheView:
             kv.k, kv.v = kc, vc
         return out
 
-    def _einsum(self, q, k, v, kc, vc, row, live):
+    def _einsum(self, q, k, v, kc, vc, row, live, sink=None, k_cols=False):
         """`attend` where no kernel runs: scatter the new row (and its
         scales), then one masked float32 einsum over every row of the
-        layer. -> (out, kc', vc'); updated scales go to the carrier."""
+        layer (the sink, where there is one, a last column of the scores
+        that the value product leaves out). -> (out, kc', vc'); updated
+        scales go to the carrier."""
         import jax
         import jax.numpy as jnp
         from ...ops import pallas_kernels as pk
@@ -280,18 +311,27 @@ class LayerCacheView:
             v, v_sc = quantize_kv(v)
             kv.k_scale = append(kv.k_scale, k_sc)
             kv.v_scale = append(kv.v_scale, v_sc)
-        kc, vc = append(kc, k), append(vc, v)
-        kf = kc[layer].astype(jnp.float32)
+        if k_cols:           # kc[layer, b, :, :, row[b]] = k[b, :, 0]
+            kc = kc.at[layer, slots, :, :, row].set(
+                k[:, :, 0].astype(kc.dtype))
+            kf = jnp.swapaxes(kc[layer], 2, 3).astype(jnp.float32)
+        else:
+            kc = append(kc, k)
+            kf = kc[layer].astype(jnp.float32)
+        vc = append(vc, v)
         vf = vc[layer].astype(jnp.float32)
         if quantized:
             kf = kf * kv.k_scale[layer][..., None]
             vf = vf * kv.v_scale[layer][..., None]
         scores = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
                             kf) * (1.0 / math.sqrt(q.shape[-1]))
-        ok = jnp.arange(kc.shape[3])[None, :] < live[:, None]   # [B, rows]
-        probs = jax.nn.softmax(
-            jnp.where(ok[:, None, None, :], scores, jnp.float32(-1e30)),
-            axis=-1)
+        ok = jnp.arange(vc.shape[3])[None, :] < live[:, None]   # [B, rows]
+        scores = jnp.where(ok[:, None, None, :], scores, jnp.float32(-1e30))
+        if sink is not None:
+            scores = jnp.concatenate([scores, jnp.broadcast_to(
+                sink.astype(jnp.float32).reshape(1, q.shape[1], -1, 1),
+                scores.shape[:-1] + (1,))], -1)
+        probs = jax.nn.softmax(scores, axis=-1)[..., :vc.shape[3]]
         out = jnp.einsum("bhgk,bhkd->bhgd", probs, vf)
         return out.astype(q.dtype), kc, vc
 
@@ -346,18 +386,24 @@ class PagedKVCache:
 
     `layer_kinds` ("full" | "window" a layer; default all full) with
     `window` splits the layers into the two stacks of the module
-    docstring; `n_heads` is the number of key-value heads."""
+    docstring; `n_heads` is the number of key-value heads and `head_dim`
+    the size of a key row and of a value row, of both kinds — unless
+    `kv_geometry` says otherwise: {kind: (key-value heads, key size, value
+    size)} for the kinds whose stack differs (`geometry` holds the answer
+    for both)."""
 
     def __init__(self, n_layers: int, max_batch: int, n_heads: int,
                  max_seq_len: int, head_dim: int, kv_dtype="float32",
                  layer_kinds: Optional[Sequence[str]] = None,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None, kv_geometry=None):
         import jax.numpy as jnp
         self.n_layers = int(n_layers)
         self.max_batch = int(max_batch)
-        self.n_heads = int(n_heads)
         self.max_seq_len = int(max_seq_len)
-        self.head_dim = int(head_dim)
+        self.geometry = {kind: (int(n_heads), int(head_dim), int(head_dim))
+                         for kind in ("full", "window")}
+        for kind, g in (kv_geometry or {}).items():
+            self.geometry[kind] = tuple(int(x) for x in g)
         self.kv_dtype = str(kv_dtype)
         self.quantized = self.kv_dtype == "int8"
         self.layer_kinds = tuple(layer_kinds or ("full",) * self.n_layers)
@@ -372,21 +418,32 @@ class PagedKVCache:
             raise ValueError("window layers need a window")
         if n_window and self.quantized:
             raise ValueError("an int8 cache has no window layers yet")
-        shape = (self.n_layers - n_window, self.max_batch, self.n_heads,
-                 self.max_seq_len, self.head_dim)
         store = jnp.int8 if self.quantized else self.kv_dtype
-        self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
+
+        # a key row that is not a value row's size is kept by column
+        # (the module docstring says why)
+        self.k_cols = tuple(kind for kind, (_, dk, dv)
+                            in sorted(self.geometry.items()) if dk != dv)
+        if self.k_cols and self.quantized:
+            raise ValueError("an int8 cache keeps no K by column yet")
+
+        def stack(kind, layers, rows):
+            heads, dk, dv = self.geometry[kind]
+            lead = (layers, self.max_batch, heads)
+            k_shape = (dk, rows) if kind in self.k_cols else (rows, dk)
+            return jnp.zeros(lead + k_shape, store), \
+                jnp.zeros(lead + (rows, dv), store)
+
+        self.k, self.v = stack("full", self.n_layers - n_window,
+                               self.max_seq_len)
         self.lens = jnp.zeros((self.max_batch,), jnp.int32)
         if self.quantized:
-            self.k_scale = jnp.zeros(shape[:-1], jnp.float32)
-            self.v_scale = jnp.zeros(shape[:-1], jnp.float32)
+            self.k_scale = jnp.zeros(self.k.shape[:-1], jnp.float32)
+            self.v_scale = jnp.zeros(self.v.shape[:-1], jnp.float32)
         else:
             self.k_scale = self.v_scale = None
         if n_window:
-            ring = (n_window,) + shape[1:3] + (self.window, self.head_dim)
-            self.wk = jnp.zeros(ring, store)
-            self.wv = jnp.zeros(ring, store)
+            self.wk, self.wv = stack("window", n_window, self.window)
         else:
             self.wk = self.wv = None
         self._fields = _state_fields(self.quantized, bool(n_window))
@@ -399,6 +456,8 @@ class PagedKVCache:
         return kind, self.layer_kinds[:layer].count(kind)
 
     def nbytes_by_kind(self) -> dict:
+        """Bytes reserved a kind: each stack's own arrays, so unequal
+        heads and K and V rows of different sizes count as they are."""
         full = int(self.k.nbytes) + int(self.v.nbytes)
         if self.quantized:
             full += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
@@ -412,7 +471,9 @@ class PagedKVCache:
 
     def observe_live_rows(self, lengths) -> None:
         """One observation a kind of `pt_kv_rows_live`: the rows that
-        requests of these context lengths hold in one layer of it."""
+        requests of these context lengths hold in one layer of it. A row
+        is one position's K and V over the kind's heads (`geometry`): its
+        bytes are the kind's own, not one size for both stacks."""
         KV_ROWS_LIVE.labels("full").observe(
             float(sum(min(n, self.max_seq_len) for n in lengths)))
         if self.wk is not None:
@@ -445,7 +506,7 @@ class PagedKVCache:
     def carrier(self, state) -> StackedKV:
         """A state tuple (this cache's own, or the traced one a jitted
         step received) as one `StackedKV`."""
-        return StackedKV(kinds=self.layer_kinds,
+        return StackedKV(kinds=self.layer_kinds, k_cols=self.k_cols,
                          **dict(zip(self._fields, self._checked(state))))
 
     def set_state(self, *state) -> None:
@@ -463,11 +524,16 @@ class PagedKVCache:
         return views
 
     def head(self, slot: int, n: int):
-        """The first `n` rows of `slot` in every layer, as new device
+        """The first `n` rows of `slot` in every FULL layer, as new device
         buffers (a later donation of the cache cannot invalidate them):
-        [k, v] of [n_layers, 1, n_heads, n, head_dim], then the two
-        scales when quantized. What `StackedKV.insert(prefix=)` puts
-        back and `head_kv` reads."""
+        k [n_full_layers, 1, heads, n, key size] and v [.., value size] of
+        the full stack's geometry, then the two scales when quantized.
+        What `StackedKV.insert(prefix=)` puts back and `head_kv` reads. A
+        cache with rings has no reusable head: position p of a ring is
+        gone once p + window is written."""
+        if self.wk is not None:
+            raise ValueError("a cache with window layers keeps no prompt "
+                             "head: its rings have overwritten it")
         s = int(slot)
         arrays = [self.k[:, s:s + 1, :, :n, :], self.v[:, s:s + 1, :, :n, :]]
         if self.quantized:

@@ -42,8 +42,12 @@ telemetry kill-switch.
 The engine reads no attribute of any particular model. It calls
 `model.serving()` and asks the answer for what it needs:
 
-  n_layers, kv_heads, head_dim     the cache's geometry
+  n_layers                         the model's layers
   layer_kinds, window              "full" | "window" a layer (cache.py)
+  kv_geometry                      {kind: (key-value heads, key size,
+                                   value size)}: the two kinds of layer
+                                   need agree on none of the three, nor
+                                   a key row with a value row
   max_positions                    the longest context the model places
   prefix_cache                     whether a stored prompt head can be
                                    re-inserted (not into a ring)
@@ -51,7 +55,9 @@ The engine reads no attribute of any particular model. It calls
   moe_layers, moe_top_k, moe_experts
                                    expert layers; a step of such a model
                                    sends its routing statistics (int32
-                                   [2]) back WITH its tokens
+                                   [2]; [3] where the layers hold a share
+                                   of their experts: the assignments that
+                                   fell on it) back WITH its tokens
   prefill(ids, true_len[, prefix]) -> (logits [1, 1, V] at the last real
                                    row, [k a layer], [v a layer], stats)
   decode(last, views)              -> (logits [B, 1, V], stats), the
@@ -115,8 +121,18 @@ MOE_LOAD = metrics.histogram(
     buckets=metrics.exponential_buckets(1, 1.5, 16))
 MOE_ASSIGNMENTS = metrics.counter(
     "pt_moe_assignments_total",
-    "Token-expert assignments the expert layers computed (padding rows "
+    "Token-expert assignments the expert layers routed (padding rows "
     "and idle slots included: the device computes them)")
+MOE_HERE = metrics.counter(
+    "pt_moe_assignments_here_total",
+    "Of pt_moe_assignments_total, those that fell on the experts held "
+    "here (all of them unless the layers hold a share of their experts)")
+MOE_HERE_PCT = metrics.histogram(
+    "pt_moe_here_pct",
+    "Share of a step's assignments that fell on the experts held here, "
+    "in percent; one observation a decode step and a prefill of a model "
+    "whose expert layers hold a share of their experts",
+    buckets=(1.0, 2.0, 4.0, 6.25, 8.0, 12.5, 25.0, 50.0, 100.0))
 
 AHEAD_PCT = metrics.histogram(
     "pt_serve_ahead_pct",
@@ -192,8 +208,6 @@ class GenerationEngine:
         model.eval()
         self.model = model
         self._n_layers = sv.n_layers
-        self._n_heads = sv.kv_heads
-        self._head_dim = sv.head_dim
         self._max_pos = sv.max_positions
         self.span_attrs = {
             "moe_layers": sv.moe_layers,
@@ -222,10 +236,11 @@ class GenerationEngine:
         self._buffers = buffers
         self._mutable = self._weights + buffers
 
+        heads, key_size, _ = next(iter(sv.kv_geometry.values()))
         self.kv = cache_mod.PagedKVCache(
-            self._n_layers, self.max_batch, self._n_heads,
-            self.max_seq_len, self._head_dim, kv_dtype=kv_dtype,
-            layer_kinds=sv.layer_kinds, window=sv.window)
+            self._n_layers, self.max_batch, heads, self.max_seq_len,
+            key_size, kv_dtype=kv_dtype, layer_kinds=sv.layer_kinds,
+            window=sv.window, kv_geometry=sv.kv_geometry)
         self._last = jnp.zeros((self.max_batch, 1), jnp.int32)
 
         budget = cache_mod.prefix_cache_budget(prefix_cache_bytes) \
@@ -343,7 +358,7 @@ class GenerationEngine:
     @staticmethod
     def _packed(tok, stats):
         """A step with routing statistics sends them back in the one
-        array its tokens come back in: int32 [n_tokens + 2]."""
+        array its tokens come back in: int32 [n_tokens + 2 or 3]."""
         import jax.numpy as jnp
         if stats is None:
             return ()
@@ -355,7 +370,12 @@ class GenerationEngine:
         MOE_TOUCHED.observe(stats[0] / layers)
         MOE_LOAD.observe((stats[1] / layers)
                          / (n_rows * sv.moe_top_k / float(sv.moe_experts)))
-        MOE_ASSIGNMENTS.inc(n_rows * sv.moe_top_k * layers)
+        routed = n_rows * sv.moe_top_k * layers
+        MOE_ASSIGNMENTS.inc(routed)
+        here = float(stats[2]) if len(stats) > 2 else routed
+        MOE_HERE.inc(here)
+        if len(stats) > 2:
+            MOE_HERE_PCT.observe(100.0 * here / routed)
 
     # -- host API ---------------------------------------------------------
 

@@ -8,24 +8,34 @@ model's name:
   * RMSNorm; optionally four norms a layer ("sandwich": the attention and
     MLP outputs are normed inside the residual branch);
   * grouped-query attention: `num_heads` query heads over `num_kv_heads`
-    key-value heads of `head_dim`; optional RMSNorm over each head of q
+    key-value heads; a key row `head_dim` wide and a value row
+    `v_head_dim`; the window layers may have head counts and sizes of
+    their own (`window_geometry`); optional RMSNorm over each head of q
     and k; optional sigmoid output gate before the output projection;
+    optional scale on the values (`value_scale`); optionally one learned
+    logit a query head in the softmax's denominator, which takes no value
+    (`sink_kinds`: the kinds of layer that have one);
   * per layer, full causal attention or a sliding window, and rotary
-    positions or none (`layer_kinds`, `rope_layers`);
+    positions or none (`layer_kinds`, `rope_layers`), rotate-half over the
+    first `rope_dim` of a head's key size, with a base a kind
+    (`rope_theta`, `window_rope_theta`);
   * per layer, a dense SwiGLU or an expert layer (`mlp_kinds`): sigmoid
-    router with a bias for the choice, top-k, a shared expert, NO capacity
-    and no dropped token (`incubate/moe.py`: tokens sorted by expert, a
-    grouped matrix product over the experts held);
+    router with a bias for the choice, top-k, a shared expert or none, NO
+    capacity and no dropped token (`incubate/moe.py`: tokens sorted by
+    expert, a grouped matrix product over the experts held, which may be
+    one chip's share of them: `MoEConfig.experts_held`);
   * optional sqrt(d) embedding scale, untied output head.
 
 The mathematics is plain `jax.numpy` over the parameters' arrays: forward
 only (serving and evaluation). There is no backward through the
-framework's tape yet — rotary scaling, latent attention, chunked prefill
-and experts over chips are not here either (ROADMAP R7, R9).
+framework's tape yet — rotary scaling, softmax and group-limited routing,
+latent attention, chunked prefill and the exchange of a sharded expert
+layer between chips are not here either (ROADMAP R7, R9).
 
 `DecoderLM.serving()` answers what `inference/serving/engine.py` asks of a
 model; the sliding-window layers keep a ring of `window` rows in the paged
-cache and the full layers every row (`serving/cache.py`).
+cache and the full layers every row, each kind with its own key-value
+heads and its own key and value sizes (`serving/cache.py`).
 """
 from __future__ import annotations
 
@@ -83,6 +93,14 @@ class DecoderConfig:
     embed_scale: float = 1.0
     max_positions: int = 2048
     moe: Optional[MoEConfig] = field(default=None)
+    v_head_dim: Optional[int] = None        # None: a value row is head_dim
+    #: (num_heads, num_kv_heads, head_dim, v_head_dim) of the window
+    #: layers; None: the full layers' own
+    window_geometry: Optional[Tuple[int, int, int, int]] = None
+    rope_dim: Optional[int] = None          # leading dims rotated; None: all
+    window_rope_theta: Optional[float] = None   # None: rope_theta
+    value_scale: float = 1.0
+    sink_kinds: Tuple[str, ...] = ()        # kinds with a sink a query head
 
     def __post_init__(self):
         n = len(self.layer_kinds)
@@ -96,53 +114,144 @@ class DecoderConfig:
             raise ValueError("window layers need a window")
         if "moe" in self.mlp_kinds and self.moe is None:
             raise ValueError("expert layers need a MoEConfig")
-        if self.num_heads % self.num_kv_heads:
-            raise ValueError("query heads must divide over the kv heads")
+        for kind in set(self.layer_kinds):
+            hq, hkv, dk, _ = self.geometry(kind)
+            if hq % hkv:
+                raise ValueError("query heads must divide over the kv heads")
+            r = self.rotary_dim(kind)
+            if not 0 < r <= dk or r % 2:
+                raise ValueError("rope_dim must be even and within a key row")
+        if set(self.sink_kinds) - {"full", "window"}:
+            raise ValueError("unknown layer kind")
 
     @property
     def num_layers(self):
         return len(self.layer_kinds)
 
+    def geometry(self, kind):
+        """(query heads, key-value heads, key size, value size) of the
+        layers of `kind`."""
+        if kind == "window" and self.window_geometry is not None:
+            return tuple(self.window_geometry)
+        return (self.num_heads, self.num_kv_heads, self.head_dim,
+                self.v_head_dim or self.head_dim)
+
+    def rotary_dim(self, kind):
+        return self.rope_dim or self.geometry(kind)[2]
+
+    def theta(self, kind):
+        if kind == "window" and self.window_rope_theta is not None:
+            return self.window_rope_theta
+        return self.rope_theta
+
     @classmethod
     def from_hf(cls, cfg, experts_held=None):
         """From the keys of a published `config.json` whose block is this
-        one (sliding and full attention mixed, sigmoid-routed experts with
-        a shared expert behind `num_dense_layers` dense layers)."""
-        kinds = tuple("window" if k == "sliding_attention" else "full"
-                      for k in cfg["layer_types"])
+        one. Every part is read from a key, under either of the two sets
+        of names the published configs of such models use (first name
+        below, then its other spelling):
+
+          layer_types ("sliding_attention" | "full_attention") or
+          hybrid_layer_pattern (1 window, 0 full); num_dense_layers
+          (leading) or moe_layer_freq (0 dense, 1 experts, a layer);
+          num_experts / n_routed_experts; num_shared_experts /
+          n_shared_experts; route_norm / norm_topk_prob; route_scale /
+          routed_scaling_factor; rms_norm_eps / layernorm_epsilon;
+          v_head_dim; swa_num_attention_heads, swa_num_key_value_heads,
+          swa_head_dim, swa_v_head_dim (the window layers' own);
+          partial_rotary_factor; swa_rope_theta; attention_value_scale;
+          add_swa_attention_sink_bias, add_full_attention_sink_bias.
+
+        Four parts of the block no published key states; each has a key
+        here, and a config that leaves it out gets what the modelling code
+        behind its set of names does: `qk_norm`, `attention_output_gate`,
+        `sandwich_norm` (all three on for a config that says `layer_types`,
+        off for one that says `hybrid_layer_pattern`) and `rope_layer_kinds`
+        (the kinds of layer that rotate: window alone for the first set,
+        both for the second). Rotary scaling other than `default` and
+        group-limited or softmax routing are not here: a config that asks
+        for one is refused."""
+        def key(*names, default=None):
+            for name in names:
+                if cfg.get(name) is not None:
+                    return cfg[name]
+            return default
+
         n = int(cfg["num_hidden_layers"])
-        if len(kinds) != n:
-            raise ValueError("layer_types has %d entries for %d layers"
-                             % (len(kinds), n))
-        dense = int(cfg.get("num_dense_layers", n))
+        pattern = cfg.get("hybrid_layer_pattern")
+        if pattern is not None:
+            kinds = tuple("window" if k else "full" for k in pattern)
+        else:
+            kinds = tuple("window" if k == "sliding_attention" else "full"
+                          for k in cfg["layer_types"])
+        freq = cfg.get("moe_layer_freq")
+        if freq is not None:
+            mlps = tuple("moe" if f else "dense" for f in freq)
+        else:
+            dense = int(cfg.get("num_dense_layers", n))
+            mlps = tuple("dense" if i < dense else "moe" for i in range(n))
+        if len(kinds) != n or len(mlps) != n:
+            raise ValueError("the layer lists have %d and %d entries for %d "
+                             "layers" % (len(kinds), len(mlps), n))
+        scaling = cfg.get("rope_scaling") or {}
+        if scaling.get("rope_type", scaling.get("type", "default")) \
+                != "default":
+            raise NotImplementedError("rotary scaling %r" % (scaling,))
+        if key("scoring_func", "score_func", default="sigmoid") != "sigmoid" \
+                or int(key("n_group", "num_expert_groups", default=1)) != 1:
+            raise NotImplementedError(
+                "softmax or group-limited routing is not in this block")
         d = int(cfg["hidden_size"])
         moe = None
-        if dense < n:
+        if "moe" in mlps:
+            width = int(cfg["moe_intermediate_size"])
             moe = MoEConfig(
-                num_experts=int(cfg["num_experts"]),
-                top_k=int(cfg["num_experts_per_tok"]),
-                width=int(cfg["moe_intermediate_size"]),
-                shared_width=int(cfg["moe_intermediate_size"])
-                * int(cfg.get("num_shared_experts", 0)),
-                route_norm=bool(cfg.get("route_norm", True)),
-                route_scale=float(cfg.get("route_scale", 1.0)),
+                num_experts=int(key("num_experts", "n_routed_experts")),
+                top_k=int(cfg["num_experts_per_tok"]), width=width,
+                shared_width=width * int(key(
+                    "num_shared_experts", "n_shared_experts", default=0)),
+                route_norm=bool(key("route_norm", "norm_topk_prob",
+                                    default=True)),
+                route_scale=float(key("route_scale", "routed_scaling_factor",
+                                      default=1.0)),
                 experts_held=experts_held)
+        heads, kv = int(cfg["num_attention_heads"]), \
+            int(cfg["num_key_value_heads"])
+        hd = int(cfg["head_dim"])
+        vd = int(cfg.get("v_head_dim") or hd)
+        win = (int(cfg.get("swa_num_attention_heads") or heads),
+               int(cfg.get("swa_num_key_value_heads") or kv),
+               int(cfg.get("swa_head_dim") or hd),
+               int(cfg.get("swa_v_head_dim") or vd))
+        unstated = pattern is None       # the first set of names: see above
+        rotating = tuple(cfg.get("rope_layer_kinds",
+                                 ("window",) if unstated
+                                 else ("full", "window")))
+        sinks = tuple(kind for kind, name in (
+            ("full", "add_full_attention_sink_bias"),
+            ("window", "add_swa_attention_sink_bias")) if cfg.get(name))
         return cls(
             vocab_size=int(cfg["vocab_size"]), hidden_size=d,
-            num_heads=int(cfg["num_attention_heads"]),
-            num_kv_heads=int(cfg["num_key_value_heads"]),
-            head_dim=int(cfg["head_dim"]), layer_kinds=kinds,
-            mlp_kinds=tuple("dense" if i < dense else "moe"
-                            for i in range(n)),
-            dense_width=int(cfg["intermediate_size"]),
+            num_heads=heads, num_kv_heads=kv, head_dim=hd, layer_kinds=kinds,
+            mlp_kinds=mlps, dense_width=int(cfg["intermediate_size"]),
             window=int(cfg.get("sliding_window") or 0),
-            # rotary on the sliding layers alone: full layers carry none
-            rope_layers=tuple(k == "window" for k in kinds),
+            rope_layers=tuple(k in rotating for k in kinds),
             rope_theta=float(cfg.get("rope_theta", 10000.0)),
-            rms_eps=float(cfg.get("rms_norm_eps", 1e-5)),
-            qk_norm=True, attn_gate=True, sandwich_norm=True,
+            rms_eps=float(key("rms_norm_eps", "layernorm_epsilon",
+                              default=1e-5)),
+            qk_norm=bool(cfg.get("qk_norm", unstated)),
+            attn_gate=bool(cfg.get("attention_output_gate", unstated)),
+            sandwich_norm=bool(cfg.get("sandwich_norm", unstated)),
             embed_scale=math.sqrt(d) if cfg.get("mup_enabled") else 1.0,
-            max_positions=int(cfg["max_position_embeddings"]), moe=moe)
+            max_positions=int(cfg["max_position_embeddings"]), moe=moe,
+            v_head_dim=None if vd == hd else vd,
+            window_geometry=None if win == (heads, kv, hd, vd) else win,
+            rope_dim=None if cfg.get("partial_rotary_factor") is None
+            else int(hd * float(cfg["partial_rotary_factor"])),
+            window_rope_theta=None if cfg.get("swa_rope_theta") is None
+            else float(cfg["swa_rope_theta"]),
+            value_scale=float(cfg.get("attention_value_scale") or 1.0),
+            sink_kinds=sinks)
 
 
 # ---------------------------------------------------------------------------
@@ -155,9 +264,13 @@ def rms_norm(x, w, eps):
                              + eps) * w.astype(F32)
 
 
-def rotary(x, pos, theta):
-    """x [B, H, T, hd] (float32) at positions pos [B, T]; rotate-half."""
+def rotary(x, pos, theta, dim=None):
+    """x [B, H, T, hd] (float32) at positions pos [B, T]; rotate-half over
+    the first `dim` of hd (all of it by default), the rest unrotated."""
     hd = x.shape[-1]
+    if dim is not None and dim < hd:
+        return jnp.concatenate(
+            [rotary(x[..., :dim], pos, theta), x[..., dim:]], -1)
     inv = theta ** (-jnp.arange(0, hd, 2, dtype=F32) / hd)
     ang = pos.astype(F32)[:, None, :, None] * inv              # [B,1,T,hd/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
@@ -174,12 +287,14 @@ def swiglu(x, gate, up, down):
     return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
 
 
-def band_attention(q, k, v, window):
-    """Causal attention of q [B, Hq, T, hd] over k, v [B, Hkv, T, hd],
-    grouped heads, keys j <= i and (window) i - j < window. The Pallas
-    band kernel where it applies, else the masked einsum."""
+def band_attention(q, k, v, window, sink=None):
+    """Causal attention of q [B, Hq, T, dk] over k [B, Hkv, T, dk] and v
+    [B, Hkv, T, dv], grouped heads, keys j <= i and (window) i - j <
+    window; `sink` [Hq] float32, where given, is one more logit a query
+    head in the softmax's denominator that takes no value. -> [B, Hq, T,
+    dv]. The Pallas band kernel where it applies, else the masked einsum."""
     from ..ops import pallas_kernels as pk
-    out = pk.band_flash_attention_or_none(q, k, v, window)
+    out = pk.band_flash_attention_or_none(q, k, v, window, sink)
     if out is not None:
         return out
     B, Hq, T, hd = q.shape
@@ -190,43 +305,71 @@ def band_attention(q, k, v, window):
     ok = j <= i
     if window:
         ok = ok & (i - j < window)
-    p = jax.nn.softmax(jnp.where(ok, s, _NEG), axis=-1)
+    s = jnp.where(ok, s, _NEG)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        b = jnp.broadcast_to(sink.astype(F32).reshape(1, Hkv, -1, 1, 1),
+                             s.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, b], -1), axis=-1)[..., :-1]
     o = jnp.einsum("bkgts,bksd->bkgtd", p, v.astype(F32))
-    return o.reshape(B, Hq, T, hd).astype(q.dtype)
+    return o.reshape(B, Hq, T, v.shape[-1]).astype(q.dtype)
 
 
-def paged_attention(q, k, v, view):
-    """One new token a slot against the paged cache: q [B, Hkv, G, hd],
-    k, v [B, Hkv, 1, hd] through `view` (serving/cache.LayerCacheView),
-    which appends, attends and leaves the carrier updated."""
-    return view.attend(q, k, v)
+def paged_attention(q, k, v, view, sink=None):
+    """One new token a slot against the paged cache: q [B, Hkv, G, dk],
+    k [B, Hkv, 1, dk], v [B, Hkv, 1, dv] through `view`
+    (serving/cache.LayerCacheView), which appends, attends and leaves the
+    carrier updated; `sink` as in `band_attention`."""
+    return view.attend(q, k, v, sink)
 
 
 def _attention(cfg, i, p, h, pos, view=None):
     """Attention branch of layer i on h [B, T, d] (float32) at positions
     pos [B, T]. Without `view`: the whole sequence, returning this
-    layer's k and v [B, Hkv, T, hd] for the cache; with it: one token a
-    slot through the paged cache."""
+    layer's k [B, Hkv, T, dk] and v [B, Hkv, T, dv] for the cache; with
+    it: one token a slot through the paged cache."""
     B, T, _ = h.shape
-    Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    kind = cfg.layer_kinds[i]
+    Hq, Hkv, dk, dv = cfg.geometry(kind)
     dt = p["wq"].dtype
     a = rms_norm(h, p["attn_norm"], cfg.rms_eps).astype(dt)
-    heads = lambda y, n: y.reshape(B, T, n, hd).transpose(0, 2, 1, 3)  # noqa
-    q, k = heads(_mm(a, p["wq"]), Hq), heads(_mm(a, p["wk"]), Hkv)
-    v = heads(_mm(a, p["wv"]), Hkv).astype(dt)
+    heads = lambda y, n, w: y.reshape(B, T, n, w).transpose(0, 2, 1, 3)  # noqa
+
+    def keyed(w, n):
+        """A projection split into n heads of the key size. Heads that are
+        no whole number of 128-lane tiles make the split a relayout, and
+        left to itself XLA relays out the WEIGHT (compiled for a v5e: a
+        `copy` of bf16[4096, 12288] a layer in every step) to get the
+        product head-major; behind the barrier it relays out the few rows
+        of the product."""
+        y = _mm(a, w)
+        if dk % 128:
+            y = jax.lax.optimization_barrier(y)
+        return heads(y, n, dk)
+
+    q, k = keyed(p["wq"], Hq), keyed(p["wk"], Hkv)
+    v = heads(_mm(a, p["wv"]), Hkv, dv)
+    if cfg.value_scale != 1.0:
+        v = v * cfg.value_scale
+    v = v.astype(dt)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     if cfg.rope_layers[i]:
-        q, k = rotary(q, pos, cfg.rope_theta), rotary(k, pos, cfg.rope_theta)
+        theta, dim = cfg.theta(kind), cfg.rotary_dim(kind)
+        q, k = rotary(q, pos, theta, dim), rotary(k, pos, theta, dim)
     q, k = q.astype(dt), k.astype(dt)
+    # a layer without a sink calls the two as it always has
+    sink = {"sink": p["sink"]} if "sink" in p else {}
     if view is None:
-        window = cfg.window if cfg.layer_kinds[i] == "window" else 0
-        o = band_attention(q, k, v, window)                   # [B,Hq,T,hd]
+        window = cfg.window if kind == "window" else 0
+        o = band_attention(q, k, v, window, **sink)           # [B,Hq,T,dv]
     else:
-        o = paged_attention(q.reshape(B, Hkv, Hq // Hkv, hd), k, v, view)
-        o = o.reshape(B, Hq, 1, hd)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, Hq * hd).astype(F32)
+        o = paged_attention(q.reshape(B, Hkv, Hq // Hkv, dk), k, v, view,
+                            **sink)
+        o = o.reshape(B, Hq, 1, dv)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, Hq * dv).astype(F32)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(_mm(a, p["wg"]))
     return _mm(o, p["wo"]), k, v
@@ -268,15 +411,19 @@ def _layer(cfg, i, p, h, pos, view=None):
     return h + y, k, v, sizes
 
 
-def route_stats(sizes):
+def route_stats(sizes, share=False):
     """int32 [2] from the expert layers' assignment counts: experts that
     got at least one assignment, and the fullest expert's count, each
-    summed over the layers (the host divides by the layers)."""
+    summed over the layers (the host divides by the layers). Where the
+    layers hold a `share` of their experts, a third: the assignments that
+    fell on the experts held."""
     if not sizes:
         return None
-    touched = sum(jnp.sum(s > 0) for s in sizes)
-    fullest = sum(jnp.max(s) for s in sizes)
-    return jnp.stack([touched, fullest]).astype(jnp.int32)
+    stats = [sum(jnp.sum(s > 0) for s in sizes),
+             sum(jnp.max(s) for s in sizes)]
+    if share:
+        stats.append(sum(jnp.sum(s) for s in sizes))
+    return jnp.stack(stats).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -308,17 +455,20 @@ class DecoderBlock(Layer):
 
 def block_leaves(cfg, i, dtype):
     """{name: (shape, mean, std, dtype)} of layer i's parameters."""
-    d, hd = cfg.hidden_size, cfg.head_dim
-    qd, kd = cfg.num_heads * hd, cfg.num_kv_heads * hd
+    d = cfg.hidden_size
+    kind = cfg.layer_kinds[i]
+    hq, hkv, hd, vd = cfg.geometry(kind)
     gain, mat = (1.0, 0.02), (0.0, 0.02)
-    out = {"attn_norm": ((d,),) + gain, "wq": ((d, qd),) + mat,
-           "wk": ((d, kd),) + mat, "wv": ((d, kd),) + mat}
+    out = {"attn_norm": ((d,),) + gain, "wq": ((d, hq * hd),) + mat,
+           "wk": ((d, hkv * hd),) + mat, "wv": ((d, hkv * vd),) + mat}
     if cfg.attn_gate:
-        out["wg"] = ((d, qd),) + mat
+        out["wg"] = ((d, hq * vd),) + mat
     if cfg.qk_norm:
         out["q_norm"] = ((hd,),) + gain
         out["k_norm"] = ((hd,),) + gain
-    out["wo"] = ((qd, d),) + mat
+    if kind in cfg.sink_kinds:
+        out["sink"] = ((hq,), 0.0, 1.0)
+    out["wo"] = ((hq * vd, d),) + mat
     if cfg.sandwich_norm:
         out["post_attn_norm"] = ((d,),) + gain
     out["pre_mlp_norm"] = ((d,),) + gain
@@ -338,7 +488,7 @@ def block_leaves(cfg, i, dtype):
                        s_down=((fs, d),) + mat)
     if cfg.sandwich_norm:
         out["post_mlp_norm"] = ((d,),) + gain
-    return {k: v + (F32 if k == "expert_bias" else dtype,)
+    return {k: v + (F32 if k in ("expert_bias", "sink") else dtype,)
             for k, v in out.items()}
 
 
@@ -353,6 +503,10 @@ class DecoderLM(Layer):
                  abstract=False):
         super().__init__()
         self.cfg = cfg
+        # one chip's share of the experts: the steps then count what fell
+        # on it (`route_stats`)
+        self._share = cfg.moe is not None \
+            and cfg.moe.held[1] < cfg.moe.num_experts
         key = jax.random.PRNGKey(seed)
         V, d = cfg.vocab_size, cfg.hidden_size
         self.embed = _param((V, d), dtype, jax.random.fold_in(key, 0),
@@ -404,7 +558,7 @@ class DecoderLM(Layer):
                 sizes.append(sz)
         if last_row is not None:
             h = jax.lax.dynamic_slice_in_dim(h, last_row, 1, axis=1)
-        return self._logits(h), ks, vs, route_stats(sizes)
+        return self._logits(h), ks, vs, route_stats(sizes, self._share)
 
     def step(self, last, views):
         """last [B, 1] token a slot, views: a LayerCacheView a layer ->
@@ -418,7 +572,7 @@ class DecoderLM(Layer):
             h, _, _, sz = _layer(cfg, i, blk.arrays(), h, pos, views[i])
             if sz is not None:
                 sizes.append(sz)
-        return self._logits(h), route_stats(sizes)
+        return self._logits(h), route_stats(sizes, self._share)
 
     def forward(self, input_ids):
         """[B, T, V] logits of input_ids [B, T] (no cache, no gradient)."""
@@ -439,12 +593,20 @@ class _Serving:
         cfg = model.cfg
         self.model = model
         self.n_layers = cfg.num_layers
-        self.kv_heads, self.head_dim = cfg.num_kv_heads, cfg.head_dim
         self.layer_kinds, self.window = cfg.layer_kinds, cfg.window
+        geo = {kind: cfg.geometry(kind) for kind in set(cfg.layer_kinds)}
+        self.kv_geometry = {kind: g[1:] for kind, g in geo.items()}
         self.max_positions = cfg.max_positions
         self.moe_layers = cfg.mlp_kinds.count("moe")
+        # the grouped-query decode over rings and over full rows, and the
+        # band prefill; each also at a key size that is not the value
+        # size with a sink in the softmax, where a layer has either
         self.selfchecks = ("paged_gqa", "band_flash")
-        if cfg.num_heads == cfg.num_kv_heads and "full" in cfg.layer_kinds:
+        if cfg.sink_kinds or any(g[2] != g[3] for g in geo.values()):
+            self.selfchecks += ("paged_gqa_sink", "band_flash_sink")
+        full = geo.get("full")
+        if full and full[0] == full[1] and full[2] == full[3] \
+                and "full" not in cfg.sink_kinds:
             # one query head a key-value head: a full layer's decode
             # takes GPT's kernel (serving/cache.LayerCacheView.attend)
             self.selfchecks += ("paged",)

@@ -355,6 +355,8 @@ class _GPTServing:
         self.n_layers = len(gpt.layers)
         attn = gpt.layers[0].attn
         self.kv_heads, self.head_dim = attn.num_heads, attn.head_dim
+        self.kv_geometry = {
+            "full": (self.kv_heads, self.head_dim, self.head_dim)}
         self.layer_kinds = ("full",) * self.n_layers
         self.max_positions = \
             gpt.embeddings.position_embeddings.weight.shape[0]

@@ -132,7 +132,9 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
     eval traces never use it. needs_paged=True (the serving engine) adds
     the paged-decode kernel; `needs` names further kernels a served model
     runs ("paged_gqa", "band_flash": the grouped-query decode and the
-    band prefill of models/decoder.py), checked only for such a model.
+    band prefill of models/decoder.py; "paged_gqa_sink",
+    "band_flash_sink": the same at a key size that is not the value size
+    with a sink in the softmax), checked only for such a model.
     Raises whatever the compiler raises, or PallasSelfCheckError on a
     value mismatch."""
     if jax.default_backend() != "tpu":
@@ -144,10 +146,14 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
             checks.append(("flash_dropout", _check_flash_dropout))
         if "band_flash" in needs:
             checks.append(("band_flash", _check_band_flash))
+        if "band_flash_sink" in needs:
+            checks.append(("band_flash_sink", _check_band_flash_sink))
     if needs_paged:
         checks.append(("paged", _check_paged))
     if "paged_gqa" in needs:
         checks.append(("paged_gqa", _check_paged_gqa))
+    if "paged_gqa_sink" in needs:
+        checks.append(("paged_gqa_sink", _check_paged_gqa_sink))
     for name, check in checks:
         if name not in _SELFCHECKED:
             check()
@@ -1908,17 +1914,39 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
 # (lens mod W, min(lens+1, W)) for a window layer's ring, whose rows are in
 # no order and need none under a softmax. The stacked cache is aliased to
 # the output (PR 27's property) and only the 16-row group round the new row
-# is written back. Grid (B, H_kv, rows/block); blocks past the live rows are
-# clamped by the index map and skipped.
+# is written back. Grid (B, H_kv, rows / block); blocks past the live rows
+# are clamped by the index map and skipped.
 #
 # `prefill_band_flash`: causal flash attention forward for a whole prompt,
 # the G query heads of a key-value head folded into the rows of one block,
 # with a grid axis over K/V blocks that visits only the blocks inside the
 # causal band and (window layers) the sliding window: neither K nor V is
 # ever whole in VMEM, so a 14k-token prompt fits.
+#
+# A key row and a value row may differ in size (q and K dk wide, V and the
+# output dv; the scores are scaled by dk ** -0.5), and a layer may have a
+# SINK: one float32 logit a query head that joins the softmax's denominator
+# and takes no value. The running softmax simply starts from it — running
+# max = the sink, running sum = 1, accumulator 0 — where without one it
+# starts from (-inf, 0, 0); nothing else in a kernel knows of it. The band
+# kernel takes both as it is. For decode such a cache keeps K BY COLUMN
+# ([.., dk, rows]: why, at `_paged_kv_decode`) and `_paged_kv_kernel` is
+# `_paged_gqa_kernel` over that layout, with as many key-value heads of a
+# slot a grid step as keep its K and V blocks within `_GQA_STEP_BYTES`
+# (`_gqa_heads`): all 4 heads of a 1024-row block, all 8 of a 128-row ring
+# — few fat steps — and with the new token folded into the softmax's start
+# instead of substituted into a block. A call with dk != dv or
+# with a sink bears a name of its own (`paged_kv_ring_decode`,
+# `paged_kv_rows_decode`, `prefill_kv_band_flash`), so a trace tells it
+# from the calls of a model that has neither, and a ring's call from a
+# full layer's.
 # ---------------------------------------------------------------------------
 
 _APPEND_ROWS = 16     # a bf16 tile's sublanes: the group written back
+# K and V blocks of one grid step of the by-column decode kernel (twice that
+# is in flight): four heads of 1024 rows of a 192-wide key and a 128-wide
+# value
+_GQA_STEP_BYTES = 2560 << 10
 
 
 def _gqa_block(rows, interpret):
@@ -1932,6 +1960,14 @@ def _gqa_block(rows, interpret):
         if rows % b == 0:
             return b
     return None
+
+
+def _gqa_heads(H, block_k, dk, dv, itemsize):
+    """Key-value heads a grid step of `_paged_kv_kernel` carries: the
+    most, of H's divisors, whose K and V blocks stay within
+    _GQA_STEP_BYTES."""
+    fit = max(1, _GQA_STEP_BYTES // (block_k * (dk + dv) * itemsize))
+    return max(h for h in range(1, min(H, fit) + 1) if H % h == 0)
 
 
 def _paged_gqa_kernel(row_ref, live_ref, layer_ref, q_ref, nk_ref, nv_ref,
@@ -2053,34 +2089,253 @@ def _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
     return out, ko, vo
 
 
+def _paged_kv_kernel(row_ref, live_ref, layer_ref, q_ref, nk_ref, nkc_ref,
+                     nv_ref, *refs, block_k, sm_scale, sink):
+    """`_paged_gqa_kernel` for a cache whose K is kept BY COLUMN (a key
+    row dk wide that is no whole number of 128-lane tiles: see
+    `_paged_kv_decode`), `heads` key-value heads of a slot a grid step as
+    the refs' leading axis, and a sink where the layer has one. q [hb, G,
+    dk]; K [hb, dk, block_k]: position along the lanes; V [hb, block_k,
+    dv]; the new token's K as rows [hb, 1, dk] and, for the write-back
+    into the by-column cache, as the columns of ALL the slot's heads side
+    by side [dk, H] (a [.., dk, 1] operand a head would be padded to 128
+    lanes a column: 75 MB a ring layer in HBM), its V [hb, 1, dv].
+
+    The new token starts the running softmax (behind the sink, where there
+    is one) and the row it overwrites is masked out of the blocks, as in
+    `_paged_core`: no block has the row substituted, so a step's only work
+    on a whole block is its two products."""
+    if sink:
+        sink_ref, *refs = refs
+    k_ref, v_ref, o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref = refs
+    del layer_ref                    # read by the index maps only
+    b, j = pl.program_id(0), pl.program_id(2)
+    ar, nl = row_ref[b], live_ref[b]         # append row; live rows
+    jm, ja = (nl - 1) // block_k, ar // block_k
+    off = ar - j * block_k           # the append row within this block
+    cols = ko_ref.shape[2]           # K positions written back: a lane tile
+    first = pl.program_id(1) * ko_ref.shape[0]      # this step's first head
+    cd = k_ref.dtype
+
+    @pl.when(j == 0)
+    def _init():
+        # the sink, where there is one, is the softmax's first, valueless
+        # term (max = the sink, sum = 1); then the new token
+        m0 = sink_ref[...] if sink else jnp.full_like(m_ref, _NEG_INF)
+        l0 = jnp.ones_like(l_ref) if sink else jnp.zeros_like(l_ref)
+        s = jnp.sum(q_ref[...].astype(jnp.float32)
+                    * nk_ref[...].astype(cd).astype(jnp.float32),
+                    axis=2, keepdims=True) * sm_scale         # [hb, G, 1]
+        m1 = jnp.maximum(m0, s)
+        p = jnp.exp(s - m1)                                   # lane-broadcast
+        m_ref[...] = m1
+        l_ref[...] = l0 * jnp.exp(m0 - m1) + p
+        acc_ref[...] = p[:, :, :1] \
+            * nv_ref[...].astype(v_ref.dtype).astype(jnp.float32)
+
+    @pl.when(j == ja)
+    def _append():
+        if cols == block_k:
+            c0, old_k = 0, k_ref[...]
+        else:
+            c0 = pl.multiple_of((off // cols) * cols, cols)
+            old_k = k_ref[:, :, pl.ds(c0, cols)]
+        at = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[1:], 1)
+        cols_of = nkc_ref[...].astype(cd).astype(jnp.float32)   # [dk, H]
+        lane = jax.lax.broadcasted_iota(jnp.int32, cols_of.shape, 1)
+        for h in range(ko_ref.shape[0]):
+            # head h's column: the one lane kept, summed out (exact)
+            col = jnp.sum(jnp.where(lane == first + h, cols_of, 0.0),
+                          axis=1, keepdims=True).astype(cd)     # [dk, 1]
+            ko_ref[h] = jnp.where(at == off - c0, jax.lax.broadcast_in_dim(
+                col, at.shape, (0, 1)), old_k[h])
+        r0 = pl.multiple_of((off // _APPEND_ROWS) * _APPEND_ROWS,
+                            _APPEND_ROWS)
+        at = jax.lax.broadcasted_iota(jnp.int32, vo_ref.shape, 1)
+        vo_ref[...] = jnp.where(
+            at == off - r0,
+            jax.lax.broadcast_in_dim(nv_ref[...].astype(vo_ref.dtype),
+                                     vo_ref.shape, (0, 1, 2)),
+            v_ref[:, pl.ds(r0, _APPEND_ROWS), :])
+
+    def step(last):
+        pos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, block_k), 2)                 # [1, 1, bk]
+        ok = (pos < nl) & (pos != ar)    # live, and not the row overwritten
+        v = v_ref[...]
+        if last:
+            # rows past the live ones were written by no tenant of this
+            # slot: a NaN there would get through 0 * NaN, so select them
+            # to zero (they lie in a slot's last live block alone)
+            rows = jax.lax.broadcasted_iota(jnp.int32, v_ref.shape, 1)
+            v = jnp.where(rows + j * block_k < nl, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale  # [hb, G, bk]
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=2, keepdims=True)
+        l_prev = jnp.max(l_ref[...], axis=2, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jax.lax.broadcast_in_dim(m_new, m_ref.shape, (0, 1, 2))
+        l_ref[...] = jax.lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1, 2))
+
+    pl.when(j < jm)(lambda: step(False))
+    pl.when(j == jm)(lambda: step(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        ell = jnp.max(l_ref[...], axis=2, keepdims=True)
+        # l > 0 always: the new token is in it
+        o_ref[...] = (acc_ref[...] / ell).astype(o_ref.dtype)
+
+
+def _sink_lanes(sink, Hkv, G):
+    """A sink a query head [Hkv * G] as the float32 [Hkv, G, 128] the
+    kernels' running max starts from (lane-broadcast, like m and l)."""
+    return jnp.broadcast_to(
+        sink.astype(jnp.float32).reshape(Hkv, G, 1), (Hkv, G, _LANES))
+
+
+def _paged_kv_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
+                     layer, block_k, interpret, sink=None, heads=None,
+                     name="paged_kv_rows_decode"):
+    """q [B, H, G, dk]; new_k [B, H, 1, dk], new_v [B, H, 1, dv]; K cache
+    BY COLUMN [L, B, H, dk, R], V cache [L, B, H, R, dv]; row/live int32
+    [B]; sink float32 [H * G] or None. Returns (out [B, H, G, dv],
+    k_cache', v_cache'), the caches being the operands updated in place.
+
+    Why by column: a bfloat16 array whose minor dimension is 192 is padded
+    to 256 lanes in a row-major tiled layout, so XLA keeps [.., R, 192]
+    with R minor instead — and a kernel that takes it row-major gets the
+    whole stack copied in and out of every call (compiled for a described
+    v5e: `copy` of bf16[5,192,8,128,192] before and after the custom
+    call). [.., 192, R] is the same bytes with nothing to relayout, and
+    the score product q . K is then the plain [G, dk] x [dk, rows]."""
+    B, H, G, dk = q.shape
+    R, dv = k_cache.shape[4], v_cache.shape[4]
+    hb = heads or _gqa_heads(H, block_k, dk, dv, k_cache.dtype.itemsize)
+    cols = min(_LANES, block_k)      # K positions written back
+
+    def _last(b, live):
+        return (live[b] - 1) // block_k
+
+    def k_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, _I0, jnp.minimum(j, _last(b, live)))
+
+    def v_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, jnp.minimum(j, _last(b, live)), _I0)
+
+    def k_out_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, _I0, row[b] // cols)
+
+    def v_out_map(b, h, j, row, live, layer):
+        return (layer[0], b, h, row[b] // _APPEND_ROWS, _I0)
+
+    def tok_map(b, h, j, row, live, layer):
+        return (b, h, _I0, _I0)
+
+    def sink_map(b, h, j, row, live, layer):
+        return (h, _I0, _I0)
+
+    def tok_spec(rows, d):
+        return pl.BlockSpec((None, hb, rows, d), tok_map)
+
+    def cols_map(b, h, j, row, live, layer):
+        return (b, _I0, _I0)
+
+    tokens = [q, new_k, jnp.swapaxes(new_k[:, :, 0], 1, 2), new_v]
+    tok_specs = [tok_spec(G, dk), tok_spec(1, dk),
+                 pl.BlockSpec((None, dk, H), cols_map), tok_spec(1, dv)]
+    if sink is not None:
+        tokens.append(_sink_lanes(sink, H, G))
+        tok_specs.append(pl.BlockSpec((hb, G, _LANES), sink_map))
+    n_prefetch = 3                     # row, live, layer
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B, H // hb, R // block_k),
+        in_specs=tok_specs + [
+            pl.BlockSpec((None, None, hb, dk, block_k), k_map),
+            pl.BlockSpec((None, None, hb, block_k, dv), v_map)],
+        out_specs=[tok_spec(G, dv),
+                   pl.BlockSpec((None, None, hb, dk, cols), k_out_map),
+                   pl.BlockSpec((None, None, hb, _APPEND_ROWS, dv),
+                                v_out_map)],
+        scratch_shapes=[pltpu.VMEM((hb, G, dv), jnp.float32),
+                        pltpu.VMEM((hb, G, _LANES), jnp.float32),
+                        pltpu.VMEM((hb, G, _LANES), jnp.float32)])
+    kern = functools.partial(_paged_kv_kernel, block_k=block_k,
+                             sm_scale=float(dk) ** -0.5,
+                             sink=sink is not None)
+    # cache operands (after the prefetch and the token operands)
+    # -> outputs 1 and 2
+    n_tok = len(tokens)
+    aliases = {n_prefetch + n_tok: 1, n_prefetch + n_tok + 1: 2}
+    out, ko, vo = _pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, G, dv), q.dtype),
+                   jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        input_output_aliases=aliases, interpret=interpret, name=name)(
+        row.astype(jnp.int32), live.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), *tokens,
+        k_cache, v_cache)
+    return out, ko, vo
+
+
 def paged_gqa_decode_or_none(q, k_cache, v_cache, row, live, new_k, new_v,
-                             *, layer):
-    """Gate + dispatch of the grouped-query paged decode kernel; None when
-    the caller must take its einsum (ineligible shape, or the emulator
-    without FLAGS_paged_flash_interpret)."""
+                             *, layer, sink=None, ring=False, k_cols=False):
+    """Gate + dispatch of the grouped-query paged decode kernels; None
+    when the caller must take its einsum (ineligible shape, or the
+    emulator without FLAGS_paged_flash_interpret). `k_cols`: the K cache
+    is kept by column ([L, B, H, dk, R]: `_paged_kv_decode`, which also
+    takes the `sink`); `ring` says that the layer is a window layer's
+    ring, which names that kernel's call and nothing else."""
     if q.ndim != 4 or k_cache.ndim != 5:
         return None
-    B, H, G, D = q.shape
+    B, H, G, dk = q.shape
+    R, dv = v_cache.shape[3], v_cache.shape[4]
     interpret = jax.default_backend() != "tpu"
-    blk = _gqa_block(k_cache.shape[3], interpret)
+    blk = _gqa_block(R, interpret)
     if blk is None or k_cache.dtype != q.dtype:
         return None
+    if not k_cols and (sink is not None or dk != dv):
+        return None
     if interpret:
-        if not flag("paged_flash_interpret") or B * H > 64 or D > 128:
+        if not flag("paged_flash_interpret") or B * H > 64 \
+                or max(dk, dv) > 128:
             return None
-    elif D % 128 != 0:
+    elif dv % 128 != 0 or dk % (16 if k_cols else 128) != 0:
         return None
     _note_attn_path("paged_gqa")
+    if k_cols:
+        return _paged_kv_decode(
+            q, k_cache, v_cache, row, live, new_k, new_v, layer=layer,
+            block_k=blk, interpret=interpret, sink=sink,
+            name="paged_kv_ring_decode" if ring else "paged_kv_rows_decode")
     return _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v,
                              layer=layer, block_k=blk, interpret=interpret)
 
 
-def _gqa_oracle(q, k, v, ok):
-    """softmax(q.k^T / sqrt(D), masked by ok [.., Tq, Tk]) . v with q
-    [B, H, G, Tq, D] and k, v [B, H, Tk, D], in float32."""
+def _gqa_oracle(q, k, v, ok, sink=None):
+    """softmax(q.k^T / sqrt(dk), masked by ok [.., Tq, Tk]) . v with q
+    [B, H, G, Tq, dk], k [B, H, Tk, dk] and v [B, H, Tk, dv], in float32;
+    `sink` [H * G]: one more column of the scores, dropped after the
+    softmax."""
     s = jnp.einsum("bhgqd,bhkd->bhgqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * (float(q.shape[-1]) ** -0.5)
-    p = jax.nn.softmax(jnp.where(ok, s, _NEG_INF), axis=-1)
+    s = jnp.where(ok, s, _NEG_INF)
+    if sink is not None:
+        col = sink.astype(jnp.float32).reshape(1, q.shape[1], q.shape[2],
+                                               1, 1)
+        s = jnp.concatenate(
+            [s, jnp.broadcast_to(col, s.shape[:-1] + (1,))], -1)
+    p = jax.nn.softmax(s, axis=-1)[..., :k.shape[2]]
     return jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
 
 
@@ -2120,11 +2375,55 @@ def _check_paged_gqa():
                     _max_err(out, want)))
 
 
-def _band_blocks(T, interpret):
-    """(query rows, key rows) of a block of the band kernel, or None."""
+def _check_paged_gqa_sink():
+    """The by-column kernel at a 192-wide key and a 128-wide value with a
+    sink, against the einsum: over 1024-row blocks one head a step (ragged
+    lengths, one slot at the wall), and over a wrapped 128-row ring every
+    head of a slot in one step; every row the call did not append must
+    come back unchanged."""
+    L, B, H, G, dk, dv = 2, 3, 2, 8, 192, 128
+    rs = np.random.RandomState(0)
+    arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
+    q, nk, nv = arr(B, H, G, dk), arr(B, H, 1, dk), arr(B, H, 1, dv)
+    sink = jnp.asarray(rs.randn(H * G), jnp.float32)
+    lens = jnp.asarray([0, 1300, 5000], jnp.int32)
+    run = jax.jit(_paged_kv_decode,
+                  static_argnames=("layer", "block_k", "interpret"))
+    for R, ring in ((2048, False), (128, True)):
+        k, v = arr(L, B, H, dk, R), arr(L, B, H, R, dv)
+        row = lens % R if ring else jnp.minimum(lens, R - 1)
+        live = jnp.minimum(lens + 1, R)
+        out, ko, vo = run(q, k, v, row, live, nk, nv, sink=sink, layer=1,
+                          block_k=_gqa_block(R, False), interpret=False)
+        slots = jnp.arange(B)
+        kb = k.at[1, slots, :, :, row].set(nk[:, :, 0])
+        vb = v.at[1, slots, :, row].set(nv[:, :, 0])
+        ok = (jnp.arange(R)[None, :] < live[:, None])[:, None, None, None]
+        want = _gqa_oracle(q[:, :, :, None], jnp.swapaxes(kb[1], 2, 3),
+                           vb[1], ok, sink)[:, :, :, 0]
+        if not (np.allclose(np.asarray(out, np.float32), np.asarray(want),
+                            rtol=2e-2, atol=2e-2)
+                and np.array_equal(np.asarray(ko), np.asarray(kb))
+                and np.array_equal(np.asarray(vo), np.asarray(vb))):
+            raise PallasSelfCheckError(
+                "by-column paged decode (%d rows) disagrees with the "
+                "einsum on %s: max|out-want|=%.3e" % (
+                    R, jax.devices()[0].device_kind, _max_err(out, want)))
+
+
+def _band_blocks(T, interpret, window=0, G=8):
+    """(query rows, key rows) of a block of the band kernel, or None. A
+    window under 512 rows takes key blocks of its own size (128 or 256:
+    at 512 three quarters of a 128-row window's two blocks would lie
+    outside the band), and more than 8 query heads a key-value head take
+    fewer query rows, so that the score tile stays 1024 rows."""
     if interpret:
         return (8, 8) if T % 8 == 0 and T <= 64 else None
-    return (128, 512) if T % 512 == 0 else None
+    if T % 512:
+        return None
+    block_k = 512 if not window or window > 256 else \
+        128 if window <= 128 else 256
+    return (128 if G <= 8 else max(16, 1024 // G), block_k)
 
 
 def _band_range(i, block_q, block_k, window):
@@ -2134,8 +2433,11 @@ def _band_range(i, block_q, block_k, window):
     return lo, ((i + 1) * block_q - 1) // block_k
 
 
-def _band_flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                       block_q, block_k, window, sm_scale):
+def _band_flash_kernel(q_ref, k_ref, v_ref, *refs, block_q, block_k, window,
+                       sm_scale, sink):
+    if sink:
+        sink_ref, *refs = refs
+    o_ref, acc_ref, m_ref, l_ref = refs
     i, j = pl.program_id(1), pl.program_id(2)
     lo, hi = _band_range(i, block_q, block_k, window)
     kb = lo + j
@@ -2145,8 +2447,13 @@ def _band_flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink:         # the sink is the softmax's first, valueless term
+            m_ref[...] = jnp.broadcast_to(
+                sink_ref[...], (G, block_q, _LANES)).reshape(rows, _LANES)
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     @pl.when(kb <= hi)
     def _step():
@@ -2181,15 +2488,16 @@ def _band_flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         ell = jnp.max(l_ref[...], axis=1, keepdims=True)
-        o_ref[...] = (acc_ref[...] / ell).reshape(G, block_q, d).astype(
+        o_ref[...] = (acc_ref[...] / ell).reshape(o_ref.shape).astype(
             o_ref.dtype)
 
 
-def _band_flash(q, k, v, window, block_q, block_k, interpret):
-    """q [B, Hq, T, D], k/v [B, Hkv, T, D] -> out [B, Hq, T, D]: causal,
-    keys within `window` of the query (0 = all), grouped heads."""
-    B, Hq, T, D = q.shape
-    Hkv = k.shape[1]
+def _band_flash(q, k, v, window, block_q, block_k, interpret, sink=None):
+    """q [B, Hq, T, dk], k [B, Hkv, T, dk], v [B, Hkv, T, dv] -> out [B,
+    Hq, T, dv]: causal, keys within `window` of the query (0 = all),
+    grouped heads; `sink` float32 [Hq] or None."""
+    B, Hq, T, dk = q.shape
+    Hkv, dv = k.shape[1], v.shape[3]
     G = Hq // Hkv
     n_k = T // block_k
     if window:          # the most blocks any query block's band spans
@@ -2202,64 +2510,90 @@ def _band_flash(q, k, v, window, block_q, block_k, interpret):
         lo, hi = _band_range(i, block_q, block_k, window)
         return (h, jnp.minimum(lo + j, hi), _I0)
 
+    def sink_map(h, i, j):
+        return (jax.lax.rem(h, jnp.int32(Hkv)), _I0, _I0, _I0)
+
     kern = functools.partial(_band_flash_kernel, block_q=block_q,
                              block_k=block_k, window=window,
-                             sm_scale=float(D) ** -0.5)
-    q_spec = pl.BlockSpec((None, G, block_q, D), q_map)
-    kv_spec = pl.BlockSpec((None, block_k, D), kv_map)
+                             sm_scale=float(dk) ** -0.5,
+                             sink=sink is not None)
+    operands = [q.reshape(B * Hkv, G, T, dk), k.reshape(B * Hkv, T, dk),
+                v.reshape(B * Hkv, T, dv)]
+    in_specs = [pl.BlockSpec((None, G, block_q, dk), q_map),
+                pl.BlockSpec((None, block_k, dk), kv_map),
+                pl.BlockSpec((None, block_k, dv), kv_map)]
+    name = "prefill_band_flash"
+    if sink is not None or dk != dv:
+        name = "prefill_kv_band_flash"
+    if sink is not None:
+        operands.append(_sink_lanes(sink, Hkv, G)[:, :, None, :])
+        in_specs.append(pl.BlockSpec((None, G, 1, _LANES), sink_map))
     rows = G * block_q
     out = _pallas_call(
         kern, grid=(B * Hkv, T // block_q, n_k),
-        in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, G, block_q, dv), q_map),
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, dv), jnp.float32),
                         pltpu.VMEM((rows, _LANES), jnp.float32),
                         pltpu.VMEM((rows, _LANES), jnp.float32)],
-        interpret=interpret, name="prefill_band_flash")(
-        q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, T, D),
-        v.reshape(B * Hkv, T, D))
-    return out.reshape(B, Hq, T, D)
+        interpret=interpret, name=name)(*operands)
+    return out.reshape(B, Hq, T, dv)
 
 
-def band_flash_attention_or_none(q, k, v, window):
+def band_flash_attention_or_none(q, k, v, window, sink=None):
     """Gate + dispatch of the band prefill kernel; None when the caller
     must take its masked einsum (flag off or ineligible shape; off the
     TPU the emulator takes the small shapes of `_band_blocks` alone, as
     the flash forward's `_shapes_ok` does)."""
     if not flag("use_flash_attention") or q.ndim != 4:
         return None
-    T, D = q.shape[2], q.shape[3]
-    interpret = jax.default_backend() != "tpu"
-    blocks = _band_blocks(T, interpret)
-    if blocks is None or k.shape[2] != T or q.shape[1] % k.shape[1]:
+    T, dk, dv = q.shape[2], q.shape[3], v.shape[3]
+    if k.shape[2] != T or q.shape[1] % k.shape[1]:
         return None
-    if not interpret and D % 128 != 0:
+    interpret = jax.default_backend() != "tpu"
+    blocks = _band_blocks(T, interpret, int(window or 0),
+                          q.shape[1] // k.shape[1])
+    if blocks is None:
+        return None
+    if not interpret and (dk % 64 != 0 or dv % 128 != 0):
         return None
     _note_attn_path("band_flash")
     return _band_flash(q, k, v, int(window or 0), *blocks,
-                       interpret=interpret)
+                       interpret=interpret, sink=sink)
 
 
-def _check_band_flash():
+def _check_band_flash(dk=128, dv=128, sink=False, W=256, Hq=8, Hkv=2):
     """The band kernel, windowed and not, at two key-value heads of four
     query heads each over 1024 positions, against the masked einsum."""
-    B, Hq, Hkv, T, D, W = 1, 8, 2, 1024, 128, 256
+    B, T = 1, 1024
     rs = np.random.RandomState(0)
     arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
-    q, k, v = arr(B, Hq, T, D), arr(B, Hkv, T, D), arr(B, Hkv, T, D)
+    q, k, v = arr(B, Hq, T, dk), arr(B, Hkv, T, dk), arr(B, Hkv, T, dv)
+    b = jnp.asarray(rs.randn(Hq), jnp.float32) if sink else None
     i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
-    run = jax.jit(functools.partial(
-        _band_flash, block_q=128, block_k=512, interpret=False),
-        static_argnames=("window",))
+    run = jax.jit(_band_flash, static_argnames=(
+        "window", "block_q", "block_k", "interpret"))
     for window in (0, W):
-        got = run(q, k, v, window=window)
+        bq, bk = _band_blocks(T, False, window, Hq // Hkv)
+        got = run(q, k, v, window=window, block_q=bq, block_k=bk,
+                  interpret=False, sink=b)
         ok = (j <= i) & ((i - j < window) if window else True)
-        want = _gqa_oracle(q.reshape(B, Hkv, Hq // Hkv, T, D), k, v,
-                           ok).reshape(B, Hq, T, D)
+        want = _gqa_oracle(q.reshape(B, Hkv, Hq // Hkv, T, dk), k, v,
+                           ok, b).reshape(B, Hq, T, dv)
         if not np.allclose(np.asarray(got, np.float32), np.asarray(want),
                            rtol=2e-2, atol=2e-2):
             raise PallasSelfCheckError(
-                "band flash attention (window=%d) disagrees with the "
-                "einsum on %s: max|out-want|=%.3e" % (
-                    window, jax.devices()[0].device_kind,
+                "band flash attention (window=%d, key %d, value %d, "
+                "sink=%s) disagrees with the einsum on %s: "
+                "max|out-want|=%.3e" % (
+                    window, dk, dv, sink, jax.devices()[0].device_kind,
                     _max_err(got, want)))
+
+
+def _check_band_flash_sink():
+    """The same at a 192-wide key and a 128-wide value with a sink, under
+    a 128-row window (key blocks of 128) and with sixteen query heads a
+    key-value head (query blocks of 64)."""
+    _check_band_flash(192, 128, True, W=128)
+    _check_band_flash(192, 128, True, W=128, Hq=16, Hkv=1)

@@ -144,3 +144,83 @@ def test_decoder_decode_step_updates_both_cache_stacks_in_place(
         if m and any(s in m.group(2) for s in stacks):
             assert m.group(3) in ("custom-call", "parameter", "tuple",
                                   "get-tuple-element", "bitcast"), line
+
+
+# MiMo-V2.5's widths (benchmarks/perf/configs/mimo-v2.5.json) on a full
+# dense layer and three window expert layers: the two kinds of stack agree
+# on nothing but the slots
+MIMO = {
+    "hidden_size": 4096, "num_hidden_layers": 4,
+    "hybrid_layer_pattern": [0, 1, 1, 1], "moe_layer_freq": [0, 1, 1, 1],
+    "num_attention_heads": 64, "num_key_value_heads": 4, "head_dim": 192,
+    "v_head_dim": 128, "swa_num_attention_heads": 64,
+    "swa_num_key_value_heads": 8, "swa_head_dim": 192, "swa_v_head_dim": 128,
+    "sliding_window": 128, "partial_rotary_factor": 0.334,
+    "rope_theta": 10000000, "swa_rope_theta": 10000,
+    "attention_value_scale": 0.707, "add_swa_attention_sink_bias": True,
+    "add_full_attention_sink_bias": False, "layernorm_epsilon": 1e-05,
+    "intermediate_size": 16384, "moe_intermediate_size": 2048,
+    "n_routed_experts": 256, "num_experts_per_tok": 8,
+    "norm_topk_prob": True, "scoring_func": "sigmoid", "n_group": 1,
+    "vocab_size": 2048, "max_position_embeddings": 1048576}
+
+
+def test_decode_step_at_unequal_stacks_copies_neither_cache_nor_weight(
+        one_chip, monkeypatch):
+    """`GenerationEngine._decode_fn` at 4 full-layer and 8 ring heads, a
+    192-wide key (kept by column) and a 128-wide value, a sink on the ring
+    and 16 of 256 experts held, compiled for the v5e: each kind's kernel
+    call bears its own name, both stacks are aliased through with no op of
+    a stack's size beside the kernels, and no weight is relaid out inside
+    the step (left to itself XLA copies bf16[4096, 12288] a layer to split
+    the q projection into 192-wide heads: `models/decoder._attention`)."""
+    from paddle_tpu.framework.random import RNG
+    from paddle_tpu.inference.serving.engine import GenerationEngine
+    from paddle_tpu.models.decoder import DecoderConfig, DecoderLM
+    slots, depth = 192, 1024
+    net = DecoderLM(DecoderConfig.from_hf(MIMO, experts_held=(0, 16)),
+                    "bfloat16", abstract=True)
+    # the engine holds two slots; the step is lowered at the cell's 192, so
+    # that the stacks are too large for XLA to stage in fast memory (at a
+    # few tens of MB it copies a ring stack there and back)
+    eng = GenerationEngine(net, max_batch=2, max_seq_len=depth,
+                           prefill_buckets=(512,), kv_dtype="bfloat16")
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+
+    def sds(a, slots_at=None):
+        shape = tuple(a.shape)
+        if slots_at is not None:
+            shape = shape[:slots_at] + (slots,) + shape[slots_at + 1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+
+    state = eng.kv.state()
+    cache = tuple(sds(a, 1) for a in state[:4]) + (sds(state[4], 0),)
+    compiled = eng._jit_decode.lower(
+        [sds(p._data) for p in eng._weights],
+        [sds(b._data) for b in eng._buffers], sds(RNG.key), cache,
+        sds(eng._last, 0)).compile()
+    text = compiled.as_text()
+    assert "paged_kv_ring_decode" in text and "paged_kv_rows_decode" in text
+    assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 9
+    assert eng.kv.k_cols == ("full", "window")
+    assert [a.shape for a in cache[:4]] == [
+        (1, slots, 4, 192, depth), (1, slots, 4, depth, 128),
+        (3, slots, 8, 192, 128), (3, slots, 8, 128, 128)]
+    ma = compiled.memory_analysis()
+    nbytes = slots * 4 * depth * 320 * 2 + 3 * slots * 8 * 128 * 320 * 2
+    assert ma.alias_size_in_bytes >= nbytes
+    # the expert layers' rows and the projections of 192 tokens: well
+    # under the smallest stack (151 MB); the shapes below say the rest
+    assert ma.temp_size_in_bytes < 64 << 20
+    stacks = ("bf16[1,%d,4,192,%d]" % (slots, depth),
+              "bf16[1,%d,4,%d,128]" % (slots, depth),
+              "bf16[3,%d,8,192,128]" % slots, "bf16[3,%d,8,128,128]" % slots)
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) "
+                     r"([\w\-]+)\(", line)
+        if m and any(s in m.group(2) for s in stacks):
+            assert m.group(3) in ("custom-call", "parameter", "tuple",
+                                  "get-tuple-element", "bitcast"), line
+        if m and m.group(3) == "copy":       # no weight relaid out
+            assert "[4096,12288]" not in m.group(2) \
+                and "[12288,4096]" not in m.group(2), line
