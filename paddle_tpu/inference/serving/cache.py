@@ -7,13 +7,18 @@ preallocated at engine construction:
 
     k: [n_layers, max_batch, n_heads, max_seq_len, key size]
     v: [n_layers, max_batch, n_heads, max_seq_len, value size]
-    lens: int32 [max_batch]   (tokens already resident per slot)
+    lens: int32 [max_batch]   (tokens already resident per slot;
+                               0 = the slot holds no request)
 
 and every update is a `jax.lax.dynamic_update_slice` at a traced
 (slot, length) index — all dynamism lives in INDICES, never in shapes
 (the DeepCompile framing: the decode step is one fixed compiled
-program). A slot is "freed" by simply overwriting it on the next
-prefill; no deallocation, no shape change, no recompile.
+program). A slot is freed by its `lens` going to 0 (the decode step that
+no longer counts it live does that, engine.py) and refilled by the next
+prefill overwriting it; no deallocation, no shape change, no recompile.
+`lens == 0` is the ONE encoding of an empty slot — a prompt has at least
+one token — and an empty slot costs a decode step nothing it can avoid:
+the work-list kernel lists no block of it, the other paths clamp to one row.
 
 Two throughput multipliers live here (ROADMAP item 3c):
 
@@ -102,6 +107,13 @@ KV_ROWS_LIVE = metrics.histogram(
     "pt_kv_rows_live",
     "Cache rows the live requests hold in one layer of a kind, one "
     "observation a decode step", labelnames=("kind",),
+    buckets=metrics.exponential_buckets(64, 2, 16))
+KV_ROWS_GIVEN = metrics.histogram(
+    "pt_kv_rows_given",
+    "Cache rows of a full layer a decode step is given to sweep: the "
+    "device's own sum of the slots' lengths at the step's start, one "
+    "observation a decode step. Over pt_kv_rows_live{kind=full} it is the "
+    "share of the sweep that belongs to a request",
     buckets=metrics.exponential_buckets(64, 2, 16))
 
 # env knob: default byte budget for each engine's PrefixCache; 0 disables
@@ -245,7 +257,9 @@ class LayerCacheView:
         [B, H_kv, G, dv] out. `sink` (float32 [H_kv * G], or None) is one
         more logit a query head in the softmax's denominator, which takes
         no value. A full layer appends at row `lens` (a slot that hit the
-        wall rewrites its last row) and attends rows <= lens; a window
+        wall rewrites its last row) and attends rows <= lens (an EMPTY
+        slot, lens == 0, is at most that one row of work, its output
+        belongs to no one, and the work-list kernel gives it 0); a window
         layer appends at `lens mod W` of its ring and attends the ring's
         live rows, which are in no order and need none under a softmax.
         The carrier's arrays are replaced by the updated ones.

@@ -20,7 +20,10 @@ loop. Three executable families cover all of decoding:
     TTFT on a hit is suffix-length cost.
   * decode: ONE compile, ever. All requests, all tokens, all slots run
     the same [max_batch, 1] program; per-slot progress lives in the
-    `lens` index vector (cache.py), never in shapes.
+    `lens` index vector (cache.py), never in shapes, and so does which
+    slots hold a request: `lens == 0` is an empty slot, the step's `live`
+    input (bool [max_batch]) sends a released slot there, and the step's
+    own sum of `lens` comes back with its tokens (`pt_kv_rows_given`).
 
 What the cache holds is `cache.py`'s alone: the engine threads
 `PagedKVCache.state()` through its executables as an opaque tuple (its
@@ -169,14 +172,18 @@ class GenerationEngine:
     Host API (used by the scheduler):
       prefill(slot, prompt) -> first generated token (admits a request);
                                enqueued at once, read at `int(...)`
-      enqueue_decode()      -> None; one more decode step in flight
+      enqueue_decode(live)  -> None; one more decode step in flight, for
+                               the slots `live` names (default: every slot
+                               that was prefilled)
       decode() -> np.int32[max_batch], next token for every slot, of the
                   OLDEST step in flight (enqueues one if none is)
 
-    Inactive slots keep decoding garbage into their (clamped) tail —
-    that is by design: masking slots out would put batch composition
-    into the compiled program's shape. The scheduler simply ignores
-    tokens from slots it has not admitted.
+    An empty slot is one whose `lens` is 0, and that is the only way it
+    is told: batch composition stays out of the compiled program's
+    shape. The step computes a token for it like for any other (the
+    scheduler ignores it) but sweeps no cache row for it, and its length
+    stays 0 until a prefill fills it. The caller says which slots hold a
+    request (`enqueue_decode(live)`); a slot it leaves out goes to 0.
 
     `kv_dtype="int8"` swaps the paged cache for the int8 layout
     (~0.53x bf16 bytes at head_dim 64 — see cache.py); `prefix_cache`
@@ -254,6 +261,8 @@ class GenerationEngine:
         # back in, `_programs` once it was enqueued)
         self._steps = collections.deque()
         self._programs = 0       # programs this engine has enqueued
+        # the live mask of the last decode step: (host copy, device copy)
+        self._live = None
         # instant the latest result reached the host, until the next
         # enqueue has taken its gap from it; None before the first
         # program and across an idle wait
@@ -343,22 +352,33 @@ class GenerationEngine:
                 [v[:, :, p:, :] for v in vs], tl, slot, offset=p,
                 prefix=prefix)
 
-    def _decode_fn(self, arrs, buf_arrs, key, cache, last):
+    def _decode_fn(self, arrs, buf_arrs, key, cache, last, live):
         import jax.numpy as jnp
         self._traces["decode"] += 1
         with self._traced(arrs, buf_arrs, key):
             # one carrier of the stacked arrays; each layer's attention
             # appends its row in place and leaves the updated arrays here
             kv = self.kv.carrier(cache)
+            # `lens == 0` IS "this slot holds no request": a slot the
+            # caller no longer counts live goes to 0 before the step
+            # sweeps anything for it, and only a slot above 0 advances
+            kv.lens = jnp.where(live, kv.lens, 0)
+            rows = jnp.sum(kv.lens, dtype=jnp.int32).reshape(1)
             logits, stats = self._sv.decode(last, self.kv.views(kv))
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            lens = jnp.minimum(kv.lens + 1, jnp.int32(self.max_seq_len))
-            return (kv.state(lens), tok, RNG.key) + self._packed(tok, stats)
+            lens = jnp.where(
+                kv.lens > 0,
+                jnp.minimum(kv.lens + 1, jnp.int32(self.max_seq_len)), 0)
+            # the rows swept (and the routing statistics) go back in the
+            # one array the tokens come back in
+            return (kv.state(lens), tok, RNG.key) + self._packed(
+                tok, rows if stats is None else jnp.concatenate([rows, stats]))
 
     @staticmethod
     def _packed(tok, stats):
-        """A step with routing statistics sends them back in the one
-        array its tokens come back in: int32 [n_tokens + 2 or 3]."""
+        """A program with statistics sends them back in the one array its
+        tokens come back in: int32 [n_tokens + len(stats)] (a decode
+        step's swept rows; an expert model's routing, 2 or 3 numbers)."""
         import jax.numpy as jnp
         if stats is None:
             return ()
@@ -481,11 +501,21 @@ class GenerationEngine:
             self.prefix_cache.store(prompt[:p_store],
                                     self.kv.head(slot, p_store))
 
-    def enqueue_decode(self) -> None:
+    def enqueue_decode(self, live=None) -> None:
         """Enqueue one decode step for the whole batch and return; its
-        tokens wait on the device for a later `decode()`."""
+        tokens wait on the device for a later `decode()`. `live`: bool
+        [max_batch], the slots that hold a request at this enqueue; the
+        step sets every other slot's length to 0 before it attends, and
+        there it stays until a prefill fills the slot. Without it every
+        slot above 0 counts as live (a slot never prefilled stays at 0).
+        The mask goes to the device only when it differs from the last."""
+        mask = np.ones(self.max_batch, np.bool_) if live is None \
+            else np.asarray(live, np.bool_).reshape(self.max_batch)
+        if self._live is None or not np.array_equal(mask, self._live[0]):
+            import jax
+            self._live = (mask, jax.device_put(mask))
         arr = self._run(self._jit_decode, self._decode_tel, "decode",
-                        "host_gap_decode")
+                        "host_gap_decode", self._live[1])
         self._steps.append((arr, self._programs))
 
     def decode(self) -> np.ndarray:
@@ -496,8 +526,10 @@ class GenerationEngine:
         arr, programs = self._steps.popleft()
         out = self._fetch(arr).reshape(-1)
         AHEAD_PCT.observe(100.0 if self._programs > programs else 0.0)
-        if out.size > self.max_batch:   # packed: the tokens, then the stats
-            self._observe_moe(out[self.max_batch:], self.max_batch)
+        # packed: the tokens, the rows the step swept, then the stats
+        cache_mod.KV_ROWS_GIVEN.observe(float(out[self.max_batch]))
+        if out.size > self.max_batch + 1:
+            self._observe_moe(out[self.max_batch + 1:], self.max_batch)
         return out[:self.max_batch]
 
     # -- the host's side of the gap between two programs -------------------

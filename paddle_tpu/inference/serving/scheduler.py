@@ -189,11 +189,8 @@ class ContinuousBatcher:
         self._read_ts = self._period = None
         self.live_slot_steps = 0
         # what the engine's model adds to the prefill / decode_step spans
-        # (expert and window layers); the live rows are counted a step only
-        # where they differ by kind, for a cache that has window layers
+        # (expert and window layers)
         self._span_attrs = dict(getattr(engine, "span_attrs", None) or {})
-        self._kv = engine.kv if self._span_attrs.get("window_layers") \
-            else None
 
     # -- introspection ----------------------------------------------------
 
@@ -397,7 +394,9 @@ class ContinuousBatcher:
         # straight behind a step in flight: it is read one run after it
         # (or was enqueued as that one was read, where the loop held late)
         timed = bool(self._flight) and isinstance(self._flight[-1], _Step)
-        self.engine.enqueue_decode()
+        # the slots that hold a request NOW: one released by count a step
+        # ago, or by an eos read since, is empty to this step already
+        self.engine.enqueue_decode([req is not None for req in self.slots])
         pairs = []
         for slot, req in enumerate(self.slots):
             if req is None:
@@ -452,9 +451,11 @@ class ContinuousBatcher:
             # the token computed for it is dropped
             pairs = [(slot, req) for slot, req in step.pairs
                      if not req.done]
-            if self._kv is not None:
-                self._kv.observe_live_rows(
-                    [len(r.prompt) + len(r.tokens) + 1 for _, r in pairs])
+            # the rows each holds once this step's row is in: what the
+            # step attended (`pt_kv_rows_given` is the device's own count
+            # of the rows it swept before that row, for every slot)
+            self.engine.kv.observe_live_rows(
+                [len(r.prompt) + len(r.tokens) for _, r in pairs])
             for slot, req in pairs:
                 req.tokens.append(int(toks[slot]))
                 ITL_MS.observe((now - req.token_ts[-1]) * 1e3)

@@ -396,7 +396,7 @@ class _GPTServing:
     def decode(self, last, views):
         import jax.numpy as jnp
         # new token's absolute position == tokens already resident;
-        # clamped so idle slots that hit the wall index a real row
+        # clamped so a slot that hit the wall indexes a real row
         pos = jnp.minimum(views[0].lens, self.max_positions - 1)[:, None]
         hidden, _ = self._gpt(Tensor(last, _internal=True),
                               Tensor(pos.astype(jnp.int32), _internal=True),

@@ -1515,9 +1515,13 @@ def flash_attention_or_none(query, key, value, attn_mask, is_causal,
 # block) pairs that hold a live row — slot 0's blocks 0 .. lens[0] //
 # block_k, then slot 1's, and so on (`_paged_work`, computed from `lens` in
 # the jitted step). Its length n is the grid's dynamic bound, so a call is
-# sum_b (lens[b] // block_k + 1) steps — B for an empty cache, at most
-# B * T // block_k — and never a step that fetches or computes nothing; the
-# executable is still compiled once. Folded into the same pass:
+# sum_b (lens[b] // block_k + 1) steps over the slots that hold a request —
+# at most B * T // block_k — and never a step that fetches or computes
+# nothing; the executable is still compiled once. A slot with lens == 0
+# holds no request (a prompt is at least one token, and the serving engine
+# keeps a released slot's lens at 0): it has no entry in the list, no row
+# of it is read or written, and its output rows are zeros. A call in which
+# every slot is empty is a grid of no steps. Folded into the same pass:
 #   * the new token: its score and its value start the running softmax at
 #     a slot's first block (for an int8 cache after quantize_kv's exact
 #     absmax rule, as the einsum path attends the row it has just
@@ -1589,9 +1593,12 @@ def _paged_block(T, H, D, dtype, interpret):
 def _paged_work(lens, T, block_k):
     """The work list of a call: (slot, block, n), int32 [B * T // block_k]
     twice and a scalar. Entry i < n is the i-th (slot, block) pair in slot
-    order, a slot's blocks 0 .. min(lens, T - 1) // block_k in turn; the
-    entries from n on are never visited."""
-    nblk = jnp.minimum(lens, T - 1) // block_k + 1          # [B]
+    order, a slot's blocks 0 .. min(lens, T - 1) // block_k in turn, and
+    no block of an EMPTY slot (lens == 0: it holds no request, the serving
+    engine's one encoding of that); the entries from n on are never
+    visited, and n is 0 where every slot is empty."""
+    nblk = jnp.where(lens > 0,
+                     jnp.minimum(lens, T - 1) // block_k + 1, 0)    # [B]
     end = jnp.cumsum(nblk)
     i = jnp.arange(lens.shape[0] * (T // block_k), dtype=jnp.int32)
     before = end[None, :] <= i[:, None]       # slots wholly before entry i
@@ -1731,7 +1738,8 @@ def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
     q/new_k/new_v: [B, H, 1, D]; caches [L, B, H, T, D] (+f32 scales
     [L, B, H, T] when int8); `layer` an int or an int32 scalar. Returns
     (out, k_cache', v_cache', k_scale'|None, v_scale'|None): the caches
-    are the operands updated in place (aliased), one row a (slot, head).
+    are the operands updated in place (aliased), one row a (slot, head) of
+    the slots with lens > 0; an empty slot's rows stay and its `out` is 0.
 
     The layer rides as a scalar-prefetch operand, not as a constant folded
     into the index maps, and the work list's length as the grid's dynamic
@@ -1808,22 +1816,24 @@ def _paged_decode(q, k_cache, v_cache, lens, new_k, new_v, k_scale,
     outs = _pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                         input_output_aliases=aliases,
                         interpret=interpret)(*prefetch, *operands)
+    out, ko, vo, *scales = outs
+    # an empty slot had no grid step: nothing wrote its output rows
+    out = jnp.where((lens > 0)[:, None, None, None], out, 0)
     if quantized:
-        out, ko, vo, kso, vso = outs
-        return (out, ko, vo, kso.reshape(k_scale.shape),
-                vso.reshape(v_scale.shape))
-    out, ko, vo = outs
+        return (out, ko, vo, scales[0].reshape(k_scale.shape),
+                scales[1].reshape(v_scale.shape))
     return out, ko, vo, None, None
 
 
 def _check_paged():
     """The float paged-decode kernel at a small but representative shape
     (stacked cache of 3 layers, eight heads a block, two blocks a slot;
-    an idle slot, one whose append row opens the second block and one that
+    an empty slot, one whose append row opens the second block and one that
     has crossed into it), called for the middle layer: the output and that
     layer's cache are value-checked against the einsum oracle, and every
-    row the call did not append — the other layers, and the named layer's
-    rows past each slot's length — must come back bit-identical."""
+    row the call did not append — the other layers, the named layer's
+    rows past each slot's length, the whole of the empty slot, whose output
+    is 0 — must come back bit-identical."""
     L, B, H, T, D = 3, 3, 8, 256, 128
     layer = 1
     blk = _paged_block(T, H, D, jnp.float32, interpret=False)
@@ -1854,14 +1864,25 @@ def _check_paged():
     valid = (jnp.arange(T)[None, None, None, :]
              <= lens[:, None, None, None])
     s = jnp.where(valid, s, jnp.float32(_NEG_INF))
-    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vb)
-    out_ok = np.allclose(np.asarray(out), np.asarray(want), rtol=2e-3,
-                         atol=2e-3)
+    want = np.array(jnp.einsum("bhqk,bhkd->bhqd",
+                               jax.nn.softmax(s, axis=-1), vb))
+    # the empty slot on the host (an engine's start compiles nothing for
+    # it): its output 0, its rows what they were
+    empty = np.asarray(lens) == 0
+    want[empty] = 0.0
+    out_ok = np.allclose(np.asarray(out), want, rtol=2e-3, atol=2e-3)
+
+    def stack_as_it_must_be(was, rows):
+        was, rows = np.array(was), np.array(rows)
+        rows[empty] = was[layer][empty]
+        was[layer] = rows
+        return was
+
     # the whole stacked cache, exactly: the call changes one row a
     # (slot, head) of the named layer and nothing else
     cache_ok = all(
-        np.array_equal(np.asarray(got), np.asarray(was.at[layer].set(want)))
-        for got, was, want in ((ko, k, kb), (vo, v, vb)))
+        np.array_equal(np.asarray(got), stack_as_it_must_be(was, rows))
+        for got, was, rows in ((ko, k, kb), (vo, v, vb)))
     if not (out_ok and cache_ok):
         raise PallasSelfCheckError(
             "paged-decode attention disagrees with the einsum oracle on "
@@ -1879,7 +1900,8 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
     attend): q/new_k/new_v [B, H, 1, D], the STACKED
     caches [L, B, H, T, D] (+ scales [L, B, H, T] for int8), `layer` the
     layer this call attends and appends to, lens [B] int32 = live length
-    per slot BEFORE this token. Returns (out, k_cache', v_cache',
+    per slot BEFORE this token, 0 for a slot that holds no request (left
+    as it is, its output rows 0). Returns (out, k_cache', v_cache',
     k_scale', v_scale') — the stacked cache updated in place, carrying
     the appended token — or None when the caller must take its einsum
     (ineligible shape, or interpret mode without

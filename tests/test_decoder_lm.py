@@ -180,6 +180,26 @@ def test_the_run_ahead_loop_gives_the_depth_0_tokens_through_the_rings(
     assert refills >= 3 and b.steps > 0
 
 
+@pytest.mark.parametrize("kernels", [False, True])
+def test_live_tokens_with_empty_slots_are_the_parent_rules(net, kernels):
+    """tests/test_serving.py's hand-driven four slots on the decoder
+    family: empty slots between live ones, an arrival mid-flight, a slot
+    released and refilled; the rings (8 rows) wrap, `lens mod W` of an
+    empty slot is row 0, and the live requests' tokens are the ones they
+    get where every slot advances."""
+    from test_serving import live_tokens_are_the_parent_rules
+    set_flags({"FLAGS_paged_flash_interpret": kernels,
+               "FLAGS_use_flash_attention": kernels})
+    try:
+        live_tokens_are_the_parent_rules(
+            lambda cls: cls(net, max_batch=4, max_seq_len=64,
+                            prefill_buckets=(8, 16), kv_dtype="float32"),
+            CFG["vocab_size"])
+    finally:
+        set_flags({"FLAGS_paged_flash_interpret": False,
+                   "FLAGS_use_flash_attention": True})
+
+
 def test_span_attributes_and_counters_of_a_served_model(net):
     from paddle_tpu.inference.serving import cache as cache_mod
     from paddle_tpu.inference.serving import engine as engine_mod

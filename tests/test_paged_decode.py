@@ -45,7 +45,9 @@ def _quantize_np(x):
 
 def _oracle(q, kc, vc, lens, nk, nv, ks=None, vs=None):
     """Numpy replay of the megakernel contract. Returns
-    (out, kc', vc', ks', vs') with the new token appended at lens[b]."""
+    (out, kc', vc', ks', vs') with the new token appended at lens[b] — of
+    the slots that hold a request: lens[b] == 0 is an EMPTY slot, whose
+    output is 0 and whose rows stay as they are."""
     q = np.asarray(q, np.float32)
     B, H, _, D = q.shape
     kc, vc = np.array(kc), np.array(vc)
@@ -57,6 +59,8 @@ def _oracle(q, kc, vc, lens, nk, nv, ks=None, vs=None):
     out = np.zeros((B, H, 1, D), np.float32)
     for b in range(B):
         ln = int(lens[b])
+        if ln == 0:
+            continue
         if quant:
             kc[b, :, ln] = nkq[b, :, 0]
             vc[b, :, ln] = nvq[b, :, 0]
@@ -175,8 +179,8 @@ class TestPagedDecodeKernel:
         _check(_mk(lens=(5, 40), quantized=True), atol=1e-4)
 
     def test_ragged_lens_with_idle_slots(self):
-        # idle slot (lens=0) sees ONLY its appended token; garbage in
-        # every other position must not reach the output
+        # an empty slot (lens=0) is given no grid step: its output is 0,
+        # no row of it is written, and the garbage it holds reaches nothing
         _check(_mk(B=4, lens=(0, 1, 33, 95)), atol=1e-5)
 
     def test_int8_idle_and_full_slots(self):
@@ -263,17 +267,43 @@ class TestPagedDecodeKernel:
                atol=1e-4 if quantized else 1e-5)
 
     @pytest.mark.parametrize("lens,n", [
-        ((0, 0, 0), 3),                    # a step a slot, even when empty
+        ((0, 0, 0), 0),                    # no step for an empty slot
+        ((0, 31, 0, 64, 0), 1 + 3),        # empty slots between live ones
         ((31, 32, 95), 1 + 2 + 3),
         ((200, 95, 94), 3 + 3 + 3),        # past the wall: clamped
     ])
     def test_work_list_holds_the_live_blocks_in_slot_order(self, lens, n):
         slot, blk, got = pk._paged_work(jnp.asarray(lens, jnp.int32), 96, 32)
-        assert int(got) == n and slot.shape == blk.shape == (9,)
-        want = [(b, j) for b, ln in enumerate(lens)
+        assert int(got) == n and slot.shape == blk.shape == (3 * len(lens),)
+        want = [(b, j) for b, ln in enumerate(lens) if ln
                 for j in range(min(ln, 95) // 32 + 1)]
         assert list(zip(np.asarray(slot)[:n].tolist(),
                         np.asarray(blk)[:n].tolist())) == want
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    @pytest.mark.parametrize("lens", [(0, 0, 0), (0, 40, 0), (95, 0, 1)],
+                             ids=["all-empty", "one-live", "one-empty"])
+    def test_an_empty_slot_is_left_as_it_is_and_given_zeros(self, lens,
+                                                            quantized):
+        # a grid of no steps runs; an empty slot's output rows are exact
+        # zeros whatever garbage its rows hold, and every array of the
+        # cache comes back with that slot bit-identical in every layer
+        args = _mk(B=3, lens=lens, L=3, quantized=quantized)
+        assert int(pk._paged_work(args[3], 96, 32)[2]) == sum(
+            min(n, 95) // 32 + 1 for n in lens if n)
+        out = _run(args)
+        _check(args, atol=1e-4 if quantized else 1e-5)
+        for b, n in enumerate(lens):
+            if n:
+                assert np.abs(np.asarray(out[0])[b]).max() > 0
+                continue
+            assert not np.asarray(out[0])[b].any()
+            for got, before in zip(out[1:], (args[1], args[2], args[6],
+                                             args[7])):
+                if before is not None:
+                    assert np.asarray(got)[:, b].tobytes() == \
+                        np.asarray(before)[:, b].tobytes()
 
     @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
     def test_grid_of_the_served_shape_is_the_work_list(self, kv_dtype):
@@ -300,12 +330,12 @@ class TestPagedDecodeKernel:
             H, blk, D, jnp.dtype(kv_dtype).itemsize) == 1
         # the dynamic bound is the work list's length: the sum of the
         # slots' block counts, B * T // block_k when every slot is full
-        for lens, n in ((np.zeros(B), B), (np.full(B, T), B * T // blk),
-                        (np.arange(B) * 40, None)):
+        for lens, n in ((np.zeros(B), 0), (np.full(B, T), B * T // blk),
+                        (np.ones(B), B), (np.arange(B) * 40, None)):
             slot, _, got = pk._paged_work(jnp.asarray(lens, jnp.int32), T,
                                           blk)
             assert slot.shape == (B * T // blk,)
-            assert B <= int(got) <= B * T // blk <= 192
+            assert np.count_nonzero(lens) <= int(got) <= B * T // blk <= 192
             assert n is None or int(got) == n
 
     @pytest.mark.parametrize("args,want", [
@@ -433,30 +463,34 @@ def test_attend_is_the_one_decode_attention_over_the_cache(case, kernel):
     after = pk.attention_path_counts()
     assert {p for p in after if after[p] != before.get(p, 0)} == {path}
 
-    # the layer as it must be afterwards, in float
+    # the layer as it must be afterwards, in float. An empty slot (lens
+    # == 0) is one row of work to every path but the work-list kernel,
+    # which leaves it as it is and gives it 0
     rows = W if ring else T
     lens = np.asarray(lens)
     row = lens % rows if ring else np.minimum(lens, rows - 1)
     live = np.minimum(lens + 1, rows)
-    slots = np.arange(B)
+    held = lens > 0 if path == "paged_flash" else np.ones(B, bool)
+    slots, row = np.arange(B)[held], row[held]
     want = []
     for name, new in zip(names, (nk, nv)):
         new = np.asarray(new)[:, :, 0]
         if quantized:
             new, new_sc = _quantize_np(new)
             sc = was[name + "_scale"]
-            sc[1, slots, :, row] = new_sc
+            sc[1, slots, :, row] = new_sc[held]
             np.testing.assert_allclose(
                 np.asarray(getattr(kv, name + "_scale")), sc, rtol=2e-5)
-        was[name][1, slots, :, row] = new
+        was[name][1, slots, :, row] = new[held]
         assert np.array_equal(np.asarray(getattr(kv, name)), was[name])
         want.append(np.asarray(dequantize_kv(was[name][1], sc[1]))
                     if quantized else was[name][1])
     ok = (np.arange(rows)[None, :] < live[:, None])[:, None, None, None]
     ref = pk._gqa_oracle(q[:, :, :, None], want[0], want[1],
                          jnp.asarray(ok))[:, :, :, 0]
+    ref = np.where(held[:, None, None, None], np.asarray(ref), 0.0)
     assert out.shape == q.shape and out.dtype == q.dtype
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    np.testing.assert_allclose(np.asarray(out), ref,
                                atol=1e-4 if quantized else 1e-5)
     if ring:                # the other kind's stack is the array it was
         assert kv.k is state[0] and kv.v is state[1]
@@ -564,7 +598,7 @@ class TestEngineFusedPath:
         closed = jax.make_jaxpr(eng._decode_fn)(
             [p._data for p in eng._weights],
             [b._data for b in eng._buffers], RNG.key, eng.kv.state(),
-            eng._last)
+            eng._last, jnp.ones((eng.max_batch,), jnp.bool_))
 
         def walk(jaxpr):
             for eqn in jaxpr.eqns:

@@ -575,7 +575,7 @@ def run_ahead_matches_depth0(eng, vocab, admit_mid_flight=True):
     enqueued, refills = [], []
     enqueue_decode, prefill = eng.enqueue_decode, eng.prefill
 
-    def counted_enqueue():
+    def counted_enqueue(live):
         # no step for a batch whose every request is complete by count:
         # someone's tokens read or in flight (the first token's prefill,
         # the steps that carry the request) fall short of what it asked
@@ -587,7 +587,8 @@ def run_ahead_matches_depth0(eng, vocab, admit_mid_flight=True):
         assert any(owed(r) > 0 for r in b.pending_requests()
                    if r.slot is not None)
         enqueued.append(b.steps)
-        return enqueue_decode()
+        assert list(live) == [r is not None for r in b.slots]
+        return enqueue_decode(live)
 
     def watched_prefill(slot, prompt):
         refills.append(any(r.slot == slot and not r.done
@@ -658,7 +659,8 @@ class TestRunAhead:
         r = b.submit(Request(prompt=_prompt(rs, 5), max_new_tokens=max_new))
         n = []
         enqueue_decode = eng.enqueue_decode
-        eng.enqueue_decode = lambda: (n.append(1), enqueue_decode())[1]
+        eng.enqueue_decode = lambda live: (n.append(1),
+                                           enqueue_decode(live))[1]
         try:
             b.step()
             # by count, all it needs (up to two steps) is in flight or read
@@ -704,10 +706,10 @@ class TestRunAhead:
         enqueue_decode, decode = eng.enqueue_decode, eng.decode
         free, ends = [clk()], []
 
-        def enqueue_8ms():       # the device on this clock: 8 ms a step,
+        def enqueue_8ms(live):   # the device on this clock: 8 ms a step,
             free[0] = max(free[0], clk()) + 0.008    # one after another
             ends.append(free[0])
-            return enqueue_decode()
+            return enqueue_decode(live)
 
         def decode_when_done():
             clk.now = max(clk(), ends.pop(0))
@@ -777,6 +779,252 @@ class TestRunAhead:
         assert not b.idle
         b.run_until_idle()
         assert r.done and b.idle and b.active == 0
+
+
+# ---- an empty slot costs the decode step nothing ----------------------
+
+
+class ParentRuleEngine(GenerationEngine):
+    """The decode step as it was before `lens == 0` meant "empty": every
+    slot advances by one a step, whoever holds it, and no one is told
+    which slots are live."""
+
+    def _decode_fn(self, arrs, buf_arrs, key, cache, last, live):
+        import jax.numpy as jnp
+        from paddle_tpu.framework.random import RNG
+        self._traces["decode"] += 1
+        with self._traced(arrs, buf_arrs, key):
+            kv = self.kv.carrier(cache)
+            rows = jnp.sum(kv.lens, dtype=jnp.int32).reshape(1)
+            logits, stats = self._sv.decode(last, self.kv.views(kv))
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            lens = jnp.minimum(kv.lens + 1, jnp.int32(self.max_seq_len))
+            return (kv.state(lens), tok, RNG.key) + self._packed(
+                tok, rows if stats is None else jnp.concatenate([rows, stats]))
+
+
+def interleaved_tokens(eng, vocab, masked=True):
+    """Four slots driven by hand: slots 0 and 2 hold a request, 1 and 3
+    stay empty; a request arrives in slot 3 mid-flight; slot 0 is
+    released and, three steps on, refilled. -> (the tokens of each of the
+    four requests, `lens` on the device after every step). `masked`
+    False tells the engine nothing (the parent rule's engine is told
+    nothing either way)."""
+    rs = np.random.RandomState(31)
+    prompts = [rs.randint(1, vocab, (n,)).astype(np.int64)
+               for n in (6, 9, 4, 7)]
+    toks, lens = {}, []
+
+    def steps(n, live):
+        for _ in range(n):
+            if masked:
+                eng.enqueue_decode([s in live for s in range(4)])
+            out = eng.decode()
+            lens.append(np.asarray(eng.kv.lens).tolist())
+            for s, name in live.items():
+                toks[name].append(int(out[s]))
+
+    steps(1, {})                   # no slot was ever filled: nothing to do
+    toks["a"] = [int(eng.prefill(0, prompts[0]))]
+    toks["b"] = [int(eng.prefill(2, prompts[1]))]
+    steps(3, {0: "a", 2: "b"})
+    toks["c"] = [int(eng.prefill(3, prompts[2]))]     # arrives mid-flight
+    steps(3, {0: "a", 2: "b", 3: "c"})
+    steps(3, {2: "b", 3: "c"})                        # "a" is released
+    toks["d"] = [int(eng.prefill(0, prompts[3]))]     # its slot refilled
+    steps(4, {0: "d", 2: "b", 3: "c"})
+    return toks, lens
+
+
+def live_tokens_are_the_parent_rules(make_engine, vocab):
+    """`make_engine(cls)` -> a four-slot engine of class `cls`: the live
+    requests' greedy tokens under the rule "an empty slot is `lens == 0`
+    and stays there" are the parent rule's, token for token, and the
+    device's lengths are the live requests' and 0 for everyone else."""
+    want, climbing = interleaved_tokens(make_engine(ParentRuleEngine), vocab)
+    eng = make_engine(GenerationEngine)
+    got, lens = interleaved_tokens(eng, vocab)
+    assert got == want and eng.decode_compiles == 1
+    assert [len(t) for t in got.values()] == [7, 14, 11, 5]
+    # nobody's slot stays at 0; under the parent's rule it climbs
+    assert lens[0] == [0, 0, 0, 0] and climbing[0] == [1, 1, 1, 1]
+    assert lens[3] == [9, 0, 12, 0] and climbing[3] == [9, 4, 12, 4]
+    assert lens[6] == [12, 0, 15, 7]
+    assert lens[7] == [0, 0, 16, 8] and lens[9] == [0, 0, 18, 10]
+    assert lens[-1] == [11, 0, 22, 14] and climbing[-1][1] == 14
+    return eng
+
+
+def enqueues_of(eng, b, run, log=None):
+    """Run `run()` with every decode step the batcher `b` enqueues on
+    `eng` written down (into `log`, for a caller that reads it while it
+    grows): [(the live mask it was given, {slot: (request, the steps
+    enqueued for it before this one)}, `lens` on the device after the
+    step)]."""
+    log = [] if log is None else log
+    enqueue_decode = eng.enqueue_decode
+
+    def logged(live):
+        held = {s: (r, r.max_new_tokens - 1 - b._left[s])
+                for s, r in enumerate(b.slots) if r is not None}
+        enqueue_decode(live)
+        log.append((list(live), held, np.asarray(eng.kv.lens).tolist()))
+    eng.enqueue_decode = logged
+    try:
+        run()
+    finally:
+        del eng.enqueue_decode
+    # what holds whoever is released or admitted: a slot's length is its
+    # request's rows, this step's included, and 0 where no request is
+    for live, held, lens in log:
+        assert live == [s in held for s in range(eng.max_batch)]
+        for s in range(eng.max_batch):
+            r, before = held.get(s, (None, 0))
+            assert lens[s] == (len(r.prompt) + before + 1 if r else 0)
+    return log
+
+
+def eos_request(eng, rs, max_new=10):
+    """A request that its eos stops early (-> it, its depth-0 tokens)."""
+    while True:
+        prompt = _prompt(rs, 5)
+        toks = depth0_tokens(eng, prompt, max_new)
+        for k in range(2, max_new - 3):
+            if toks[k] not in toks[:k]:
+                return Request(prompt=prompt, max_new_tokens=max_new,
+                               eos_id=toks[k]), toks[:k + 1]
+
+
+class TestEmptySlots:
+    @pytest.mark.parametrize("released", ["by_count", "by_eos", "unused"])
+    def test_a_slot_without_a_request_reads_0_from_the_next_enqueue_on(
+            self, released):
+        """Slot 1's request is released while slot 0's keeps the loop
+        stepping: the first step ENQUEUED after the release leaves
+        `lens[1]` at 0 on the device, and so does every later one; a slot
+        nobody was admitted to reads 0 all through."""
+        eng, rs = _shared_engine(), np.random.RandomState(32)
+        b = ContinuousBatcher(eng)
+        long = Request(prompt=_prompt(rs, 6), max_new_tokens=14)
+        if released == "by_eos":
+            short, want = eos_request(eng, rs)
+        else:
+            short, want = Request(prompt=_prompt(rs, 5), max_new_tokens=3), \
+                None
+        b.submit(long)
+        b.submit(short)
+        log = enqueues_of(eng, b, b.run_until_idle)
+        assert len(log) == 13 and len(long.tokens) == 14
+        if released == "unused":
+            assert all(lens[2:] == [0, 0] for _, _, lens in log)
+            return
+        if want is not None:
+            assert short.tokens == want
+        held = [1 in h for _, h, _ in log]
+        last = max(i for i, x in enumerate(held) if x)
+        assert held[:last + 1] == [True] * (last + 1)
+        # by count it leaves with the step that carries its last token;
+        # its eos is learned a step late, so one more step carried it
+        assert last + 1 == len(short.tokens) - (released == "by_count")
+        assert log[last][2][1] == 5 + last + 1
+        after = [lens[1] for _, _, lens in log[last + 1:]]
+        assert len(after) >= 6 and set(after) == {0}
+
+    @pytest.mark.parametrize("released", ["by_count", "by_eos"])
+    def test_a_slot_refilled_in_the_turn_it_was_released_keeps_its_new_rows(
+            self, released):
+        """No decode step lies between the release of slot 0 and the
+        prefill that refills it: the slot's length goes from the old
+        request's to the new prompt's, the next step's mask has it live,
+        and the new request's tokens are the ones it gets alone."""
+        eng, rs = _shared_engine(), np.random.RandomState(33)
+        if released == "by_eos":
+            old, _ = eos_request(eng, rs)
+        else:
+            old = Request(prompt=_prompt(rs, 5), max_new_tokens=2)
+        prompt = _prompt(rs, 9)
+        want = depth0_tokens(eng, prompt, 6)
+        new = Request(prompt=prompt, max_new_tokens=6)
+        b = ContinuousBatcher(eng)
+        prefills, prefill = [], eng.prefill
+
+        def run():
+            b.submit(old)
+            while old.slot is not None or not old.tokens:
+                b.step()
+                if b.slots[0] is None and not new.submit_ts:
+                    b.submit(new)         # waits when the slot falls free
+            b.run_until_idle()
+        eng.prefill = lambda slot, p: (prefills.append((slot, len(log))),
+                                       prefill(slot, p))[1]
+        log = []
+        try:
+            enqueues_of(eng, b, run, log)
+        finally:
+            del eng.prefill
+        assert new.tokens == want and len(old.tokens) >= 2
+        assert [slot for slot, _ in prefills] == [0, 0]
+        refill = prefills[1][1]        # steps enqueued before the refill
+        assert log[refill - 1][0][0] and log[refill - 1][2][0] > 0
+        assert log[refill][0][0] and log[refill][2][0] == 9 + 1
+        assert log[-1][2][0] == 9 + 5
+
+    def test_one_decode_executable_whatever_the_mask(self):
+        """Masks come and go, the executable is one; a mask equal to the
+        last one is not sent to the device again."""
+        eng = GenerationEngine(_tiny(), max_batch=4, max_seq_len=32,
+                               prefill_buckets=(8,))
+        rs = np.random.RandomState(34)
+        for s in range(4):
+            int(eng.prefill(s, _prompt(rs, 3 + s)))
+        sent = []
+        for live in ([1, 1, 1, 1], None, [1, 0, 1, 1], [1, 0, 1, 1],
+                     [0, 0, 1, 1], None, [0, 0, 0, 0], [0, 0, 0, 0]):
+            eng.enqueue_decode(live)
+            sent.append(eng._live[1])
+            eng.decode()
+        assert eng.decode_compiles == 1
+        # `None` is "every slot that holds rows": all four, then the two
+        # still above 0 — the same mask of ones both times
+        assert sent[0] is sent[1] and sent[2] is sent[3]
+        assert sent[6] is sent[7] and sent[4] is not sent[3]
+        assert np.asarray(eng.kv.lens).tolist() == [0, 0, 0, 0]
+        int(eng.prefill(1, _prompt(rs, 5)))
+        assert eng.decode().shape == (4,)
+        assert np.asarray(eng.kv.lens).tolist() == [0, 6, 0, 0]
+        assert eng.decode_compiles == 1
+
+    @pytest.mark.parametrize("kv_dtype,kernel", [
+        ("float32", False), ("float32", True), ("int8", False),
+        ("int8", True)])
+    def test_live_tokens_are_the_parent_rules(self, kv_dtype, kernel):
+        from paddle_tpu.framework.flags import set_flags
+        set_flags({"FLAGS_paged_flash_interpret": kernel})
+        try:
+            live_tokens_are_the_parent_rules(
+                lambda cls: cls(_tiny(), max_batch=4, max_seq_len=32,
+                                prefill_buckets=(8, 16), kv_dtype=kv_dtype,
+                                prefix_cache_bytes=0), VOCAB)
+        finally:
+            set_flags({"FLAGS_paged_flash_interpret": False})
+
+    def test_rows_given_are_the_live_requests_rows(self):
+        """`pt_kv_rows_given` (the device's sum of `lens` at a step's
+        start) against `pt_kv_rows_live{kind=full}` (the scheduler's count
+        at the harvest, the step's own row included): one observation a
+        step each, one row a live slot apart."""
+        from paddle_tpu.inference.serving import cache as cache_mod
+        eng, rs = _shared_engine(), np.random.RandomState(35)
+        given, live = cache_mod.KV_ROWS_GIVEN, \
+            cache_mod.KV_ROWS_LIVE.labels("full")
+        g0, l0 = (given.sum, given.count), (live.sum, live.count)
+        b = ContinuousBatcher(eng)
+        for n, max_new in CHURN:
+            b.submit(Request(prompt=_prompt(rs, n), max_new_tokens=max_new))
+        b.run_until_idle()
+        assert given.count - g0[1] == live.count - l0[1] == b.steps > 10
+        assert (live.sum - l0[0]) - (given.sum - g0[0]) == b.live_slot_steps
+        assert given.sum - g0[0] > 5 * b.live_slot_steps
 
 
 class TestPredictorPoolSharing:

@@ -123,7 +123,8 @@ def test_decoder_decode_step_updates_both_cache_stacks_in_place(
     compiled = eng._jit_decode.lower(
         [sds(p._data) for p in eng._weights],
         [sds(b._data) for b in eng._buffers], sds(RNG.key),
-        tuple(sds(a) for a in eng.kv.state()), sds(eng._last)).compile()
+        tuple(sds(a) for a in eng.kv.state()), sds(eng._last),
+        jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert text.count('name="paged_gqa_decode"') >= 1 or \
         "paged_gqa_decode" in text
@@ -198,7 +199,8 @@ def test_decode_step_at_unequal_stacks_copies_neither_cache_nor_weight(
     compiled = eng._jit_decode.lower(
         [sds(p._data) for p in eng._weights],
         [sds(b._data) for b in eng._buffers], sds(RNG.key), cache,
-        sds(eng._last, 0)).compile()
+        sds(eng._last, 0),
+        jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert "paged_kv_ring_decode" in text and "paged_kv_rows_decode" in text
     assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 9
