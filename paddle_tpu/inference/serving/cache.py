@@ -74,6 +74,21 @@ row is no whole number of the chip's 128-lane tiles, XLA therefore stores
 row-major gets the whole stack copied in and out of every call
 (`ops/pallas_kernels._paged_kv_decode`). `StackedKV.k_cols` names those
 kinds; `insert`, `attend` and the einsum fallback are the only readers.
+
+A third kind, "latent" (multi-head latent attention, every layer of such a
+model): a token's row is ONE array of numbers a layer, (normed latent |
+rotary part), that is key and value at once for every query head — no
+per-head key, no value. Its stack is two arrays of the slot-by-
+`max_seq_len` layout, `c [L, B, max_seq_len, latent size]` BY ROW and `kr
+[L, B, rotary size, max_seq_len]` BY COLUMN, together exactly latent +
+rotary numbers a token: a 512-wide bfloat16 row is four whole lane tiles
+and stays row-major, where one 576-wide row (four tiles and a half) would
+be padded to 640 or stored rows-minor and copied round every kernel call,
+as the 192-wide key was; the 64-wide rotary part by column has the
+positions along the lanes, nothing padded. `insert` takes a prompt's
+rows as `serving().prefill` returns them, `attend` the absorbed query
+(`kv_geometry={"latent": (latent size, rotary size)}`); such a cache has
+no other kind of layer, no int8 rows and no stored head yet.
 """
 from __future__ import annotations
 
@@ -121,11 +136,13 @@ PREFIX_CACHE_BYTES_ENV = "PADDLE_TPU_PREFIX_CACHE_BYTES"
 _PREFIX_CACHE_DEFAULT = 256 << 20
 
 
-def _state_fields(quantized, ring):
+def _state_fields(quantized, ring, latent=False):
     """The arrays of a cache state, in the order the jitted steps thread
     them (their parameter numbers): the scales MUST travel with the
     values they decode, and a model with no window layer has the three
-    (or five) arrays it always had."""
+    (or five) arrays it always had; a latent cache its two."""
+    if latent:
+        return "c", "kr", "lens"
     if quantized:
         return "k", "v", "k_scale", "v_scale", "lens"
     if ring:
@@ -143,17 +160,20 @@ class StackedKV:
     n_heads, max_seq_len] (None otherwise). wk/wv: the window layers'
     rings [n_window_layers, B, ring heads, window, key | value size], None
     when the model has none; `kinds` then says which of the model's layers
-    they are.
+    they are. A latent cache has c [n_layers, B, max_seq_len, latent size]
+    and kr [n_layers, B, rotary size, max_seq_len] in their place.
     `insert` and each layer's `attend` replace the arrays with their
     updated ones."""
 
     __slots__ = ("k", "v", "lens", "k_scale", "v_scale", "wk", "wv", "kinds",
-                 "k_cols")
+                 "k_cols", "c", "kr")
 
-    def __init__(self, k, v, lens, k_scale=None, v_scale=None, wk=None,
-                 wv=None, kinds=None, k_cols=()):
+    def __init__(self, k=None, v=None, lens=None, k_scale=None, v_scale=None,
+                 wk=None, wv=None, kinds=None, k_cols=(), c=None, kr=None):
         self.k = k
         self.v = v
+        self.c = c
+        self.kr = kr
         self.lens = lens
         self.k_scale = k_scale
         self.v_scale = v_scale
@@ -168,7 +188,8 @@ class StackedKV:
         if lens is not None:
             self.lens = lens
         return tuple(getattr(self, f) for f in _state_fields(
-            self.k_scale is not None, self.wk is not None))
+            self.k_scale is not None, self.wk is not None,
+            self.c is not None))
 
     def insert(self, ks, vs, true_len, slot, offset=0, prefix=None):
         """A prompt enters `slot`: ks/vs, a layer's fresh float K/V
@@ -178,12 +199,26 @@ class StackedKV:
         cache is int8), behind a stored head (`PagedKVCache.head`) put
         back VERBATIM at row 0 when `prefix` is given, and the slot's
         length becomes `true_len`. A window layer keeps the prompt's
-        last `window` rows, position p at row p mod window. Runs inside
-        a trace."""
+        last `window` rows, position p at row p mod window. A latent
+        cache is given ks alone, a layer's rows [1, T', latent + rotary
+        size]: the latents go into `c` by row, the rotary parts into `kr`
+        by column. Runs inside a trace."""
         import jax
         import jax.numpy as jnp
         kinds = self.kinds or ("full",) * len(ks)
         upd = jax.lax.dynamic_update_slice
+        if self.c is not None:
+            with jax.named_scope("insert_kv"):
+                rows = jnp.stack(ks)                       # [L,1,T',r+dr]
+                r = self.c.shape[-1]
+                s, z = slot.astype(jnp.int32), jnp.int32(0)
+                o = jnp.int32(offset)
+                self.c = upd(self.c, rows[..., :r].astype(self.c.dtype),
+                             (z, s, o, z))
+                self.kr = upd(self.kr, jnp.swapaxes(
+                    rows[..., r:], 2, 3).astype(self.kr.dtype), (z, s, z, o))
+                self.lens = upd(self.lens, jnp.reshape(true_len, (1,)), (s,))
+            return
         # the scope travels in the HLO's `op_name` metadata (HLO text,
         # xprof's op profile) whatever XLA fuses the insert into; the
         # fusions' instruction names do not change
@@ -235,9 +270,10 @@ class StackedKV:
 class LayerCacheView:
     """One layer of the paged cache during a traced step: `layer` (a
     static int) into the `StackedKV` carrier `kv` that every view of the
-    step shares. `kind`: "full" (rows of `kv.k/v`) or "window" (the ring
-    `kv.wk/wv`); `layer` counts within the stack of its kind. A model's
-    attention hands `attend` the step's q, k, v."""
+    step shares. `kind`: "full" (rows of `kv.k/v`), "window" (the ring
+    `kv.wk/wv`) or "latent" (rows of `kv.c/kr`); `layer` counts within the
+    stack of its kind. A model's attention hands `attend` the step's q, k,
+    v."""
 
     __slots__ = ("kv", "layer", "kind")
 
@@ -250,7 +286,7 @@ class LayerCacheView:
     def lens(self):
         return self.kv.lens
 
-    def attend(self, q, k, v, sink=None):
+    def attend(self, q, k, v, sink=None, scale=None):
         """One new token a slot against this layer of the cache: q
         [B, H_kv, G, dk] (G query heads share a key-value head; GPT has
         G = 1), k [B, H_kv, 1, dk], v [B, H_kv, 1, dv]; arrays in, array
@@ -271,10 +307,31 @@ class LayerCacheView:
         without FLAGS_paged_flash_interpret, an ineligible shape), one
         einsum over all the layer's rows, counter
         pt_attn_path_total{path=xla_paged}. Either way shapes never
-        depend on traced values: decode compiles once."""
+        depend on traced values: decode compiles once.
+
+        A LATENT layer is given its absorbed query q [B, 1, H, latent +
+        rotary size], the new token's row k [B, 1, 1, latent + rotary
+        size] and no v: every head attends the slot's rows, each key (all
+        of it) and value (its latent part) at once, under the softmax
+        `scale` its caller states — the row is wider than the head the
+        scale belongs to; -> [B, 1, H, latent size]. The kernel
+        `paged_latent_decode` (path latent_absorbed), else the einsum
+        (path xla_latent). The other kinds' kernels scale by the key size:
+        for them `scale` stays None."""
         import jax.numpy as jnp
         from ...ops import pallas_kernels as pk
         kv, layer = self.kv, self.layer
+        if self.kind == "latent":
+            fused = pk.paged_latent_decode_or_none(
+                q[:, 0], kv.c, kv.kr, kv.lens, k[:, 0, 0], layer=layer,
+                scale=scale)
+            if fused is None:
+                fused = self._latent_einsum(q[:, 0], k[:, 0, 0], scale)
+            out, kv.c, kv.kr = fused
+            return out[:, None]
+        if scale is not None:
+            raise ValueError("the kernels of a %s layer scale by its key "
+                             "size: no other scale is taken" % self.kind)
         ring = self.kind == "window"
         kc, vc = (kv.wk, kv.wv) if ring else (kv.k, kv.v)
         k_cols = self.kind in kv.k_cols
@@ -293,7 +350,8 @@ class LayerCacheView:
             q, kc, vc, row, live, k, v, layer=layer, sink=sink, ring=ring,
             k_cols=k_cols)
         if fused is None:
-            fused = self._einsum(q, k, v, kc, vc, row, live, sink, k_cols)
+            fused = self._einsum(q, k, v, kc, vc, row, live, sink, k_cols,
+                                 1.0 / math.sqrt(q.shape[-1]))
         out, kc, vc = fused
         if ring:
             kv.wk, kv.wv = kc, vc
@@ -301,12 +359,36 @@ class LayerCacheView:
             kv.k, kv.v = kc, vc
         return out
 
-    def _einsum(self, q, k, v, kc, vc, row, live, sink=None, k_cols=False):
+    def _latent_einsum(self, q, new, scale):
+        """`attend` of a latent layer where no kernel runs: q [B, H, r +
+        dr], the new row [B, r + dr]; scatter it, then one masked float32
+        einsum over every row of the layer. -> (out [B, H, r], c', kr')."""
+        import jax
+        import jax.numpy as jnp
+        from ...ops import pallas_kernels as pk
+        pk._note_attn_path("xla_latent")
+        kv, layer = self.kv, self.layer
+        r, rows = kv.c.shape[-1], kv.c.shape[2]
+        slots = jnp.arange(q.shape[0])
+        row = jnp.minimum(kv.lens, rows - 1)
+        c = kv.c.at[layer, slots, row].set(new[:, :r].astype(kv.c.dtype))
+        kr = kv.kr.at[layer, slots, :, row].set(
+            new[:, r:].astype(kv.kr.dtype))
+        cf, qf = c[layer].astype(jnp.float32), q.astype(jnp.float32)
+        scores = (jnp.einsum("bhr,btr->bht", qf[..., :r], cf)
+                  + jnp.einsum("bhd,bdt->bht", qf[..., r:],
+                               kr[layer].astype(jnp.float32))) * scale
+        ok = jnp.arange(rows)[None, :] <= row[:, None]          # [B, rows]
+        probs = jax.nn.softmax(
+            jnp.where(ok[:, None, :], scores, jnp.float32(-1e30)), axis=-1)
+        return jnp.einsum("bht,btr->bhr", probs, cf).astype(q.dtype), c, kr
+
+    def _einsum(self, q, k, v, kc, vc, row, live, sink, k_cols, scale):
         """`attend` where no kernel runs: scatter the new row (and its
         scales), then one masked float32 einsum over every row of the
-        layer (the sink, where there is one, a last column of the scores
-        that the value product leaves out). -> (out, kc', vc'); updated
-        scales go to the carrier."""
+        layer, the scores times `scale` (the sink, where there is one, a
+        last column of the scores that the value product leaves out). ->
+        (out, kc', vc'); updated scales go to the carrier."""
         import jax
         import jax.numpy as jnp
         from ...ops import pallas_kernels as pk
@@ -338,7 +420,7 @@ class LayerCacheView:
             kf = kf * kv.k_scale[layer][..., None]
             vf = vf * kv.v_scale[layer][..., None]
         scores = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                            kf) * (1.0 / math.sqrt(q.shape[-1]))
+                            kf) * scale
         ok = jnp.arange(vc.shape[3])[None, :] < live[:, None]   # [B, rows]
         scores = jnp.where(ok[:, None, None, :], scores, jnp.float32(-1e30))
         if sink is not None:
@@ -404,27 +486,64 @@ class PagedKVCache:
     the size of a key row and of a value row, of both kinds — unless
     `kv_geometry` says otherwise: {kind: (key-value heads, key size, value
     size)} for the kinds whose stack differs (`geometry` holds the answer
-    for both)."""
+    for both), and then `n_heads` and `head_dim` may be None. A "latent"
+    kind (every layer, or none) has no heads and no value: its geometry
+    is (latent size, rotary size), its stack `c` and `kr` of the module
+    docstring."""
 
-    def __init__(self, n_layers: int, max_batch: int, n_heads: int,
-                 max_seq_len: int, head_dim: int, kv_dtype="float32",
+    def __init__(self, n_layers: int, max_batch: int, n_heads: Optional[int],
+                 max_seq_len: int, head_dim: Optional[int],
+                 kv_dtype="float32",
                  layer_kinds: Optional[Sequence[str]] = None,
                  window: Optional[int] = None, kv_geometry=None):
         import jax.numpy as jnp
         self.n_layers = int(n_layers)
         self.max_batch = int(max_batch)
         self.max_seq_len = int(max_seq_len)
-        self.geometry = {kind: (int(n_heads), int(head_dim), int(head_dim))
-                         for kind in ("full", "window")}
+        self.geometry = {} if n_heads is None else {
+            kind: (int(n_heads), int(head_dim), int(head_dim))
+            for kind in ("full", "window")}
         for kind, g in (kv_geometry or {}).items():
             self.geometry[kind] = tuple(int(x) for x in g)
+        # a kind of K/V stack that is not stated is like the one that is
+        stated = [g for k, g in self.geometry.items() if k != "latent"]
+        for kind in ("full", "window") if stated else ():
+            self.geometry.setdefault(kind, stated[0])
         self.kv_dtype = str(kv_dtype)
         self.quantized = self.kv_dtype == "int8"
         self.layer_kinds = tuple(layer_kinds or ("full",) * self.n_layers)
         if len(self.layer_kinds) != self.n_layers \
-                or set(self.layer_kinds) - {"full", "window"}:
+                or set(self.layer_kinds) - {"full", "window", "latent"}:
             raise ValueError("layer_kinds must name each of the %d layers "
-                             "\"full\" or \"window\"" % self.n_layers)
+                             "\"full\", \"window\" or \"latent\""
+                             % self.n_layers)
+        if set(self.layer_kinds) - set(self.geometry):
+            raise ValueError("no geometry for the layers of kind %s"
+                             % sorted(set(self.layer_kinds)
+                                      - set(self.geometry)))
+        self.lens = jnp.zeros((self.max_batch,), jnp.int32)
+        self.k = self.v = self.wk = self.wv = self.c = self.kr = None
+        self.k_scale = self.v_scale = None
+        self.window, self.k_cols = 0, ()
+        if "latent" in self.layer_kinds:
+            if set(self.layer_kinds) != {"latent"} or self.quantized:
+                raise ValueError("a latent cache has latent layers alone "
+                                 "and no int8 rows yet")
+            r, dr = self.geometry["latent"]
+            lead = (self.n_layers, self.max_batch)
+            self.c = jnp.zeros(lead + (self.max_seq_len, r), self.kv_dtype)
+            self.kr = jnp.zeros(lead + (dr, self.max_seq_len), self.kv_dtype)
+        else:
+            self._kv_stacks(window)
+        self._fields = _state_fields(self.quantized, self.wk is not None,
+                                     self.c is not None)
+        for kind, n in self.nbytes_by_kind().items():
+            KV_BYTES.labels(kind).set(n)
+
+    def _kv_stacks(self, window):
+        """The full layers' K and V (and scales) and the window layers'
+        rings of `window` rows."""
+        import jax.numpy as jnp
         n_window = self.layer_kinds.count("window")
         # a ring never needs more rows than a slot can hold
         self.window = min(int(window or 0), self.max_seq_len)
@@ -450,19 +569,11 @@ class PagedKVCache:
 
         self.k, self.v = stack("full", self.n_layers - n_window,
                                self.max_seq_len)
-        self.lens = jnp.zeros((self.max_batch,), jnp.int32)
         if self.quantized:
             self.k_scale = jnp.zeros(self.k.shape[:-1], jnp.float32)
             self.v_scale = jnp.zeros(self.v.shape[:-1], jnp.float32)
-        else:
-            self.k_scale = self.v_scale = None
         if n_window:
             self.wk, self.wv = stack("window", n_window, self.window)
-        else:
-            self.wk = self.wv = None
-        self._fields = _state_fields(self.quantized, bool(n_window))
-        for kind, n in self.nbytes_by_kind().items():
-            KV_BYTES.labels(kind).set(n)
 
     def layer_index(self, layer: int) -> Tuple[str, int]:
         """(kind, index within that kind's stack) of a model layer."""
@@ -472,6 +583,8 @@ class PagedKVCache:
     def nbytes_by_kind(self) -> dict:
         """Bytes reserved a kind: each stack's own arrays, so unequal
         heads and K and V rows of different sizes count as they are."""
+        if self.c is not None:
+            return {"latent": int(self.c.nbytes) + int(self.kr.nbytes)}
         full = int(self.k.nbytes) + int(self.v.nbytes)
         if self.quantized:
             full += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
@@ -488,7 +601,8 @@ class PagedKVCache:
         requests of these context lengths hold in one layer of it. A row
         is one position's K and V over the kind's heads (`geometry`): its
         bytes are the kind's own, not one size for both stacks."""
-        KV_ROWS_LIVE.labels("full").observe(
+        kind = "latent" if self.c is not None else "full"
+        KV_ROWS_LIVE.labels(kind).observe(
             float(sum(min(n, self.max_seq_len) for n in lengths)))
         if self.wk is not None:
             KV_ROWS_LIVE.labels("window").observe(
@@ -508,13 +622,14 @@ class PagedKVCache:
                 "a state of this cache has %d arrays for kv_dtype=%s, got "
                 "%d (a quantized cache's scales must round-trip with it)"
                 % (len(self._fields), self.kv_dtype, len(state)))
-        for name, arr in zip("kv", state):
-            if str(arr.dtype) != str(self.k.dtype):
+        store = getattr(self, self._fields[0]).dtype
+        for name, arr in zip(self._fields, state[:2]):
+            if str(arr.dtype) != str(store):
                 raise ValueError(
                     "state %s dtype %s does not match this cache's "
                     "kv_dtype=%s storage (%s); rebuild the cache instead "
                     "of mixing quantized and float states"
-                    % (name, arr.dtype, self.kv_dtype, self.k.dtype))
+                    % (name, arr.dtype, self.kv_dtype, store))
         return state
 
     def carrier(self, state) -> StackedKV:
@@ -548,6 +663,9 @@ class PagedKVCache:
         if self.wk is not None:
             raise ValueError("a cache with window layers keeps no prompt "
                              "head: its rings have overwritten it")
+        if self.c is not None:
+            raise ValueError("a latent cache keeps no prompt head yet: no "
+                             "prefill attends stored latents")
         s = int(slot)
         arrays = [self.k[:, s:s + 1, :, :n, :], self.v[:, s:s + 1, :, :n, :]]
         if self.quantized:

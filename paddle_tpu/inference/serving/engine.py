@@ -46,11 +46,14 @@ The engine reads no attribute of any particular model. It calls
 `model.serving()` and asks the answer for what it needs:
 
   n_layers                         the model's layers
-  layer_kinds, window              "full" | "window" a layer (cache.py)
+  layer_kinds, window              "full" | "window" | "latent" a layer
+                                   (cache.py)
   kv_geometry                      {kind: (key-value heads, key size,
                                    value size)}: the two kinds of layer
                                    need agree on none of the three, nor
-                                   a key row with a value row
+                                   a key row with a value row; a latent
+                                   kind has no heads and no value:
+                                   {"latent": (latent size, rotary size)}
   max_positions                    the longest context the model places
   prefix_cache                     whether a stored prompt head can be
                                    re-inserted (not into a ring)
@@ -62,7 +65,9 @@ The engine reads no attribute of any particular model. It calls
                                    of their experts: the assignments that
                                    fell on it) back WITH its tokens
   prefill(ids, true_len[, prefix]) -> (logits [1, 1, V] at the last real
-                                   row, [k a layer], [v a layer], stats)
+                                   row, [k a layer], [v a layer], stats);
+                                   a latent model: [the rows its cache
+                                   keeps, a layer] and None
   decode(last, views)              -> (logits [B, 1, V], stats), the
                                    views' carrier left holding the
                                    updated cache
@@ -243,10 +248,11 @@ class GenerationEngine:
         self._buffers = buffers
         self._mutable = self._weights + buffers
 
-        heads, key_size, _ = next(iter(sv.kv_geometry.values()))
+        # what a kind of layer keeps a token is the model's to say and
+        # the cache's to lay out
         self.kv = cache_mod.PagedKVCache(
-            self._n_layers, self.max_batch, heads, self.max_seq_len,
-            key_size, kv_dtype=kv_dtype, layer_kinds=sv.layer_kinds,
+            self._n_layers, self.max_batch, None, self.max_seq_len, None,
+            kv_dtype=kv_dtype, layer_kinds=sv.layer_kinds,
             window=sv.window, kv_geometry=sv.kv_geometry)
         self._last = jnp.zeros((self.max_batch, 1), jnp.int32)
 

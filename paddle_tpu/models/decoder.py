@@ -19,6 +19,15 @@ model's name:
     positions or none (`layer_kinds`, `rope_layers`), rotate-half over the
     first `rope_dim` of a head's key size, with a base a kind
     (`rope_theta`, `window_rope_theta`);
+  * or, on every layer, multi-head LATENT attention (`latent_rank`, kind
+    "latent"): keys and values are up-projections of one `latent_rank`-wide
+    normed latent a token, and a `latent_rope_dim`-wide rotary part that all
+    the heads share is appended to each head's key. A whole sequence
+    EXPANDS the latent into per-head keys and values (`mla_expand`) and
+    takes the band kernel; one new token a slot ABSORBS the two
+    up-projections into its query and its output (`mla_absorb`) and attends
+    the cached rows themselves, each (latent | rotary part), key and value
+    at once — the two paths are one function of the weights;
   * per layer, a dense SwiGLU or an expert layer (`mlp_kinds`): sigmoid
     router with a bias for the choice, top-k, a shared expert or none, NO
     capacity and no dropped token (`incubate/moe.py`: tokens sorted by
@@ -29,13 +38,16 @@ model's name:
 The mathematics is plain `jax.numpy` over the parameters' arrays: forward
 only (serving and evaluation). There is no backward through the
 framework's tape yet — rotary scaling, softmax and group-limited routing,
-latent attention, chunked prefill and the exchange of a sharded expert
-layer between chips are not here either (ROADMAP R7, R9).
+a low-rank query projection, a suffix prefill over cached latents, chunked
+prefill and the exchange of a sharded expert layer between chips are not
+here either (ROADMAP R7, R8, R9).
 
 `DecoderLM.serving()` answers what `inference/serving/engine.py` asks of a
 model; the sliding-window layers keep a ring of `window` rows in the paged
 cache and the full layers every row, each kind with its own key-value
-heads and its own key and value sizes (`serving/cache.py`).
+heads and its own key and value sizes; a latent layer keeps one row of
+`latent_rank + latent_rope_dim` numbers a token and no per-head key or
+value (`serving/cache.py`).
 """
 from __future__ import annotations
 
@@ -80,7 +92,7 @@ class DecoderConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    layer_kinds: Tuple[str, ...]            # "full" | "window", a layer
+    layer_kinds: Tuple[str, ...]    # "full" | "window" | "latent", a layer
     mlp_kinds: Tuple[str, ...]              # "dense" | "moe", a layer
     dense_width: int
     window: int = 0
@@ -101,15 +113,29 @@ class DecoderConfig:
     window_rope_theta: Optional[float] = None   # None: rope_theta
     value_scale: float = 1.0
     sink_kinds: Tuple[str, ...] = ()        # kinds with a sink a query head
+    #: latent attention: the width of the normed latent a token that every
+    #: head's keys (their first head_dim - latent_rope_dim dims) and values
+    #: are up-projections of, and of the rotary part the heads share
+    latent_rank: int = 0
+    latent_rope_dim: int = 0
 
     def __post_init__(self):
         n = len(self.layer_kinds)
         if len(self.mlp_kinds) != n or len(self.rope_layers) != n:
             raise ValueError("layer_kinds, mlp_kinds and rope_layers must "
                              "name the same layers")
-        if set(self.layer_kinds) - {"full", "window"} \
+        if set(self.layer_kinds) - {"full", "window", "latent"} \
                 or set(self.mlp_kinds) - {"dense", "moe"}:
             raise ValueError("unknown layer kind")
+        if "latent" in self.layer_kinds:
+            if set(self.layer_kinds) != {"latent"}:
+                raise ValueError("latent layers beside full or window "
+                                 "layers are not here")
+            if self.latent_rank < 1 or self.num_kv_heads != self.num_heads \
+                    or not 0 < self.latent_rope_dim < self.head_dim:
+                raise ValueError(
+                    "latent layers need a latent_rank, a latent_rope_dim "
+                    "within a key row and one key head a query head")
         if "window" in self.layer_kinds and self.window < 1:
             raise ValueError("window layers need a window")
         if "moe" in self.mlp_kinds and self.moe is None:
@@ -137,6 +163,8 @@ class DecoderConfig:
                 self.v_head_dim or self.head_dim)
 
     def rotary_dim(self, kind):
+        if kind == "latent":
+            return self.latent_rope_dim
         return self.rope_dim or self.geometry(kind)[2]
 
     def theta(self, kind):
@@ -152,15 +180,20 @@ class DecoderConfig:
         below, then its other spelling):
 
           layer_types ("sliding_attention" | "full_attention") or
-          hybrid_layer_pattern (1 window, 0 full); num_dense_layers
-          (leading) or moe_layer_freq (0 dense, 1 experts, a layer);
-          num_experts / n_routed_experts; num_shared_experts /
+          hybrid_layer_pattern (1 window, 0 full), neither: every layer
+          full; num_dense_layers (leading) or moe_layer_freq (a list: 0
+          dense, 1 experts, a layer; an integer f with
+          first_k_dense_replace k: experts on the layers i >= k with
+          i mod f = 0); num_experts / n_routed_experts; num_shared_experts /
           n_shared_experts; route_norm / norm_topk_prob; route_scale /
           routed_scaling_factor; rms_norm_eps / layernorm_epsilon;
           v_head_dim; swa_num_attention_heads, swa_num_key_value_heads,
           swa_head_dim, swa_v_head_dim (the window layers' own);
           partial_rotary_factor; swa_rope_theta; attention_value_scale;
-          add_swa_attention_sink_bias, add_full_attention_sink_bias.
+          add_swa_attention_sink_bias, add_full_attention_sink_bias;
+          kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+          (latent attention on every layer: a key row is the two qk sizes
+          together and no `head_dim` is read; `q_lora_rank` must be null).
 
         Four parts of the block no published key states; each has a key
         here, and a config that leaves it out gets what the modelling code
@@ -168,9 +201,9 @@ class DecoderConfig:
         `sandwich_norm` (all three on for a config that says `layer_types`,
         off for one that says `hybrid_layer_pattern`) and `rope_layer_kinds`
         (the kinds of layer that rotate: window alone for the first set,
-        both for the second). Rotary scaling other than `default` and
-        group-limited or softmax routing are not here: a config that asks
-        for one is refused."""
+        every kind otherwise). Rotary scaling other than `default`, a
+        low-rank query projection and group-limited or softmax routing are
+        not here: a config that asks for one is refused."""
         def key(*names, default=None):
             for name in names:
                 if cfg.get(name) is not None:
@@ -178,15 +211,28 @@ class DecoderConfig:
             return default
 
         n = int(cfg["num_hidden_layers"])
+        rank = int(cfg.get("kv_lora_rank") or 0)
+        if rank and cfg.get("q_lora_rank") is not None:
+            raise NotImplementedError(
+                "a low-rank query projection (q_lora_rank %r) is not in "
+                "this block" % (cfg["q_lora_rank"],))
         pattern = cfg.get("hybrid_layer_pattern")
-        if pattern is not None:
+        if rank:
+            kinds = ("latent",) * n
+        elif pattern is not None:
             kinds = tuple("window" if k else "full" for k in pattern)
-        else:
+        elif cfg.get("layer_types") is not None:
             kinds = tuple("window" if k == "sliding_attention" else "full"
                           for k in cfg["layer_types"])
+        else:
+            kinds = ("full",) * n
         freq = cfg.get("moe_layer_freq")
-        if freq is not None:
+        if isinstance(freq, (list, tuple)):
             mlps = tuple("moe" if f else "dense" for f in freq)
+        elif freq is not None:
+            first = int(cfg.get("first_k_dense_replace") or 0)
+            mlps = tuple("moe" if i >= first and i % int(freq) == 0
+                         else "dense" for i in range(n))
         else:
             dense = int(cfg.get("num_dense_layers", n))
             mlps = tuple("dense" if i < dense else "moe" for i in range(n))
@@ -217,16 +263,26 @@ class DecoderConfig:
                 experts_held=experts_held)
         heads, kv = int(cfg["num_attention_heads"]), \
             int(cfg["num_key_value_heads"])
-        hd = int(cfg["head_dim"])
+        rope_part = int(cfg["qk_rope_head_dim"]) if rank else 0
+        if rank:
+            hd = int(cfg["qk_nope_head_dim"]) + rope_part
+        elif cfg.get("head_dim") is None:
+            raise KeyError(
+                "head_dim: the config states no size of a head (only a "
+                "latent-attention config, which says qk_nope_head_dim and "
+                "qk_rope_head_dim, goes without)")
+        else:
+            hd = int(cfg["head_dim"])
         vd = int(cfg.get("v_head_dim") or hd)
         win = (int(cfg.get("swa_num_attention_heads") or heads),
                int(cfg.get("swa_num_key_value_heads") or kv),
                int(cfg.get("swa_head_dim") or hd),
                int(cfg.get("swa_v_head_dim") or vd))
-        unstated = pattern is None       # the first set of names: see above
+        # the first set of names: see above
+        unstated = cfg.get("layer_types") is not None and not rank
         rotating = tuple(cfg.get("rope_layer_kinds",
                                  ("window",) if unstated
-                                 else ("full", "window")))
+                                 else ("full", "window", "latent")))
         sinks = tuple(kind for kind, name in (
             ("full", "add_full_attention_sink_bias"),
             ("window", "add_swa_attention_sink_bias")) if cfg.get(name))
@@ -251,7 +307,7 @@ class DecoderConfig:
             window_rope_theta=None if cfg.get("swa_rope_theta") is None
             else float(cfg["swa_rope_theta"]),
             value_scale=float(cfg.get("attention_value_scale") or 1.0),
-            sink_kinds=sinks)
+            sink_kinds=sinks, latent_rank=rank, latent_rope_dim=rope_part)
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +333,22 @@ def rotary(x, pos, theta, dim=None):
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def rotary_pairs(x, pos, theta):
+    """x [B, H, T, d] (float32) at positions pos [B, T]: each ADJACENT pair
+    (x[2i], x[2i+1]) turned by the angle pos * theta^(-2i/d), which is how
+    the latent-attention models rotate (their published code de-interleaves
+    a head and then rotates halves: the same rotation, its output permuted
+    alike for q and k). The neighbour comes by a lane roll, so no array
+    with a minor dimension of 2 is ever made."""
+    d = x.shape[-1]
+    lane = jnp.arange(d)
+    inv = theta ** (-(2 * (lane // 2)).astype(F32) / d)
+    ang = pos.astype(F32)[:, None, :, None] * inv              # [B,1,T,d]
+    other = jnp.where(lane % 2 == 0, -jnp.roll(x, -1, -1),
+                      jnp.roll(x, 1, -1))
+    return x * jnp.cos(ang) + other * jnp.sin(ang)
 
 
 def _mm(x, w):
@@ -324,13 +396,71 @@ def paged_attention(q, k, v, view, sink=None):
     return view.attend(q, k, v, sink)
 
 
+def _latent_attention(cfg, i, p, h, pos, view=None):
+    """`_attention` of a latent layer; returns beside its output the rows
+    the cache keeps, [B, T, latent_rank + latent_rope_dim] = (normed latent
+    | rotary part), and no v. Without `view` the sequence's latents are
+    EXPANDED into every head's keys and values and attended causally. With it
+    the two up-projections are ABSORBED: the query is taken into the
+    latent space, attends the cached rows themselves (each key and value at
+    once) and its output comes back through the values' up-projection.
+    Either way the softmax scale is the expanded key's, 1/sqrt(head_dim)."""
+    from ..ops import pallas_kernels as pk
+    B, T, _ = h.shape
+    H, r, dr = cfg.num_heads, cfg.latent_rank, cfg.latent_rope_dim
+    dk, dv = cfg.head_dim, cfg.v_head_dim or cfg.head_dim
+    dn = dk - dr
+    dt = p["wq"].dtype
+    a = rms_norm(h, p["attn_norm"], cfg.rms_eps).astype(dt)
+    q = _mm(a, p["wq"])
+    if dk % 128:         # as `_attention`'s `keyed`: relay out rows, not wq
+        q = jax.lax.optimization_barrier(q)
+    q = q.reshape(B, T, H, dk).transpose(0, 2, 1, 3)           # [B,H,T,dk]
+    row = _mm(a, p["wkv_a"])                                   # [B,T,r+dr]
+    c = rms_norm(row[..., :r], p["kv_norm"], cfg.rms_eps)
+    q_rope, k_rope = q[..., dn:], row[:, None, :, r:]          # one k head
+    if cfg.rope_layers[i]:
+        theta = cfg.theta("latent")
+        q_rope = rotary_pairs(q_rope, pos, theta)
+        k_rope = rotary_pairs(k_rope, pos, theta)
+    c, k_rope = c.astype(dt), k_rope.astype(dt)
+    rows = jnp.concatenate([c, k_rope[:, 0]], -1)      # what the cache keeps
+    up = p["wkv_b"].reshape(r, H, dn + dv)     # a head: (keys' | values')
+    scale = 1.0 / math.sqrt(dk)
+    if view is None:
+        pk._note_attn_path("latent_expanded")
+        with jax.named_scope("mla_expand"):
+            kv = jnp.einsum("btr,rhn->bhtn", c, up,
+                            preferred_element_type=F32).astype(dt)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            k_rope, (B, H, T, dr))], -1)
+        q = jnp.concatenate([q[..., :dn], q_rope], -1).astype(dt)
+        o = band_attention(q, k, kv[..., dn:], 0)              # [B,H,T,dv]
+    else:
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bhtn,rhn->bthr", q[..., :dn].astype(dt),
+                               up[..., :dn], preferred_element_type=F32)
+        q_row = jnp.concatenate(
+            [q_lat, q_rope.transpose(0, 2, 1, 3)], -1).astype(dt)
+        o_lat = view.attend(q_row, rows[:, None], None,
+                            scale=scale)                       # [B,1,H,r]
+        with jax.named_scope("mla_absorb"):
+            o = jnp.einsum("bthr,rhn->bhtn", o_lat.astype(dt),
+                           up[..., dn:], preferred_element_type=F32)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, H * dv).astype(F32)
+    return _mm(o, p["wo"]), rows, None
+
+
 def _attention(cfg, i, p, h, pos, view=None):
     """Attention branch of layer i on h [B, T, d] (float32) at positions
     pos [B, T]. Without `view`: the whole sequence, returning this
-    layer's k [B, Hkv, T, dk] and v [B, Hkv, T, dv] for the cache; with
-    it: one token a slot through the paged cache."""
+    layer's k [B, Hkv, T, dk] and v [B, Hkv, T, dv] for the cache (a
+    latent layer: its rows and None); with it: one token a slot through
+    the paged cache."""
     B, T, _ = h.shape
     kind = cfg.layer_kinds[i]
+    if kind == "latent":
+        return _latent_attention(cfg, i, p, h, pos, view)
     Hq, Hkv, dk, dv = cfg.geometry(kind)
     dt = p["wq"].dtype
     a = rms_norm(h, p["attn_norm"], cfg.rms_eps).astype(dt)
@@ -459,8 +589,13 @@ def block_leaves(cfg, i, dtype):
     kind = cfg.layer_kinds[i]
     hq, hkv, hd, vd = cfg.geometry(kind)
     gain, mat = (1.0, 0.02), (0.0, 0.02)
-    out = {"attn_norm": ((d,),) + gain, "wq": ((d, hq * hd),) + mat,
-           "wk": ((d, hkv * hd),) + mat, "wv": ((d, hkv * vd),) + mat}
+    out = {"attn_norm": ((d,),) + gain, "wq": ((d, hq * hd),) + mat}
+    if kind == "latent":
+        r, dr = cfg.latent_rank, cfg.latent_rope_dim
+        out.update(wkv_a=((d, r + dr),) + mat, kv_norm=((r,),) + gain,
+                   wkv_b=((r, hq * (hd - dr + vd)),) + mat)
+    else:
+        out.update(wk=((d, hkv * hd),) + mat, wv=((d, hkv * vd),) + mat)
     if cfg.attn_gate:
         out["wg"] = ((d, hq * vd),) + mat
     if cfg.qk_norm:
@@ -543,8 +678,10 @@ class DecoderLM(Layer):
 
     def run(self, ids, last_row=None):
         """ids [B, T] -> (logits, [k a layer], [v a layer], stats): the
-        whole sequence, no cache. `last_row` (traced int) keeps the head
-        to that one position: logits [B, 1, V]."""
+        whole sequence, no cache (a latent layer's k is its cache rows
+        [B, T, latent_rank + latent_rope_dim], its v None). `last_row`
+        (traced int) keeps the head to that one position: logits
+        [B, 1, V]."""
         cfg = self.cfg
         B, T = ids.shape
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -598,6 +735,16 @@ class _Serving:
         self.kv_geometry = {kind: g[1:] for kind, g in geo.items()}
         self.max_positions = cfg.max_positions
         self.moe_layers = cfg.mlp_kinds.count("moe")
+        self.moe_top_k = cfg.moe.top_k if cfg.moe else 0
+        self.moe_experts = cfg.moe.num_experts if cfg.moe else 0
+        if "latent" in geo:
+            # a row of the cache is (latent | rotary part): no per-head
+            # key, no value; the absorbed decode over those rows and the
+            # band prefill at one query head a key head
+            self.kv_geometry = {
+                "latent": (cfg.latent_rank, cfg.latent_rope_dim)}
+            self.selfchecks = ("paged_latent", "band_flash_latent")
+            return
         # the grouped-query decode over rings and over full rows, and the
         # band prefill; each also at a key size that is not the value
         # size with a sink in the softmax, where a layer has either
@@ -610,8 +757,6 @@ class _Serving:
             # one query head a key-value head: a full layer's decode
             # takes GPT's kernel (serving/cache.LayerCacheView.attend)
             self.selfchecks += ("paged",)
-        self.moe_top_k = cfg.moe.top_k if cfg.moe else 0
-        self.moe_experts = cfg.moe.num_experts if cfg.moe else 0
 
     def prefill(self, ids, true_len):
         logits, ks, vs, stats = self.model.run(ids, last_row=true_len - 1)

@@ -134,7 +134,9 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
     runs ("paged_gqa", "band_flash": the grouped-query decode and the
     band prefill of models/decoder.py; "paged_gqa_sink",
     "band_flash_sink": the same at a key size that is not the value size
-    with a sink in the softmax), checked only for such a model.
+    with a sink in the softmax; "paged_latent", "band_flash_latent": the
+    absorbed decode over a latent cache and the band prefill at one query
+    head a key head of a 192-wide key), checked only for such a model.
     Raises whatever the compiler raises, or PallasSelfCheckError on a
     value mismatch."""
     if jax.default_backend() != "tpu":
@@ -148,12 +150,16 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
             checks.append(("band_flash", _check_band_flash))
         if "band_flash_sink" in needs:
             checks.append(("band_flash_sink", _check_band_flash_sink))
+        if "band_flash_latent" in needs:
+            checks.append(("band_flash_latent", _check_band_flash_latent))
     if needs_paged:
         checks.append(("paged", _check_paged))
     if "paged_gqa" in needs:
         checks.append(("paged_gqa", _check_paged_gqa))
     if "paged_gqa_sink" in needs:
         checks.append(("paged_gqa_sink", _check_paged_gqa_sink))
+    if "paged_latent" in needs:
+        checks.append(("paged_latent", _check_paged_latent))
     for name, check in checks:
         if name not in _SELFCHECKED:
             check()
@@ -1391,7 +1397,8 @@ def fused_block_rows(n, hdim, dtype):
 # the metrics registry (pt_attn_path_total{path=}) via _note_attn_path so
 # bench.py and ptdoctor report from one source.
 _ATTN_PATHS = {"flash": 0, "flash_dropout": 0, "xla_sdpa": 0,
-               "xla_chunked": 0, "paged_flash": 0, "xla_paged": 0}
+               "xla_chunked": 0, "paged_flash": 0, "xla_paged": 0,
+               "latent_absorbed": 0, "latent_expanded": 0, "xla_latent": 0}
 
 _ATTN_HELP = "Attention implementations traced, by path"
 
@@ -2433,19 +2440,277 @@ def _check_paged_gqa_sink():
                     R, jax.devices()[0].device_kind, _max_err(out, want)))
 
 
+# ---------------------------------------------------------------------------
+# The absorbed decode of latent attention (models/decoder.py, kind "latent")
+#
+# The cache keeps ONE row a token a layer, (normed latent | rotary part),
+# that is key and value at once for every query head: `c [L, B, T, r]` by
+# row (r = 512: four whole lane tiles) and `kr [L, B, dr, T]` by column
+# (dr = 64: the positions along the lanes), see serving/cache.py for why
+# two arrays. The query arrives absorbed, q_lat [B, H, r] and q_rope
+# [B, H, dr], and a head's score on a row is (q_lat . c + q_rope . kr) *
+# scale, its output the probabilities times c.
+#
+# `paged_latent_decode` is `_paged_core`'s way round that cache: the grid
+# walks a work list of the live (slot, block) pairs — no block of an empty
+# slot (lens == 0), none past a slot's rows — every head of the slot in the
+# step; ONE block of rows of c is read a step and serves the scores and
+# the values; the new token starts the running softmax at a slot's first
+# block and its row goes back, with the 16-row group of c and the 128-lane
+# tile of kr round it, through outputs aliased to the cache at the slot's
+# last. The caller states the scale: the row is wider than the head the
+# scale belongs to.
+# ---------------------------------------------------------------------------
+
+def _paged_latent_kernel(lens_ref, layer_ref, slot_ref, blk_ref, ql_ref,
+                         qr_ref, nc_ref, nkr_ref, nkc_ref, c_ref, kr_ref,
+                         o_ref, co_ref, kro_ref, acc_ref, m_ref, l_ref, *,
+                         block_k, t_max, sm_scale):
+    """Grid (n,); this body runs once for entry i of the work list: block j
+    of slot b. q_lat [H, r], q_rope [H, dr]; the new row's latent [1, r],
+    its rotary part as a row [1, dr] and as a column broadcast over a lane
+    tile [dr, cols]; c [block_k, r]; kr [dr, block_k]. State (acc / m / l)
+    lives in VMEM scratch across a slot's blocks, as in `_paged_core`."""
+    del layer_ref                    # read by the index maps only
+    i = pl.program_id(0)
+    b, j = slot_ref[i], blk_ref[i]
+    cl = jnp.minimum(lens_ref[b], t_max - 1)    # the append row
+    ja = cl // block_k                          # its block
+    off = cl - j * block_k
+    cd = c_ref.dtype
+    cols = kro_ref.shape[1]
+    ql, qr = ql_ref[...], qr_ref[...]
+
+    @pl.when(j == 0)
+    def _init():
+        nc = nc_ref[...].astype(cd).astype(jnp.float32)         # [1, r]
+        nkr = nkr_ref[...].astype(cd).astype(jnp.float32)       # [1, dr]
+        s = (jnp.sum(ql.astype(jnp.float32) * nc, axis=1, keepdims=True)
+             + jnp.sum(qr.astype(jnp.float32) * nkr, axis=1,
+                       keepdims=True)) * sm_scale               # [H, 1]
+        m_ref[...] = jnp.broadcast_to(s, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(nc, acc_ref.shape)
+
+    def step(last):
+        pos = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        live = pos < off             # before the append row: a live one
+        c = c_ref[...]
+        s = (jax.lax.dot_general(
+            ql, c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(
+                qr, kr_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)) * sm_scale  # [H, bk]
+        s = jnp.where(live, s, _NEG_INF)
+        if last:
+            # rows from the append row on were written by no tenant of
+            # this slot: a NaN there would get through 0 * NaN, so select
+            # them to zero (they lie in a slot's last block alone)
+            rows = jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+            c = jnp.where(rows < off, c, jnp.zeros_like(c))
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(cd), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [H, r]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    pl.when(j < ja)(lambda: step(False))
+    pl.when((j == ja) & (off > 0))(lambda: step(True))
+
+    @pl.when(j == ja)
+    def _append_and_finish():
+        r0 = pl.multiple_of((off // _APPEND_ROWS) * _APPEND_ROWS,
+                            _APPEND_ROWS)
+        at = jax.lax.broadcasted_iota(jnp.int32, co_ref.shape, 0)
+        co_ref[...] = jnp.where(
+            at == off - r0,
+            jnp.broadcast_to(nc_ref[...].astype(cd), co_ref.shape),
+            c_ref[pl.ds(r0, _APPEND_ROWS), :])
+        if cols == block_k:
+            c0, old = 0, kr_ref[...]
+        else:
+            c0 = pl.multiple_of((off // cols) * cols, cols)
+            old = kr_ref[:, pl.ds(c0, cols)]
+        lane = jax.lax.broadcasted_iota(jnp.int32, kro_ref.shape, 1)
+        kro_ref[...] = jnp.where(lane == off - c0,
+                                 nkc_ref[...].astype(cd), old)
+        # l >= 1 always: the new token is in it
+        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def _paged_latent_decode(q_lat, q_rope, c_cache, kr_cache, lens, new_c,
+                         new_kr, *, layer, block_k, scale, interpret):
+    """q_lat [B, H, r], q_rope [B, H, dr]; the new rows' latents new_c
+    [B, r] and rotary parts new_kr [B, dr]; c_cache [L, B, T, r], kr_cache
+    [L, B, dr, T]; lens int32 [B], each slot's rows BEFORE this token, 0
+    for a slot that holds no request. Returns (out [B, H, r], c_cache',
+    kr_cache'): the caches are the operands updated in place (aliased), one
+    row a slot with lens > 0; an empty slot's rows stay and its `out` is 0.
+    The layer rides as a scalar-prefetch operand and the work list's
+    length as the grid's dynamic bound, as in `_paged_decode`."""
+    B, H, r = q_lat.shape
+    dr, T = kr_cache.shape[2], kr_cache.shape[3]
+    cols = min(_LANES, block_k)      # positions of kr written back
+    lens = lens.astype(jnp.int32)
+    slot, blk, n_work = _paged_work(lens, T, block_k)
+
+    def _row(b, lens):
+        return jnp.minimum(lens[b], T - 1)
+
+    def tok_map(i, lens, layer, slot, blk):
+        return (slot[i], _I0, _I0)
+
+    def c_map(i, lens, layer, slot, blk):
+        return (layer[0], slot[i], blk[i], _I0)
+
+    def kr_map(i, lens, layer, slot, blk):
+        return (layer[0], slot[i], _I0, blk[i])
+
+    def c_out_map(i, lens, layer, slot, blk):
+        return (layer[0], slot[i], _row(slot[i], lens) // _APPEND_ROWS, _I0)
+
+    def kr_out_map(i, lens, layer, slot, blk):
+        return (layer[0], slot[i], _I0, _row(slot[i], lens) // cols)
+
+    def tok_spec(rows, d):
+        return pl.BlockSpec((None, rows, d), tok_map)
+
+    dt = c_cache.dtype
+    tokens = [q_lat, q_rope, new_c[:, None, :], new_kr[:, None, :],
+              jnp.broadcast_to(new_kr.astype(dt)[:, :, None], (B, dr, cols))]
+    prefetch = [lens, jnp.asarray(layer, jnp.int32).reshape(1), slot, blk]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_work,),
+        in_specs=[tok_spec(H, r), tok_spec(H, dr), tok_spec(1, r),
+                  tok_spec(1, dr), tok_spec(dr, cols),
+                  pl.BlockSpec((None, None, block_k, r), c_map),
+                  pl.BlockSpec((None, None, dr, block_k), kr_map)],
+        out_specs=[tok_spec(H, r),
+                   pl.BlockSpec((None, None, _APPEND_ROWS, r), c_out_map),
+                   pl.BlockSpec((None, None, dr, cols), kr_out_map)],
+        scratch_shapes=[pltpu.VMEM((H, r), jnp.float32),
+                        pltpu.VMEM((H, _LANES), jnp.float32),
+                        pltpu.VMEM((H, _LANES), jnp.float32)])
+    kern = functools.partial(_paged_latent_kernel, block_k=block_k,
+                             t_max=T, sm_scale=float(scale))
+    # cache operands (after the prefetch and the token operands)
+    # -> outputs 1 and 2
+    n_in = len(prefetch) + len(tokens)
+    out, co, kro = _pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
+                   jax.ShapeDtypeStruct(c_cache.shape, dt),
+                   jax.ShapeDtypeStruct(kr_cache.shape, dt)],
+        input_output_aliases={n_in: 1, n_in + 1: 2}, interpret=interpret,
+        name="paged_latent_decode")(*prefetch, *tokens, c_cache, kr_cache)
+    # an empty slot had no grid step: nothing wrote its output rows
+    return jnp.where((lens > 0)[:, None, None], out, 0), co, kro
+
+
+def paged_latent_decode_or_none(q, c_cache, kr_cache, lens, new, *, layer,
+                                scale):
+    """Gate + dispatch of the latent decode kernel. q [B, H, r + dr], the
+    absorbed query (latent part | rotary part); `new` [B, r + dr], the new
+    token's row; caches as `_paged_latent_decode` takes them. None when
+    the caller must take its einsum (ineligible shape, or the emulator
+    without FLAGS_paged_flash_interpret). Bumps
+    pt_attn_path_total{path=latent_absorbed} at trace time when it fires."""
+    if q.ndim != 3 or c_cache.ndim != 4 or kr_cache.ndim != 4:
+        return None
+    B, H, _ = q.shape
+    T, r, dr = c_cache.shape[2], c_cache.shape[3], kr_cache.shape[2]
+    interpret = jax.default_backend() != "tpu"
+    blk = _gqa_block(T, interpret)       # rows of c a grid step reads
+    if blk is None or c_cache.dtype != q.dtype:
+        return None
+    if interpret:
+        if not flag("paged_flash_interpret") or B * H > 64 or r + dr > 128:
+            return None
+    elif r % 128 != 0 or dr % 16 != 0 or H % 8 != 0:
+        return None
+    _note_attn_path("latent_absorbed")
+    return _paged_latent_decode(
+        q[..., :r], q[..., r:], c_cache, kr_cache, lens, new[:, :r],
+        new[:, r:], layer=layer, block_k=blk, scale=scale,
+        interpret=interpret)
+
+
+def _latent_oracle(q, c, kr, ok, scale):
+    """softmax((q_lat . c + q_rope . kr) * scale, masked by ok [B, T]) . c
+    with q [B, H, r + dr], c [B, T, r], kr [B, dr, T], in float32."""
+    r = c.shape[-1]
+    qf, cf = q.astype(jnp.float32), c.astype(jnp.float32)
+    s = (jnp.einsum("bhr,btr->bht", qf[..., :r], cf)
+         + jnp.einsum("bhd,bdt->bht", qf[..., r:],
+                      kr.astype(jnp.float32))) * scale
+    p = jax.nn.softmax(jnp.where(ok[:, None, :], s, _NEG_INF), axis=-1)
+    return jnp.einsum("bht,btr->bhr", p, cf)
+
+
+def _check_paged_latent():
+    """The latent decode kernel at the published sizes (16 heads over a
+    512-wide latent and a 64-wide rotary part, scale 1/sqrt(192)) on a
+    stack of two layers: an empty slot, one whose append row opens a block,
+    one in the middle of its second block and one at the wall, against the
+    einsum; every row the call did not append must come back unchanged and
+    the empty slot's output is 0."""
+    L, B, H, r, dr, T = 2, 4, 16, 512, 64, 4096
+    rs = np.random.RandomState(0)
+    arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
+    q, new = arr(B, H, r + dr), arr(B, r + dr)
+    c, kr = arr(L, B, T, r), arr(L, B, dr, T)
+    lens = jnp.asarray([0, 1024, 1300, 5000], jnp.int32)
+    scale = 192.0 ** -0.5
+    run = jax.jit(functools.partial(
+        _paged_latent_decode, layer=1, block_k=_gqa_block(T, False),
+        scale=scale, interpret=False))
+    out, co, kro = run(q[..., :r], q[..., r:], c, kr, lens, new[:, :r],
+                       new[:, r:])
+    live = np.asarray(lens) > 0
+    row = jnp.minimum(lens, T - 1)
+    slots = jnp.arange(B)
+    cb = np.array(c.at[1, slots, row].set(new[:, :r]))
+    krb = np.array(kr.at[1, slots, :, row].set(new[:, r:]))
+    ok = jnp.arange(T)[None, :] <= row[:, None]
+    want = np.array(_latent_oracle(q, cb[1], krb[1], ok, scale))
+    # the empty slot on the host: its output 0, its rows what they were
+    want[~live] = 0.0
+    cb[1][~live], krb[1][~live] = np.asarray(c)[1][~live], \
+        np.asarray(kr)[1][~live]
+    if not (np.allclose(np.asarray(out, np.float32), want, rtol=2e-2,
+                        atol=2e-2)
+            and np.array_equal(np.asarray(co), cb)
+            and np.array_equal(np.asarray(kro), krb)):
+        raise PallasSelfCheckError(
+            "latent paged decode disagrees with the einsum on %s: "
+            "max|out-want|=%.3e" % (jax.devices()[0].device_kind,
+                                    _max_err(out, want)))
+
+
 def _band_blocks(T, interpret, window=0, G=8):
     """(query rows, key rows) of a block of the band kernel, or None. A
     window under 512 rows takes key blocks of its own size (128 or 256:
     at 512 three quarters of a 128-row window's two blocks would lie
-    outside the band), and more than 8 query heads a key-value head take
-    fewer query rows, so that the score tile stays 1024 rows."""
+    outside the band), and the query rows are as many as keep the score
+    tile at 1024 rows (128 at 8 query heads a key-value head, 64 at 16),
+    at most 512: one query head a key head (a latent model's expanded
+    prefill) takes 512 rows a step, where 128 would leave a step a fifth
+    of a microsecond of products and the grid's own cost most of it."""
     if interpret:
         return (8, 8) if T % 8 == 0 and T <= 64 else None
     if T % 512:
         return None
     block_k = 512 if not window or window > 256 else \
         128 if window <= 128 else 256
-    return (128 if G <= 8 else max(16, 1024 // G), block_k)
+    return (max(16, min(512, 1024 // G)), block_k)
 
 
 def _band_range(i, block_q, block_k, window):
@@ -2596,7 +2861,7 @@ def _check_band_flash(dk=128, dv=128, sink=False, W=256, Hq=8, Hkv=2):
     i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
     run = jax.jit(_band_flash, static_argnames=(
         "window", "block_q", "block_k", "interpret"))
-    for window in (0, W):
+    for window in sorted({0, W}):
         bq, bk = _band_blocks(T, False, window, Hq // Hkv)
         got = run(q, k, v, window=window, block_q=bq, block_k=bk,
                   interpret=False, sink=b)
@@ -2619,3 +2884,10 @@ def _check_band_flash_sink():
     key-value head (query blocks of 64)."""
     _check_band_flash(192, 128, True, W=128)
     _check_band_flash(192, 128, True, W=128, Hq=16, Hkv=1)
+
+
+def _check_band_flash_latent():
+    """The same at a 192-wide key, a 128-wide value and ONE query head a
+    key head (query blocks of 512), no sink: what the expanded prefill of
+    a latent-attention model asks of the band kernel."""
+    _check_band_flash(192, 128, False, W=0, Hq=4, Hkv=4)

@@ -226,3 +226,111 @@ def test_decode_step_at_unequal_stacks_copies_neither_cache_nor_weight(
         if m and m.group(3) == "copy":       # no weight relaid out
             assert "[4096,12288]" not in m.group(2) \
                 and "[12288,4096]" not in m.group(2), line
+
+
+# Kimi-VL-A3B's language model (benchmarks/perf/configs/kimi-vl-a3b.json) on
+# the dense layer and two expert layers: latent attention, a cache of one
+# 576-wide row a token
+KIMI = {
+    "hidden_size": 2048, "num_hidden_layers": 3, "num_attention_heads": 16,
+    "num_key_value_heads": 16, "kv_lora_rank": 512, "q_lora_rank": None,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "rope_theta": 800000, "rope_scaling": None, "rms_norm_eps": 1e-05,
+    "intermediate_size": 11264, "moe_intermediate_size": 1408,
+    "moe_layer_freq": 1, "first_k_dense_replace": 1, "n_routed_experts": 64,
+    "n_shared_experts": 2, "num_experts_per_tok": 6, "norm_topk_prob": True,
+    "routed_scaling_factor": 2.446, "scoring_func": "sigmoid", "n_group": 1,
+    "vocab_size": 2048, "max_position_embeddings": 131072}
+
+
+def _kimi_engine(monkeypatch, depth, buckets):
+    from paddle_tpu.inference.serving.engine import GenerationEngine
+    from paddle_tpu.models.decoder import DecoderConfig, DecoderLM
+    net = DecoderLM(DecoderConfig.from_hf(KIMI, experts_held=(0, 8)),
+                    "bfloat16", abstract=True)
+    # the engine holds two slots; the steps are lowered at the cell's 48
+    eng = GenerationEngine(net, max_batch=2, max_seq_len=depth,
+                           prefill_buckets=buckets, kv_dtype="bfloat16")
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+    return eng
+
+
+def _lowered_args(eng, one_chip, slots):
+    from paddle_tpu.framework.random import RNG
+
+    def sds(a, slots_at=None):
+        shape = tuple(a.shape)
+        if slots_at is not None:
+            shape = shape[:slots_at] + (slots,) + shape[slots_at + 1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+
+    state = eng.kv.state()
+    cache = tuple(sds(a, 1) for a in state[:-1]) + (sds(state[-1], 0),)
+    return ([sds(p._data) for p in eng._weights],
+            [sds(b._data) for b in eng._buffers], sds(RNG.key), cache,
+            sds(eng._last, 0))
+
+
+def _no_op_of_a_stacks_size(text, stacks, but=()):
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) "
+                     r"([\w\-]+)\(", line)
+        if m and any(s in m.group(2) for s in stacks):
+            assert m.group(3) in ("custom-call", "parameter", "tuple",
+                                  "get-tuple-element", "bitcast") + but, line
+
+
+def test_latent_decode_step_holds_no_copy_of_a_latent_stack(
+        one_chip, monkeypatch):
+    """`GenerationEngine._decode_fn` of a latent-attention model at the
+    published widths and the cell's 48 slots x 16 384 rows, compiled for
+    the v5e: one `paged_latent_decode` call a layer, both arrays of the
+    latent stack (the latents by row, the rotary parts by column) aliased
+    through with no op of a stack's size beside the kernels, temporaries
+    of a few megabytes, and no weight relaid out inside the step."""
+    slots, depth = 48, 16384
+    eng = _kimi_engine(monkeypatch, depth, (2048,))
+    assert eng.kv._fields == ("c", "kr", "lens")
+    args = _lowered_args(eng, one_chip, slots)
+    assert [a.shape for a in args[3][:2]] == [
+        (3, slots, depth, 512), (3, slots, 64, depth)]
+    compiled = eng._jit_decode.lower(
+        *args, jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"paged_latent_decode\S* = ", text)) == 3
+    assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 6
+    ma = compiled.memory_analysis()
+    nbytes = 3 * slots * depth * 576 * 2
+    assert ma.alias_size_in_bytes >= nbytes
+    assert ma.temp_size_in_bytes < 32 << 20
+    _no_op_of_a_stacks_size(text, ("bf16[3,%d,%d,512]" % (slots, depth),
+                                   "bf16[3,%d,64,%d]" % (slots, depth)))
+    for line in text.splitlines():          # wq is read where it lies
+        m = re.match(r"\s*%?([\w.\-]+) = (\S+) copy\(", line)
+        if m:
+            assert "[2048,3072]" not in m.group(2) \
+                and "[3072,2048]" not in m.group(2), line
+
+
+def test_latent_prefill_expands_and_inserts_in_place(one_chip, monkeypatch):
+    """`GenerationEngine._prefill_fn` at an 8 192-row bucket: the band
+    kernel runs at one query head a key head of a 192-wide key (512 query
+    rows a step), the prompt's rows enter both arrays of the stack in
+    place, and nothing of a stack's size is made beside them."""
+    slots, depth, bucket = 48, 16384, 8192
+    eng = _kimi_engine(monkeypatch, depth, (bucket,))
+    args = _lowered_args(eng, one_chip, slots)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = eng._jit_prefill.lower(
+        *args, jax.ShapeDtypeStruct((1, bucket), jnp.int32,
+                                    sharding=one_chip), i32, i32).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"prefill_kv_band_flash\S* = ", text)) == 3
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 3 * slots * depth * 576 * 2
+    assert ma.temp_size_in_bytes < 3 << 30
+    _no_op_of_a_stacks_size(
+        text, ("bf16[3,%d,%d,512]" % (slots, depth),
+               "bf16[3,%d,64,%d]" % (slots, depth)),
+        but=("fusion", "dynamic-update-slice"))
