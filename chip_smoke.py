@@ -336,8 +336,10 @@ def server_phase(model_ctor, max_batch, max_seq_len, buckets,
              "decode must compile once, prefill once per bucket", report)
     _require(report["prefix_hits"] >= 1 and report["suffix_compiles"] >= 1,
              "the shared prefix was not reused", report)
+    # a prompt takes the band kernel where its shape is eligible (a value
+    # row of whole lane tiles on the chip), else the flash forward
     _require(ap["paged_flash"] > 0 and ap["xla_paged"] == 0
-             and ap["flash"] > 0 and ap["xla_sdpa"] == 0,
+             and ap["flash"] + ap["band_flash"] > 0 and ap["xla_sdpa"] == 0,
              "attention not on the Pallas kernels", ap)
     _require(report["crash_bundles"] == 0, "the serving loop crashed",
              bundles)

@@ -149,8 +149,11 @@ class GPTAttention(Layer):
             return self.out_proj(out), cache
         if cache is not None:
             prefix_len = cache[0].shape[2]
-            k = mp.concat([cache[0], k], axis=2)
-            v = mp.concat([cache[1], v], axis=2)
+            if prefix_len:
+                # an empty cache is not concatenated: K and V stay in the
+                # model's dtype whatever dtype the empty arrays have
+                k = mp.concat([cache[0], k], axis=2)
+                v = mp.concat([cache[1], v], axis=2)
             cache = (k, v)
             if q.shape[2] > 1 and prefix_len > 0:
                 # serving suffix-prefill: multi-token queries behind a
@@ -161,6 +164,18 @@ class GPTAttention(Layer):
                 return self.out_proj(out), cache
         causal = cache is None or q.shape[2] > 1
         out = None
+        if cache is not None and causal and not self.training:
+            # a whole prompt into a cache of a model in eval mode is
+            # inference (no backward, no lse, no dropout): the band
+            # kernel's one query head a key head, window 0 case, as the
+            # decoder family's prompts take it; it bears no name, so a
+            # trace reads it under this prefill's own
+            # (`custom-call._prefill_fn`)
+            from ..ops import pallas_kernels as pk
+            out = pk.band_flash_attention_or_none(
+                q._data, k._data, v._data, 0, named=False)
+            if out is not None:
+                out = Tensor(out, _internal=True)
         if cache is None:
             # sequence-parallel ring/ulysses attention when a sep axis is
             # active (sep_utils; NEW vs reference — SURVEY.md §5)
@@ -346,7 +361,7 @@ class _GPTServing:
     a query head, learned positions, the head tied to the embedding."""
 
     prefix_cache = True
-    selfchecks = ("paged",)
+    selfchecks = ("paged", "band_flash_mha")
     window = 0
     moe_layers = moe_top_k = moe_experts = 0
 
@@ -375,9 +390,13 @@ class _GPTServing:
         gpt = self._gpt
         if prefix is None:
             pos = None
-            legacy = [(Tensor(jnp.zeros((1, self.kv_heads, 0, self.head_dim),
-                                        jnp.float32), _internal=True),) * 2
-                      for _ in range(self.n_layers)]
+            # no rows yet: the layers hand back their own K and V, in the
+            # model's dtype (an empty cache is never concatenated)
+            empty = Tensor(jnp.zeros(
+                (1, self.kv_heads, 0, self.head_dim),
+                gpt.embeddings.word_embeddings.weight._data.dtype),
+                _internal=True)
+            legacy = [(empty, empty)] * self.n_layers
         else:
             pk, pv = prefix
             legacy = [(Tensor(pk[i], _internal=True),

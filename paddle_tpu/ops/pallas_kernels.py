@@ -136,7 +136,9 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
     "band_flash_sink": the same at a key size that is not the value size
     with a sink in the softmax; "paged_latent", "band_flash_latent": the
     absorbed decode over a latent cache and the band prefill at one query
-    head a key head of a 192-wide key), checked only for such a model.
+    head a key head of a 192-wide key; "band_flash_mha": GPT's prompts,
+    one query head a key head of 128, over 768 rows in one block and over
+    1024 in blocks of 512), checked only for such a model.
     Raises whatever the compiler raises, or PallasSelfCheckError on a
     value mismatch."""
     if jax.default_backend() != "tpu":
@@ -152,6 +154,8 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
             checks.append(("band_flash_sink", _check_band_flash_sink))
         if "band_flash_latent" in needs:
             checks.append(("band_flash_latent", _check_band_flash_latent))
+        if "band_flash_mha" in needs:
+            checks.append(("band_flash_mha", _check_band_flash_mha))
     if needs_paged:
         checks.append(("paged", _check_paged))
     if "paged_gqa" in needs:
@@ -1398,6 +1402,7 @@ def fused_block_rows(n, hdim, dtype):
 # bench.py and ptdoctor report from one source.
 _ATTN_PATHS = {"flash": 0, "flash_dropout": 0, "xla_sdpa": 0,
                "xla_chunked": 0, "paged_flash": 0, "xla_paged": 0,
+               "band_flash": 0,
                "latent_absorbed": 0, "latent_expanded": 0, "xla_latent": 0}
 
 _ATTN_HELP = "Attention implementations traced, by path"
@@ -1950,7 +1955,10 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
 # the G query heads of a key-value head folded into the rows of one block,
 # with a grid axis over K/V blocks that visits only the blocks inside the
 # causal band and (window layers) the sliding window: neither K nor V is
-# ever whole in VMEM, so a 14k-token prompt fits.
+# ever whole in VMEM, so a 14k-token prompt fits. It is the one kernel a
+# prompt takes on the generation server, GPT's included (G = 1, window 0,
+# no name: `band_flash_attention_or_none`); the flash pair above, with its
+# lse, dropout and backward, is the trainer's.
 #
 # A key row and a value row may differ in size (q and K dk wide, V and the
 # output dv; the scores are scaled by dk ** -0.5), and a layer may have a
@@ -2695,22 +2703,42 @@ def _check_paged_latent():
                                     _max_err(out, want)))
 
 
+def _band_tile(T, cap):
+    """The most rows, at most `cap` and in whole 128-row tiles where `cap`
+    holds one, of a block that tiles T (a multiple of 128) exactly."""
+    b = cap - cap % _LANES or cap
+    while T % b:
+        b -= _LANES
+    return b
+
+
 def _band_blocks(T, interpret, window=0, G=8):
-    """(query rows, key rows) of a block of the band kernel, or None. A
-    window under 512 rows takes key blocks of its own size (128 or 256:
+    """(query rows, key rows) of a block of the band kernel, or None: a
+    rule of (T, G, window), T a multiple of 128. A key block is 512 rows,
+    under a window of at most 256 rows the window's own size (128 or 256:
     at 512 three quarters of a 128-row window's two blocks would lie
-    outside the band), and the query rows are as many as keep the score
-    tile at 1024 rows (128 at 8 query heads a key-value head, 64 at 16),
-    at most 512: one query head a key head (a latent model's expanded
-    prefill) takes 512 rows a step, where 128 would leave a step a fifth
-    of a microsecond of products and the grid's own cost most of it."""
+    outside the band); the query rows are as many as keep the score tile
+    at 1024 rows (128 at 8 query heads a key-value head, 64 at 16), at
+    most 512: one query head a key head (GPT's prompts; a latent model's
+    expanded prefill) takes 512 rows a step, where 128 would leave a step
+    a fifth of a microsecond of products and the grid's own cost most of
+    it. Where such a block does not tile T: a prompt of under two key
+    blocks' rows whose heads' score tile stays within 1024 rows is ONE
+    block a key-value head (768 rows at one query head a key head: one
+    step that computes the whole square took 52 us a call on the v5e
+    where the three 384-row steps of the causal band took 76 and 256-row
+    blocks 89: PERF.md section 6, PR 35); else the block is the largest
+    whole number of 128-row tiles under it that tiles T."""
     if interpret:
         return (8, 8) if T % 8 == 0 and T <= 64 else None
-    if T % 512:
+    if T % _LANES:
         return None
-    block_k = 512 if not window or window > 256 else \
+    rows = max(16, min(512, 1024 // G))
+    keys = 512 if not window or window > 256 else \
         128 if window <= 128 else 256
-    return (max(16, min(512, 1024 // G)), block_k)
+    if T % keys and T < 2 * keys and G * T <= 1024:
+        return (T, T)
+    return (_band_tile(T, rows), _band_tile(T, keys))
 
 
 def _band_range(i, block_q, block_k, window):
@@ -2779,10 +2807,12 @@ def _band_flash_kernel(q_ref, k_ref, v_ref, *refs, block_q, block_k, window,
             o_ref.dtype)
 
 
-def _band_flash(q, k, v, window, block_q, block_k, interpret, sink=None):
+def _band_flash(q, k, v, window, block_q, block_k, interpret, sink=None,
+                named=True):
     """q [B, Hq, T, dk], k [B, Hkv, T, dk], v [B, Hkv, T, dv] -> out [B,
     Hq, T, dv]: causal, keys within `window` of the query (0 = all),
-    grouped heads; `sink` float32 [Hq] or None."""
+    grouped heads; `sink` float32 [Hq] or None. A call that is not
+    `named` bears its jitted function's name in a trace."""
     B, Hq, T, dk = q.shape
     Hkv, dv = k.shape[1], v.shape[3]
     G = Hq // Hkv
@@ -2809,9 +2839,10 @@ def _band_flash(q, k, v, window, block_q, block_k, interpret, sink=None):
     in_specs = [pl.BlockSpec((None, G, block_q, dk), q_map),
                 pl.BlockSpec((None, block_k, dk), kv_map),
                 pl.BlockSpec((None, block_k, dv), kv_map)]
-    name = "prefill_band_flash"
-    if sink is not None or dk != dv:
-        name = "prefill_kv_band_flash"
+    name = None
+    if named:
+        name = "prefill_kv_band_flash" if sink is not None or dk != dv \
+            else "prefill_band_flash"
     if sink is not None:
         operands.append(_sink_lanes(sink, Hkv, G)[:, :, None, :])
         in_specs.append(pl.BlockSpec((None, G, 1, _LANES), sink_map))
@@ -2828,11 +2859,20 @@ def _band_flash(q, k, v, window, block_q, block_k, interpret, sink=None):
     return out.reshape(B, Hq, T, dv)
 
 
-def band_flash_attention_or_none(q, k, v, window, sink=None):
+def band_flash_attention_or_none(q, k, v, window, sink=None, named=True):
     """Gate + dispatch of the band prefill kernel; None when the caller
-    must take its masked einsum (flag off or ineligible shape; off the
-    TPU the emulator takes the small shapes of `_band_blocks` alone, as
-    the flash forward's `_shapes_ok` does)."""
+    must take another path (flag off or ineligible shape; off the TPU the
+    emulator takes the small shapes of `_band_blocks` alone, as the flash
+    forward's `_shapes_ok` does). It is the ONE attention a prompt takes
+    on the generation server, whatever the family: the decoder family's
+    `band_attention` calls it with its window, sink and grouped heads,
+    GPT's `_GPTServing`-driven forward (`models/gpt.py::GPTAttention`)
+    as the G = 1, window 0 case, `named=False` so that the call keeps the
+    name of GPT's jitted prefill in a trace. It has no backward, no lse
+    and no dropout: the trainer's attention (no cache at the call site)
+    stays `flash_attention_or_none`. Which of the two a call takes is
+    decided where it is made, from whether a cache is handed in — no
+    flag beyond `FLAGS_use_flash_attention`, which gates both."""
     if not flag("use_flash_attention") or q.ndim != 4:
         return None
     T, dk, dv = q.shape[2], q.shape[3], v.shape[3]
@@ -2847,13 +2887,14 @@ def band_flash_attention_or_none(q, k, v, window, sink=None):
         return None
     _note_attn_path("band_flash")
     return _band_flash(q, k, v, int(window or 0), *blocks,
-                       interpret=interpret, sink=sink)
+                       interpret=interpret, sink=sink, named=named)
 
 
-def _check_band_flash(dk=128, dv=128, sink=False, W=256, Hq=8, Hkv=2):
+def _check_band_flash(dk=128, dv=128, sink=False, W=256, Hq=8, Hkv=2,
+                      T=1024):
     """The band kernel, windowed and not, at two key-value heads of four
     query heads each over 1024 positions, against the masked einsum."""
-    B, T = 1, 1024
+    B = 1
     rs = np.random.RandomState(0)
     arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
     q, k, v = arr(B, Hq, T, dk), arr(B, Hkv, T, dk), arr(B, Hkv, T, dv)
@@ -2891,3 +2932,12 @@ def _check_band_flash_latent():
     key head (query blocks of 512), no sink: what the expanded prefill of
     a latent-attention model asks of the band kernel."""
     _check_band_flash(192, 128, False, W=0, Hq=4, Hkv=4)
+
+
+def _check_band_flash_mha():
+    """The same at one query head a key head, key and value 128 wide, no
+    window: what a GPT prompt asks of the band kernel, over 768 positions
+    (one block a head, the whole square) and over 1024 (512-row blocks:
+    the diagonal, a block below it and a step that is skipped)."""
+    for T in (768, 1024):
+        _check_band_flash(W=0, Hq=2, Hkv=2, T=T)

@@ -334,3 +334,30 @@ def test_latent_prefill_expands_and_inserts_in_place(one_chip, monkeypatch):
         text, ("bf16[3,%d,%d,512]" % (slots, depth),
                "bf16[3,%d,64,%d]" % (slots, depth)),
         but=("fusion", "dynamic-update-slice"))
+
+
+@pytest.mark.parametrize("bucket", [128, 256, 512, 768, 1024])
+def test_a_gpt_prompt_takes_the_band_kernel_under_its_prefills_name(
+        one_chip, bucket):
+    """gpt3-1.3b's 16 heads of 128 at each prefill bucket of its two
+    cells that the block rule takes: the band kernel at one query head a
+    key head compiles with the rule's blocks (768 rows: ONE block a head),
+    on bf16 operands, and — handed no name — is called after the jitted
+    function round it, which is what `prefill_flash_fwd_roofline`'s
+    pattern `^custom-call\\._prefill_fn\\.` reads."""
+    blocks = pk._band_blocks(bucket, False, 0, 1)
+    assert blocks == {768: (768, 768), 1024: (512, 512)}.get(
+        bucket, (bucket, bucket))
+
+    def _prefill_fn(q, k, v):
+        return pk._band_flash(q, k, v, 0, *blocks, interpret=False,
+                              named=False)
+
+    x = jax.ShapeDtypeStruct((1, H, bucket, D), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(_prefill_fn).lower(x, x, x).compile().as_text()
+    assert len(re.findall(
+        r"%%_prefill_fn\S* = bf16\[%d,1,%d,%d\]\S* custom-call\("
+        r"[^)]*\), custom_call_target=\"tpu_custom_call\""
+        % (H, bucket, D), text)) == 1
+    assert "f32[%d,%d,%d]" % (H, bucket, D) not in text
