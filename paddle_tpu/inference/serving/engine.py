@@ -148,6 +148,22 @@ AHEAD_PCT = metrics.histogram(
     "step whose tokens decode() returns, else 0 (the device then waits "
     "out the read); one observation a decode() return",
     buckets=(0.0, 100.0))
+ENQUEUE_IDLE_PCT = metrics.histogram(
+    "pt_serve_enqueue_idle_pct",
+    "100 where a program's enqueue found the device idle (the program "
+    "before it already done), else 0; one observation an enqueue",
+    labelnames=("program",), buckets=(0.0, 100.0))
+DECODE_DEVICE_MS = metrics.histogram(
+    "pt_serve_decode_device_ms",
+    "A decode step's own time on the device, milliseconds: the time "
+    "between its tokens' read and the step's before it, observed only "
+    "where that difference is the step's run (GenerationEngine.decode)",
+    buckets=metrics.exponential_buckets(0.01, 2.0, 24))
+
+#: a decode step enqueued at least this long before the step ahead of it
+#: ended runs straight behind it on the device (on the v5e a program lands
+#: up to ≈ 1 ms after its enqueue call returns: PERF.md §6, PR 36)
+LAUNCH_S = 1e-3
 
 # Trace-time weight swapping mutates shared Layer state (`p._data`); one
 # process-wide lock serializes dispatches so server workers sharing a
@@ -221,9 +237,6 @@ class GenerationEngine:
         self.model = model
         self._n_layers = sv.n_layers
         self._max_pos = sv.max_positions
-        self.span_attrs = {
-            "moe_layers": sv.moe_layers,
-            "window_layers": tuple(sv.layer_kinds).count("window")}
 
         buckets = sorted(set(int(b) for b in prefill_buckets))
         if not buckets or buckets[0] < 1:
@@ -240,7 +253,6 @@ class GenerationEngine:
         self.max_seq_len = int(max_seq_len)
         self.buckets = tuple(buckets)
         self.pad_id = int(pad_id)
-        self.bucket_hits = {b: 0 for b in self.buckets}
 
         from ...jit.engine import _collect_train_state
         params, frozen, buffers, _ = _collect_train_state(model, None)
@@ -273,6 +285,13 @@ class GenerationEngine:
         # enqueue has taken its gap from it; None before the first
         # program and across an idle wait
         self._fetched_ts = None
+        # the probes (telemetry on): whether the latest fetch waited for
+        # its program, and of the latest decode step read, (its program
+        # index, its read instant, whether its fetch waited)
+        self._waited = False
+        self._read_prev = None
+        self._idle_obs = {k: ENQUEUE_IDLE_PCT.labels(k).observe
+                          for k in ("decode", "prefill")}
         self._prefill_tel = tracing.StepTelemetry("serve_prefill")
         self._suffix_tel = tracing.StepTelemetry("serve_suffix")
         self._decode_tel = tracing.StepTelemetry("serve_decode")
@@ -424,8 +443,17 @@ class GenerationEngine:
         (an out-of-memory error leaves its forensics), the host's `gap`
         span is closed, and the key, the cache state and `last` are the
         program's. Nothing is read. -> the array the program's tokens
-        come back in (the packed one for a model with expert layers)."""
+        come back in (the packed one for a model with expert layers).
+
+        The enqueue probe (telemetry on): programs run in the order they
+        are enqueued, so the previous program's `last` being ready means
+        the device has nothing left to run — this enqueue finds it idle
+        (`pt_serve_enqueue_idle_pct`)."""
+        decode = jitted is self._jit_decode
         with _DISPATCH_LOCK:
+            if tracing.enabled():
+                self._idle_obs["decode" if decode else "prefill"](
+                    100.0 if self._last.is_ready() else 0.0)
             try:
                 with tel.step(key):
                     kvstate, *out = jitted(
@@ -438,7 +466,7 @@ class GenerationEngine:
                 raise
             self._enqueued(gap)
             self._programs += 1
-            if jitted is self._jit_decode:   # its tokens ARE the next `last`
+            if decode:                       # its tokens ARE the next `last`
                 tok, RNG.key, *packed = out
                 self._last = tok
             else:
@@ -473,7 +501,6 @@ class GenerationEngine:
             b = self.bucket_for(n)
         padded = np.full((1, b), self.pad_id, np.int32)
         padded[0, :n - reused] = prompt[reused:]
-        self.bucket_hits[b] += 1
         PREFILL_BUCKET_HITS.labels(str(b)).inc()
         args = (padded, np.int32(n), np.int32(slot))
         if entry is not None:
@@ -522,21 +549,39 @@ class GenerationEngine:
             self._live = (mask, jax.device_put(mask))
         arr = self._run(self._jit_decode, self._decode_tel, "decode",
                         "host_gap_decode", self._live[1])
-        self._steps.append((arr, self._programs))
+        self._steps.append((arr, self._programs, time.perf_counter()))
 
     def decode(self) -> np.ndarray:
         """The next token per slot of the OLDEST decode step in flight;
         with none in flight, one step is enqueued first."""
         if not self._steps:
             self.enqueue_decode()
-        arr, programs = self._steps.popleft()
+        arr, programs, t_enq = self._steps.popleft()
         out = self._fetch(arr).reshape(-1)
         AHEAD_PCT.observe(100.0 if self._programs > programs else 0.0)
+        if tracing.enabled():
+            self._device_time(programs, t_enq)
         # packed: the tokens, the rows the step swept, then the stats
         cache_mod.KV_ROWS_GIVEN.observe(float(out[self.max_batch]))
         if out.size > self.max_batch + 1:
             self._observe_moe(out[self.max_batch + 1:], self.max_batch)
         return out[:self.max_batch]
+
+    def _device_time(self, programs: int, t_enq: float) -> None:
+        """`pt_serve_decode_device_ms` from the fetch probe. A fetch that
+        waited reads its tokens a fixed transfer after its program ended;
+        so where this step's fetch and the step before's both waited, no
+        program ran between the two (consecutive program indices) and
+        this step's enqueue returned `LAUNCH_S` or more before the step
+        before was read (it was on the device before that one ended, and
+        started the instant it did), the two reads' difference is this
+        step's run on the device, the transfer cancelling out."""
+        prev = self._read_prev
+        now = self._fetched_ts
+        if (self._waited and prev is not None and prev[2]
+                and prev[0] == programs - 1 and prev[1] - t_enq >= LAUNCH_S):
+            DECODE_DEVICE_MS.observe((now - prev[1]) * 1e3)
+        self._read_prev = (programs, now, self._waited)
 
     # -- the host's side of the gap between two programs -------------------
 
@@ -555,6 +600,9 @@ class GenerationEngine:
         `decode_step`, or of the `prefill` a first token names); the next
         host gap starts here."""
         with spans.span("fetch", parent=parent) as sp:
+            # the fetch probe: not ready yet means this read waits for
+            # the program's end
+            self._waited = tracing.enabled() and not tok.is_ready()
             out = np.asarray(tok)
             self._fetched_ts = now = time.perf_counter()
             sp.close(now)

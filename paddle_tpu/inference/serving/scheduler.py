@@ -187,10 +187,6 @@ class ContinuousBatcher:
         # for `hold_s`: the instant of the last read (about when the
         # program behind it began) and a step's run as last measured
         self._read_ts = self._period = None
-        self.live_slot_steps = 0
-        # what the engine's model adds to the prefill / decode_step spans
-        # (expert and window layers)
-        self._span_attrs = dict(getattr(engine, "span_attrs", None) or {})
 
     # -- introspection ----------------------------------------------------
 
@@ -202,12 +198,6 @@ class ContinuousBatcher:
     @property
     def idle(self) -> bool:
         return not (self.waiting or self._live or self._flight)
-
-    @property
-    def occupancy_mean(self) -> float:
-        if not self.steps:
-            return 0.0
-        return self.live_slot_steps / (self.steps * self.engine.max_batch)
 
     def hold_s(self) -> float:
         """How long the serving loop may still wait for an arrival before
@@ -330,8 +320,7 @@ class ContinuousBatcher:
             # `step`: the decode step this admission runs ahead of
             with spans.span("prefill", parent="serve_request", t0=t_pre,
                             rid=req.rid,
-                            step=self.steps + self._steps_ahead + 1,
-                            **self._span_attrs) as sp:
+                            step=self.steps + self._steps_ahead + 1) as sp:
                 tok = self.engine.prefill(slot, req.prompt)
                 sp.defer()          # it closes when the token is read
             # what THIS admission actually dispatched: on a prefix hit
@@ -431,8 +420,7 @@ class ContinuousBatcher:
         """Top up, then read the oldest decode step in flight (which is
         the oldest program in flight) and harvest its tokens."""
         n = self.steps + 1
-        with spans.span("decode_step", t0=self._clock(), step=n,
-                        **self._span_attrs) as sp:
+        with spans.span("decode_step", t0=self._clock(), step=n) as sp:
             self._top_up(STEPS_AHEAD)
             if not self._flight:
                 sp.cancel()
@@ -462,7 +450,6 @@ class ContinuousBatcher:
                 req.token_ts.append(now)
                 if req.done:
                     self._complete(req, completed)
-            self.live_slot_steps += len(pairs)
             TOKENS.inc(len(pairs))
             OCCUPANCY_PCT.observe(100.0 * len(pairs) / len(self.slots))
             sp.close(self._clock())

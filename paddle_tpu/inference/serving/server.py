@@ -15,6 +15,15 @@ plumbing: every program the engine dispatches ticks
 watches training), a crashed loop dumps a flight-recorder crash bundle before
 failing its in-flight requests, and queue depth is exported through
 the PR 2 metrics registry (`pt_serve_queue_depth`).
+
+**The loop's line has no holes.** Every instant of `_loop` lies inside
+one innermost stacked span on its thread (`drain`, `loop_idle`, `hold`,
+`submit`, `turn` and what they hold: `prefill`, `first_tokens`,
+`decode_step` → `dispatch` / `fetch`, `harvest`, a `gc_full`), so the
+profiler's host line says what the host was doing over any idle stretch
+of the device. An iteration that runs longer than `STALL_S`, its waits
+for a request left out, is a stall: `pt_serve_stall_seconds_total` and a
+`serve_stall` journal event naming the span it was in.
 """
 from __future__ import annotations
 
@@ -23,7 +32,8 @@ import threading
 import time
 from typing import List, Optional, Sequence
 
-from ...observability import flight, httpd, metrics, spans
+from ...observability import flight, httpd, journal, metrics, spans, \
+    tracing
 from .engine import GenerationEngine
 from .scheduler import ContinuousBatcher, Request
 from .slo import AdmissionController, ShedError, SLOPolicy
@@ -33,6 +43,46 @@ __all__ = ["InferenceServer", "ServeHandle"]
 QUEUE_DEPTH = metrics.gauge(
     "pt_serve_queue_depth",
     "Requests waiting in the server queue (not yet in a decode slot)")
+STALL_SECONDS = metrics.counter(
+    "pt_serve_stall_seconds_total",
+    "Time of the serving loop's iterations that ran longer than STALL_S, "
+    "their waits for a request (hold, loop_idle) left out")
+
+#: an iteration of the loop longer than this, its waits left out, stalled
+STALL_S = 0.1
+#: the spans the loop's thread opens, nested as they are on the profiler's
+#: line (a `prefill` record outlives its block: its enqueue is `dispatch`)
+LOOP_SPANS = ("drain", "loop_idle", "hold", "submit", "turn",
+              "first_tokens", "decode_step", "dispatch", "compile", "fetch",
+              "harvest", "gc_full")
+_WAITS = ("hold", "loop_idle")
+
+
+class _Stalls:
+    """One lap an iteration of the loop: one longer than `STALL_S`, its
+    waits left out, is counted and journaled with the innermost span
+    that took most of it (telemetry on only)."""
+
+    __slots__ = ("t0", "waited")
+
+    def __init__(self):
+        self.t0, self.waited = time.perf_counter(), 0.0
+
+    def lap(self) -> None:
+        now = time.perf_counter()
+        busy = now - self.t0 - self.waited
+        if busy > STALL_S:
+            self._report(self.t0, now, busy)
+        self.t0, self.waited = now, 0.0
+
+    @staticmethod
+    def _report(t0: float, t1: float, busy: float) -> None:
+        STALL_SECONDS.inc(busy)
+        leaves = spans.innermost(t0, t1, LOOP_SPANS)
+        work = {k: v for k, v in leaves.items() if k not in _WAITS}
+        journal.emit("serve_stall", ms=round(busy * 1e3, 3),
+                     leaf=max(work, key=work.get) if work else None,
+                     gc_full_ms=round(leaves.get("gc_full", 0.0) * 1e3, 3))
 
 
 class ServeHandle:
@@ -232,35 +282,58 @@ class InferenceServer:
                 pass
         return None
 
+    def _wait(self, engine: GenerationEngine, name: str, step: int,
+              stalls: Optional[_Stalls],
+              timeout: Optional[float] = None) -> Optional[ServeHandle]:
+        """Wait on the queue inside a `name` span, `timeout` seconds or,
+        without one, until a request comes (None once the server stops).
+        A wait for a request is not the host's work: no host gap and no
+        stall is counted across it."""
+        t0 = time.perf_counter()
+        with spans.span(name, step=step):
+            if timeout is None:
+                handle = self._wait_for_request()
+            else:
+                try:
+                    handle = self._queue.get(timeout=timeout)
+                except queue.Empty:
+                    handle = None
+        engine.note_idle()
+        if stalls is not None:
+            stalls.waited += time.perf_counter() - t0
+        return handle
+
+    def _submit(self, batcher: ContinuousBatcher, handle: ServeHandle,
+                step: int) -> None:
+        with spans.span("submit", step=step):
+            self._submit_or_fail(batcher, handle)
+
     def _loop(self, engine: GenerationEngine) -> None:
         batcher = ContinuousBatcher(engine, slo=self._slo)
+        stalls = _Stalls() if tracing.enabled() else None
         try:
             while True:
-                with spans.span("drain", step=batcher.steps + 1):
+                if stalls is not None:
+                    stalls.lap()
+                step = batcher.steps + 1
+                with spans.span("drain", step=step):
                     self._drain_into(batcher)
                 if batcher.idle:
-                    with spans.span("loop_idle", step=batcher.steps + 1):
-                        handle = self._wait_for_request()
-                    # the device sat idle for want of a request, not of
-                    # the host: no host_gap across this
-                    engine.note_idle()
+                    # the device sits idle for want of a request
+                    handle = self._wait(engine, "loop_idle", step, stalls)
                     if handle is None:
                         return
-                    self._submit_or_fail(batcher, handle)
+                    self._submit(batcher, handle, step)
                     continue
                 hold = batcher.hold_s()
                 if hold > 0:
                     # a step is running and the next need not be enqueued
                     # yet: an arrival now gets its prefill in front of it
-                    with spans.span("hold", step=batcher.steps + 1):
-                        try:
-                            handle = self._queue.get(timeout=hold)
-                        except queue.Empty:
-                            handle = None
-                    engine.note_idle()      # a wait, not the host's work
+                    handle = self._wait(engine, "hold", step, stalls, hold)
                     if handle is not None:
-                        self._submit_or_fail(batcher, handle)
-                batcher.turn()
+                        self._submit(batcher, handle, step)
+                with spans.span("turn", step=step):
+                    batcher.turn()
         except BaseException as exc:
             flight.dump_crash_bundle("serve_loop", exc)
             self._fail_pending(batcher, exc)

@@ -40,6 +40,20 @@ device programs. Trace ids come from ``PADDLE_TPU_TRACE_ID`` (exported
 per-run by the launcher) so one multi-process run correlates;
 standalone processes mint their own.
 
+**One clock with the profiler.** A record's instants are
+``time.perf_counter``; ``t + EPOCH`` is the same instant on the epoch
+clock the journal and a ``jax.profiler`` trace use (an xplane event's
+absolute time is the trace's ``profile_start_time`` plus its offset), so
+a ring-only record (``host_gap_decode``, ``queue_wait``) can be laid on
+the device trace beside the annotations (docs/OBSERVABILITY.md).
+
+**Garbage collection.** While telemetry is on, a ``gc.callbacks`` hook
+makes every full (generation 2) collection a stacked ``gc_full`` span on
+the thread that ran it (``collected`` an attribute), and adds every
+collection's time to ``pt_gc_seconds_total{generation}``.
+``innermost(t0, t1)`` splits an interval by the innermost closed span
+over each instant, from the ring: what a stalled loop was in.
+
 Disabled-by-default-safe: with telemetry off (``PADDLE_TPU_TELEMETRY=0``
 / ``tracing.enable(False)``) every entry point returns a shared no-op
 (no clock read, no observe, no ring append), and without an active
@@ -49,18 +63,21 @@ metrics/journal already keep. Pure stdlib by contract.
 """
 from __future__ import annotations
 
+import atexit
 import collections
+import gc
 import os
 import sys
 import threading
 import time
 import uuid
-from typing import List, NamedTuple, Optional
+from typing import Dict, Iterable, List, NamedTuple, Optional
 
 from . import journal, metrics, tracing
 
 __all__ = ["span", "begin", "end", "record", "recent", "trace_id",
-           "current", "Span", "SpanRecord", "SPAN_MS", "RING_SIZE"]
+           "current", "innermost", "watch_gc", "Span", "SpanRecord",
+           "SPAN_MS", "GC_SECONDS", "RING_SIZE", "EPOCH"]
 
 # millisecond scale: 10us .. ~84s upper edges
 SPAN_MS = metrics.histogram(
@@ -68,6 +85,11 @@ SPAN_MS = metrics.histogram(
     "Wall time of named trace spans, milliseconds",
     labelnames=("name",),
     buckets=metrics.exponential_buckets(0.01, 2.0, 24))
+GC_SECONDS = metrics.counter(
+    "pt_gc_seconds_total",
+    "Wall time of the interpreter's garbage collections, by generation "
+    "(a full one, generation 2, is also a `gc_full` span)",
+    labelnames=("generation",))
 
 
 class SpanRecord(NamedTuple):
@@ -81,14 +103,15 @@ class SpanRecord(NamedTuple):
     attrs: dict
 
 
-#: a 40 s chat window closes ~8k spans; the ring holds four of them
+#: a 40 s chat window closes ≈ 46 k spans (PR 36): the ring holds its last ≈ 28 s
 RING_SIZE = 32768
+# appended and copied whole under the GIL: a deque's append and
+# `list(deque)` run in C with no Python code between, so need no lock
 _ring: "collections.deque[SpanRecord]" = collections.deque(maxlen=RING_SIZE)
-_ring_lock = threading.Lock()
 _clock = time.perf_counter
-# perf_counter -> epoch, fixed at import: the journal's `ts` and every
-# other rank's events are on the epoch clock
-_EPOCH = time.time() - time.perf_counter()
+#: perf_counter -> epoch, fixed at import: the journal's `ts`, every other
+#: rank's events and a `jax.profiler` trace are on the epoch clock
+EPOCH = time.time() - time.perf_counter()
 # what a span hands down to the spans opened or recorded inside it
 _INHERITED = ("rid", "step")
 
@@ -121,8 +144,7 @@ def current() -> Optional[str]:
 
 def recent() -> List[SpanRecord]:
     """The closed spans the ring still holds, oldest first."""
-    with _ring_lock:
-        return list(_ring)
+    return list(_ring)
 
 
 def _annotate(label: str, attrs: dict):
@@ -136,14 +158,17 @@ def _annotate(label: str, attrs: dict):
             return None
         _annotation_cls = jax.profiler.TraceAnnotation
     ann = _annotation_cls(
-        label, **{k: attrs[k] for k in _INHERITED if k in attrs})
+        label, **{k: attrs[k] for k in _INHERITED if k in attrs}) \
+        if "rid" in attrs or "step" in attrs else _annotation_cls(label)
     ann.__enter__()
     return ann
 
 
-def _inherit(attrs: dict) -> Optional[str]:
-    """Parent name from this thread's stack; its rid/step fill `attrs`."""
-    s = getattr(_tls, "stack", None)
+def _inherit(attrs: dict, s: Optional[list] = None) -> Optional[str]:
+    """Parent name from this thread's stack `s`; its rid/step fill
+    `attrs`."""
+    if s is None:
+        s = getattr(_tls, "stack", None)
     if not s:
         return None
     top = s[-1]
@@ -153,19 +178,36 @@ def _inherit(attrs: dict) -> Optional[str]:
     return top.name
 
 
+#: name -> the `pt_span_ms{name}` child's observe, looked up once a name
+_observe: Dict[str, object] = {}
+_observe_of = None          # the histogram `_observe` was filled from
+
+
+def _observer(name: str):
+    global _observe_of
+    if _observe_of is not SPAN_MS:      # replaced (a test's counting double)
+        _observe.clear()
+        _observe_of = SPAN_MS
+    obs = _observe[name] = SPAN_MS.labels(name).observe
+    return obs
+
+
 def _emit(name: str, t0: float, t1: float, parent: Optional[str],
           attrs: dict) -> None:
     dur_ms = (t1 - t0) * 1e3
-    SPAN_MS.labels(name).observe(dur_ms)
-    with _ring_lock:
-        _ring.append(SpanRecord(name, t0, t1, parent, trace_id(), attrs))
+    obs = _observe.get(name)
+    if obs is None or _observe_of is not SPAN_MS:
+        obs = _observer(name)
+    obs(dur_ms)
+    _ring.append(SpanRecord(name, t0, t1, parent,
+                            _trace_id or trace_id(), attrs))
     # journal writes only when a run journal is live: journal.emit with no
     # journal still taps the flight ring, and per-step span events would
     # wash real dispatch history out of its 512 slots
     if journal.get_journal() is not None:
         # tid gives traceview one track per rank x thread (the envelope
         # already carries rank/pid); masked like profiler.RecordEvent's
-        ev = {"name": name, "t0": round(t0 + _EPOCH, 6),
+        ev = {"name": name, "t0": round(t0 + EPOCH, 6),
               "dur_ms": round(dur_ms, 3), "trace": trace_id(),
               "tid": threading.get_ident() % 100000}
         if parent:
@@ -182,16 +224,17 @@ class Span:
                  "_ann", "_deferred")
 
     def __init__(self, name: str, parent: Optional[str], attrs: dict,
-                 stacked: bool, t0: Optional[float] = None,
+                 stacked: Optional[list], t0: Optional[float] = None,
                  label: Optional[str] = None):
         self.name = name
         self.parent = parent
         self.attrs = attrs
-        self._stacked = stacked
+        self._stacked = stacked         # this thread's stack, or None
         self._done = self._deferred = False
         # a handle that travels between threads is not annotated: the
         # profiler pairs an annotation's two ends on one thread's line
-        self._ann = _annotate(label or name, attrs) if stacked else None
+        self._ann = None if stacked is None else \
+            _annotate(label or name, attrs)
         self.t0 = _clock() if t0 is None else t0
 
     def cancel(self) -> None:
@@ -228,10 +271,9 @@ class Span:
             self.close()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        if self._stacked:
-            s = _stack()
-            if s and s[-1] is self:
-                s.pop()
+        s = self._stacked
+        if s and s[-1] is self:
+            s.pop()
         return False
 
 
@@ -269,10 +311,11 @@ def span(name: str, parent: Optional[str] = None,
     where it differs from the `pt_span_ms` series."""
     if not tracing.enabled():
         return _NULL
-    inherited = _inherit(attrs)
-    sp = Span(name, parent or inherited, attrs, stacked=True, t0=t0,
+    s = _stack()
+    inherited = _inherit(attrs, s)
+    sp = Span(name, parent or inherited, attrs, stacked=s, t0=t0,
               label=label)
-    _stack().append(sp)
+    s.append(sp)
     return sp
 
 
@@ -285,7 +328,7 @@ def begin(name: str, parent: Optional[str] = None,
     no-op), so call sites need no enabled() check of their own."""
     if not tracing.enabled():
         return None
-    return Span(name, parent, attrs, stacked=False, t0=t0)
+    return Span(name, parent, attrs, stacked=None, t0=t0)
 
 
 def end(handle: Optional[Span], t1: Optional[float] = None, **attrs) -> None:
@@ -307,3 +350,64 @@ def record(name: str, dur_ms: float, parent: Optional[str] = None,
     if t0 is None:
         t0 = _clock() - dur_s
     _emit(name, t0, t0 + dur_s, parent, attrs)
+
+
+def innermost(t0: float, t1: float,
+              names: Optional[Iterable[str]] = None) -> Dict[str, float]:
+    """{span name: seconds} of [t0, t1] by the innermost closed span over
+    each instant (of those the ring holds; of `names` alone where given):
+    the one that started last, as nested spans do. Time no such span
+    covers is under None. For a rare look back (a stalled loop), not a
+    hot path: it scans the ring from its newest record."""
+    keep = None if names is None else frozenset(names)
+    recs = []
+    for r in reversed(recent()):
+        if r.t1 < t0 - 1.0:         # appended as closed: older ones too
+            break
+        if r.t1 > t0 and r.t0 < t1 and (keep is None or r.name in keep):
+            recs.append(r)
+    cuts = sorted({t0, t1} | {x for r in recs for x in (r.t0, r.t1)
+                              if t0 < x < t1})
+    out: Dict[str, float] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        over = [r for r in recs if r.t0 <= a and b <= r.t1]
+        name = max(over, key=lambda r: r.t0).name if over else None
+        out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
+# -- garbage collection ------------------------------------------------------
+_gc_open = threading.local()
+_gc_counted = [GC_SECONDS.labels(str(g)) for g in range(3)]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook: a full collection is a stacked `gc_full` span
+    on the collecting thread; every collection's time is counted."""
+    gen = info.get("generation", 0)
+    if phase == "start":
+        _gc_open.t0 = _clock()
+        _gc_open.span = span("gc_full") if gen == 2 else None
+        return
+    t0 = getattr(_gc_open, "t0", None)
+    if t0 is None:                    # switched on mid-collection
+        return
+    _gc_counted[gen].inc(_clock() - t0)
+    sp, _gc_open.t0, _gc_open.span = _gc_open.span, None, None
+    if sp is not None:
+        sp.close(collected=info.get("collected", 0))
+        sp.__exit__(None, None, None)
+
+
+def watch_gc(on: bool) -> None:
+    """Register (on) or remove the collection hook; `tracing.enable`
+    calls it, so the hook is there exactly while telemetry is on."""
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+watch_gc(tracing.enabled())
+# the interpreter's last collections run with this module half torn down
+atexit.register(watch_gc, False)

@@ -61,6 +61,9 @@ def enable(on: bool = True):
     """Flip telemetry globally (tests and the overhead benchmark)."""
     global _enabled
     _enabled = bool(on)
+    # the collection hook is there exactly while telemetry is on
+    from . import spans
+    spans.watch_gc(_enabled)
 
 
 RETRACES = metrics.counter(

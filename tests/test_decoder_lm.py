@@ -200,11 +200,10 @@ def test_live_tokens_with_empty_slots_are_the_parent_rules(net, kernels):
                    "FLAGS_use_flash_attention": True})
 
 
-def test_span_attributes_and_counters_of_a_served_model(net):
+def test_counters_of_a_served_model(net):
     from paddle_tpu.inference.serving import cache as cache_mod
     from paddle_tpu.inference.serving import engine as engine_mod
     e = _engine(net)
-    assert e.span_attrs == {"moe_layers": 4, "window_layers": 4}
     n0 = engine_mod.MOE_ASSIGNMENTS.value
     int(e.prefill(0, np.arange(1, 12)))    # observed where it is read
     e.decode()

@@ -29,6 +29,8 @@ from paddle_tpu.inference.serving import (ContinuousBatcher,
                                           InferenceServer, PagedKVCache,
                                           Request, bucket_for,
                                           run_open_loop)
+from paddle_tpu.inference.serving import engine as engine_mod
+from paddle_tpu.inference.serving import scheduler as scheduler_mod
 
 VOCAB = 64
 _CACHE = {}
@@ -51,6 +53,14 @@ def _shared_engine():
         _CACHE["engine"] = GenerationEngine(
             _tiny(), max_batch=4, max_seq_len=32, prefill_buckets=(8, 16))
     return _CACHE["engine"]
+
+
+def _live_slot_steps(max_batch=4):
+    """-> a reader of the (slot, step) pairs harvested since this call,
+    from `pt_serve_occupancy_pct` (one observation a decode step)."""
+    h = scheduler_mod.OCCUPANCY_PCT
+    s0 = h.sum
+    return lambda: round((h.sum - s0) * max_batch / 100.0)
 
 
 def _prompt(rs, n):
@@ -127,6 +137,9 @@ class TestCompileOnce:
         eng = GenerationEngine(m, max_batch=2, max_seq_len=48,
                                prefill_buckets=(4, 8, 16))
         d0 = RETRACES.labels("serve_decode").value
+        hits = {k: engine_mod.PREFILL_BUCKET_HITS.labels(str(k))
+                for k in eng.buckets}
+        h0 = {k: c.value for k, c in hits.items()}
         b = ContinuousBatcher(eng)
         for n, mn in [(3, 5), (5, 3), (7, 4), (12, 6), (16, 2)]:
             b.submit(Request(prompt=_prompt(rs, n), max_new_tokens=mn))
@@ -137,7 +150,8 @@ class TestCompileOnce:
         assert eng.prefill_compiles == 3      # buckets 4, 8 and 16 all hit
         # registry-side accounting agrees (pt_jit_retraces_total)
         assert RETRACES.labels("serve_decode").value - d0 == 1
-        assert eng.bucket_hits == {4: 1, 8: 2, 16: 2}
+        assert {k: c.value - h0[k] for k, c in hits.items()} == \
+            {4: 1, 8: 2, 16: 2}
         # three more waves through the now-dirty slots: still no retrace
         for wave in range(3):
             b.submit(Request(prompt=_prompt(rs, 4), max_new_tokens=3))
@@ -208,10 +222,11 @@ class TestSchedulerModes:
                                   max_new_tokens=3)) for _ in range(3)]
         arrivals += [(0.05, Request(prompt=_prompt(rs, 5),
                                     max_new_tokens=3))]
+        occ = _live_slot_steps()
         done = run_open_loop(b, arrivals)
         assert len(done) == 4
         assert all(r.done and r.ttft_s >= 0 for r in done)
-        assert b.occupancy_mean > 0
+        assert occ() > 0
 
 
 class TestServer:
@@ -617,13 +632,14 @@ class TestRunAhead:
     @pytest.mark.parametrize("mode", ["continuous", "static"])
     def test_churned_schedule_token_for_token_with_depth_0(self, mode):
         eng = _shared_engine()
+        occ = _live_slot_steps()
         b, refills = run_ahead_matches_depth0(
             eng, VOCAB, admit_mid_flight=(mode == "continuous"))
         if mode == "continuous":
             # a prefill went into a slot released by count, behind the
             # step that carries its last owner's last token
             assert refills >= 3
-            assert b.occupancy_mean > 0.6
+            assert occ() > 0.6 * b.steps * eng.max_batch
         else:
             assert refills == 0
 
@@ -1018,13 +1034,15 @@ class TestEmptySlots:
         given, live = cache_mod.KV_ROWS_GIVEN, \
             cache_mod.KV_ROWS_LIVE.labels("full")
         g0, l0 = (given.sum, given.count), (live.sum, live.count)
+        occ = _live_slot_steps()
         b = ContinuousBatcher(eng)
         for n, max_new in CHURN:
             b.submit(Request(prompt=_prompt(rs, n), max_new_tokens=max_new))
         b.run_until_idle()
         assert given.count - g0[1] == live.count - l0[1] == b.steps > 10
-        assert (live.sum - l0[0]) - (given.sum - g0[0]) == b.live_slot_steps
-        assert given.sum - g0[0] > 5 * b.live_slot_steps
+        slot_steps = occ()
+        assert (live.sum - l0[0]) - (given.sum - g0[0]) == slot_steps
+        assert given.sum - g0[0] > 5 * slot_steps
 
 
 class TestPredictorPoolSharing:
