@@ -245,15 +245,22 @@ def sigmoid_topk_route(x, router_w, expert_bias, top_k, route_norm=True,
     return chosen.astype(jnp.int32), w * route_scale
 
 
-def grouped_experts(x, chosen, weights, e_gate, e_up, e_down, first=0):
+def grouped_experts(x, chosen, weights, e_gate, e_up, e_down, first=0,
+                    experts=None):
     """Σ_k weights[t, k] · SwiGLU_{chosen[t, k]}(x[t]) over the experts
     held here, [first, first + e_gate.shape[0]): float32 [T, d], and the
     int32 [E_held] number of assignments each held expert got.
 
-    x [T, d]; e_gate / e_up [E_held, d, f]; e_down [E_held, f, d]. Every
-    assignment to a held expert is computed; there is no capacity."""
+    x [T, d]; e_gate / e_up [E_held, d, f]; e_down [E_held, f, d];
+    `experts` the router's width, None where it scores the held experts
+    alone. Every assignment to a held expert is computed; there is no
+    capacity. A layer that holds every expert takes `jax.lax.ragged_dot`;
+    one that holds a share takes `held_grouped_dot`, which multiplies the
+    rows of the held experts alone, where its tile rule takes the shape
+    (`pt_moe_path_total{path}` counts which, at trace time)."""
     import jax
     import jax.numpy as jnp
+    from ..ops import pallas_kernels as pk
     T, k = chosen.shape
     held = e_gate.shape[0]
     local = chosen - jnp.int32(first)
@@ -263,10 +270,25 @@ def grouped_experts(x, chosen, weights, e_gate, e_up, e_down, first=0):
     sizes = jnp.sum(flat[:, None] == jnp.arange(held, dtype=flat.dtype),
                     axis=0, dtype=jnp.int32)
     xs = x[order // k]                                       # [T·k, d]
-    g = jax.lax.ragged_dot(xs, e_gate, sizes)
-    u = jax.lax.ragged_dot(xs, e_up, sizes)
-    y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(xs.dtype), e_down,
-                           sizes)                            # [T·k, d]
+    interpret = jax.default_backend() != "tpu"
+    tiles = None
+    if experts is not None and held < experts:
+        tiles = [pk.held_grouped_tiles(T * k, *w.shape[1:],
+                                       w.dtype.itemsize, interpret)
+                 for w in (e_gate, e_down)]
+        if None in tiles:
+            tiles = None
+    pk._note_moe_path("ragged_dot" if tiles is None else "held_grouped")
+
+    def dot(a, w, t):
+        if tiles is None:
+            return jax.lax.ragged_dot(a, w, sizes)
+        return pk.held_grouped_dot(a, w, sizes, tiles=t,
+                                   interpret=interpret)
+
+    up, down = tiles or (None, None)
+    g, u = dot(xs, e_gate, up), dot(xs, e_up, up)
+    y = dot((jax.nn.silu(g) * u).astype(xs.dtype), e_down, down)  # [T·k, d]
     # back to assignment order; rows past the held groups are undefined.
     # The inverse of a permutation is its argsort: a sort is 0.2 ms on the
     # chip at 114 688 assignments where the scatter it replaces was 8.7 ms

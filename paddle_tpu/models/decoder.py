@@ -508,13 +508,18 @@ def _attention(cfg, i, p, h, pos, view=None):
 def moe_layer(mc, p, x):
     """The expert layer on tokens x [N, d]: (float32 [N, d], int32
     [E_held] assignments each held expert got)."""
-    first, _ = mc.held
+    first, held = mc.held
     chosen, w = moe_ops.sigmoid_topk_route(
         x, p["router"], p["expert_bias"], mc.top_k, mc.route_norm,
         mc.route_scale)
+    # the router's width goes along only where it scores more experts
+    # than are held here, so a layer that holds them all makes the call
+    # it made before `experts` existed (wrappers of the older signature)
+    E = p["router"].shape[1]
+    share = {"experts": E} if held < E else {}
     out, sizes = moe_ops.grouped_experts(
         x.astype(p["e_gate"].dtype), chosen, w, p["e_gate"], p["e_up"],
-        p["e_down"], first=first)
+        p["e_down"], first=first, **share)
     if mc.shared_width:
         out = out + swiglu(x, p["s_gate"], p["s_up"], p["s_down"])
     return out, sizes
@@ -737,18 +742,20 @@ class _Serving:
         self.moe_layers = cfg.mlp_kinds.count("moe")
         self.moe_top_k = cfg.moe.top_k if cfg.moe else 0
         self.moe_experts = cfg.moe.num_experts if cfg.moe else 0
+        # a share of the experts takes the held-share grouped product
+        held = ("held_grouped",) if model._share else ()
         if "latent" in geo:
             # a row of the cache is (latent | rotary part): no per-head
             # key, no value; the absorbed decode over those rows and the
             # band prefill at one query head a key head
             self.kv_geometry = {
                 "latent": (cfg.latent_rank, cfg.latent_rope_dim)}
-            self.selfchecks = ("paged_latent", "band_flash_latent")
+            self.selfchecks = ("paged_latent", "band_flash_latent") + held
             return
         # the grouped-query decode over rings and over full rows, and the
         # band prefill; each also at a key size that is not the value
         # size with a sink in the softmax, where a layer has either
-        self.selfchecks = ("paged_gqa", "band_flash")
+        self.selfchecks = ("paged_gqa", "band_flash") + held
         if cfg.sink_kinds or any(g[2] != g[3] for g in geo.values()):
             self.selfchecks += ("paged_gqa_sink", "band_flash_sink")
         full = geo.get("full")

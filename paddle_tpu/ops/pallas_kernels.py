@@ -138,8 +138,9 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
     absorbed decode over a latent cache and the band prefill at one query
     head a key head of a 192-wide key; "band_flash_mha": GPT's prompts,
     one query head a key head of 128, over 768 rows in one block and over
-    1024 in blocks of 512), checked only for such a model.
-    Raises whatever the compiler raises, or PallasSelfCheckError on a
+    1024 in blocks of 512; "held_grouped": the grouped product of a layer
+    that holds a share of its router's experts), checked only for such a
+    model. Raises whatever the compiler raises, or PallasSelfCheckError on a
     value mismatch."""
     if jax.default_backend() != "tpu":
         return
@@ -164,6 +165,8 @@ def pallas_selfcheck(needs_prng=True, needs_paged=False, needs=()):
         checks.append(("paged_gqa_sink", _check_paged_gqa_sink))
     if "paged_latent" in needs:
         checks.append(("paged_latent", _check_paged_latent))
+    if "held_grouped" in needs:
+        checks.append(("held_grouped", _check_held_grouped))
     for name, check in checks:
         if name not in _SELFCHECKED:
             check()
@@ -1463,6 +1466,23 @@ def update_path_totals():
     """Cumulative {path: count} from pt_optimizer_update_path_total."""
     return _path_totals("pt_optimizer_update_path_total", _UPDATE_HELP,
                         _UPDATE_PATHS)
+
+
+# And for an expert layer's three grouped products: one increment a layer
+# traced, by implementation (`incubate/moe.grouped_experts` picks).
+_MOE_PATHS = ("held_grouped", "ragged_dot")
+_MOE_HELP = "Expert layers' grouped products traced, by implementation"
+
+
+def _note_moe_path(path):
+    from ..observability import metrics
+    metrics.counter("pt_moe_path_total", _MOE_HELP,
+                    labelnames=("path",)).labels(path).inc()
+
+
+def moe_path_totals():
+    """Cumulative {path: count} from pt_moe_path_total."""
+    return _path_totals("pt_moe_path_total", _MOE_HELP, _MOE_PATHS)
 
 
 def flash_attention_or_none(query, key, value, attn_mask, is_causal,
@@ -2941,3 +2961,148 @@ def _check_band_flash_mha():
     the diagonal, a block below it and a step that is skipped)."""
     for T in (768, 1024):
         _check_band_flash(W=0, Hq=2, Hkv=2, T=T)
+
+
+# ---------------------------------------------------------------------------
+# Grouped product of a held share of the experts
+#
+# `incubate/moe.grouped_experts` sorts a layer's token-expert assignments by
+# expert: the rows of the experts held here come first, one group an expert,
+# and the rows of the experts held elsewhere after them. `jax.lax.ragged_dot`
+# multiplies every row of that array; where a chip holds a sixteenth of the
+# experts fifteen rows in sixteen are computed and thrown away. This kernel
+# walks a work list of the (held group, row tile) pairs that hold a row, so
+# a tile past the held groups is never read, and a held expert's weights
+# are streamed once a visit.
+# ---------------------------------------------------------------------------
+
+
+def _held_work(sizes, m, tm):
+    """The work list of a call: (offsets int32 [G + 1], group, tile int32
+    [m // tm + G - 1], n). Group g's rows are offsets[g] .. offsets[g + 1];
+    entry v < n is the v-th (group, row tile) visit, a group's tiles in
+    turn and no tile of an empty group, so that a tile's visits follow one
+    another; the entries from n on are never visited, and n is 0 where no
+    row is held."""
+    G = sizes.shape[0]
+    ends = jnp.cumsum(sizes).astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first = offsets[:-1] // tm
+    ntile = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    before = jnp.cumsum(ntile) - ntile        # visits of the groups ahead
+    n_max = m // tm + G - 1
+    group = jnp.repeat(jnp.arange(G, dtype=jnp.int32), ntile,
+                       total_repeat_length=n_max)
+    v = jnp.arange(n_max, dtype=jnp.int32)
+    tile = jnp.minimum(first[group] + v - before[group], m // tm - 1)
+    return (offsets, group.astype(jnp.int32), tile.astype(jnp.int32),
+            jnp.sum(ntile).astype(jnp.int32))
+
+
+def _held_grouped_kernel(off_ref, group_ref, tile_ref, x_ref, w_ref, o_ref,
+                         *, tm):
+    """One (column tile j, visit v) step: x_ref [tm, K] the visit's row
+    tile, w_ref [K, tn] of its group's weights. The tile's rows that are
+    the group's are written; the others keep what an earlier visit of the
+    tile wrote there."""
+    v = pl.program_id(1)
+    g = group_ref[v]
+    y = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape,
+                                                      0)
+    mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
+    o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
+
+
+def _whole_tile(n, cap, step):
+    """The most, at most `cap` and a multiple of `step`, that tiles n
+    exactly; None where no such number does."""
+    for t in range(cap - cap % step, 0, -step):
+        if n % t == 0:
+            return t
+    return None
+
+
+# The most bytes of one block of an expert's weights: the whole width where
+# it fits. On the v5e 2-8 MiB blocks stream the decode step's experts at
+# 0.40 ms a product, one block of 16 MiB at 0.45 ms (docs/PERFORMANCE.md).
+_HELD_W_BLOCK = 8 << 20
+
+
+def held_grouped_tiles(m, K, N, itemsize, interpret):
+    """(rows, columns) of a block of `held_grouped_dot` on x [m, K] and
+    experts [K, N] of `itemsize` bytes, or None where the caller takes
+    `ragged_dot`. A decode step's rows (m <= 4096) take 128-row tiles, so
+    that its held rows, ~100 of them, are one tile and each held expert is
+    streamed once; a prompt's take 256-row tiles. The depth is whole: no
+    served shape measured faster in depth steps. The emulator takes 8-row
+    tiles of whole experts."""
+    if interpret:
+        return (8, N) if m % 8 == 0 else None
+    cap = 128 if m <= 4096 else 256
+    tm = m if m <= cap else _whole_tile(m, cap, 16)
+    tn = N if K * N * itemsize <= _HELD_W_BLOCK else _whole_tile(
+        N, _HELD_W_BLOCK // (K * itemsize), _LANES)
+    if tm is None or tn is None or K % _LANES:
+        return None
+    return tm, tn
+
+
+def held_grouped_dot(x, w, sizes, *, tiles, interpret):
+    """x [m, K] sorted by group, w [G, K, N], sizes int32 [G] with
+    sum(sizes) <= m -> [m, N] in x's dtype: rows offsets[g] .. offsets[g +
+    1] of the result are those rows of x times w[g], in float32 and rounded
+    once. Rows past the groups are undefined, as `ragged_dot` leaves them.
+    `tiles` = (rows, columns) of a block, each tiling its dimension exactly
+    (`held_grouped_tiles`)."""
+    m, K = x.shape
+    G, _, N = w.shape
+    tm, tn = tiles
+    offsets, group, tile, n = _held_work(sizes.astype(jnp.int32), m, tm)
+
+    def x_map(j, v, off, grp, til):
+        return (til[v], _I0)
+
+    def w_map(j, v, off, grp, til):
+        return (grp[v], _I0, j)
+
+    def o_map(j, v, off, grp, til):
+        return (til[v], j)
+
+    it = x.dtype.itemsize
+    held = tm * K * it + K * tn * w.dtype.itemsize + tm * tn * it
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(N // tn, n),
+        in_specs=[pl.BlockSpec((tm, K), x_map),
+                  pl.BlockSpec((None, K, tn), w_map)],
+        out_specs=pl.BlockSpec((tm, tn), o_map))
+    return _pallas_call(
+        functools.partial(_held_grouped_kernel, tm=tm), grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, N), x.dtype),
+        compiler_params=_resident_vmem_params(held + tm * tn * 4,
+                                              interpret),
+        interpret=interpret, name="held_grouped_dot")(
+            offsets, group, tile, x, w)
+
+
+def _check_held_grouped():
+    """The held-share product at four experts of [512, 256] over 256 rows
+    in the rule's tiles (128 rows, the experts whole): a group that crosses
+    a tile, an empty one and a tail of rows held elsewhere, against
+    `ragged_dot` on the held rows."""
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(256, 512), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(4, 512, 256) / 16.0, jnp.bfloat16)
+    sizes = jnp.asarray([100, 0, 60, 20], jnp.int32)
+    tiles = held_grouped_tiles(256, 512, 256, 2, False)
+    run = jax.jit(functools.partial(held_grouped_dot, tiles=tiles,
+                                    interpret=False))
+    got = run(x, w, sizes)
+    want = jax.lax.ragged_dot(x, w, sizes,
+                              preferred_element_type=jnp.float32)
+    got, want = np.asarray(got[:180], np.float32), np.asarray(want[:180])
+    if not np.allclose(got, want, rtol=2e-2, atol=2e-2):
+        raise PallasSelfCheckError(
+            "held-share grouped product disagrees with ragged_dot on %s: "
+            "max|out-want|=%.3e" % (jax.devices()[0].device_kind,
+                                    _max_err(got, want)))

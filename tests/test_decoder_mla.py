@@ -108,7 +108,9 @@ def test_the_published_keys_become_the_block(net):
     assert net.layers[0].gate._data.shape == (64, 96)
     sv = net.serving()
     assert sv.kv_geometry == {"latent": (32, 8)}
-    assert sv.selfchecks == ("paged_latent", "band_flash_latent")
+    # 4 of the router's 16 experts held: the held-share grouped product
+    assert sv.selfchecks == ("paged_latent", "band_flash_latent",
+                             "held_grouped")
     assert (sv.moe_layers, sv.moe_top_k, sv.moe_experts) == (2, 4, 16)
     assert sv.prefix_cache is False
 

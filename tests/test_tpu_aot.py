@@ -203,7 +203,9 @@ def test_decode_step_at_unequal_stacks_copies_neither_cache_nor_weight(
         jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert "paged_kv_ring_decode" in text and "paged_kv_rows_decode" in text
-    assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 9
+    # a held share: three products a layer on the held experts' rows alone
+    assert len(re.findall(r"held_grouped_dot\S* = ", text)) == 9
+    assert "ragged-dot" not in text
     assert eng.kv.k_cols == ("full", "window")
     assert [a.shape for a in cache[:4]] == [
         (1, slots, 4, 192, depth), (1, slots, 4, depth, 128),
@@ -299,7 +301,8 @@ def test_latent_decode_step_holds_no_copy_of_a_latent_stack(
     ).compile()
     text = compiled.as_text()
     assert len(re.findall(r"paged_latent_decode\S* = ", text)) == 3
-    assert len(re.findall(r"ragged-dot-none\S* = ", text)) == 6
+    assert len(re.findall(r"held_grouped_dot\S* = ", text)) == 6
+    assert "ragged-dot" not in text
     ma = compiled.memory_analysis()
     nbytes = 3 * slots * depth * 576 * 2
     assert ma.alias_size_in_bytes >= nbytes
