@@ -5,7 +5,8 @@ sequence and the absorbed path for one token a slot), the latent cache
 (`serving/cache.py`: one row a token a layer, no per-head key or value) and
 the decode kernel over it, against the plain float32 reference of the
 `mla_moe` family (`benchmarks/perf/reference_mla_moe.py`), which always
-expands, on seeded weights.
+expands, on seeded weights: the checks every family shares
+(`decoder_conformance.py`) and this family's own.
 
 Size: d 64, 4 heads of 16 + 8 (value 16) over a 32-wide latent and an 8-wide
 rotary part, a 16-wide router top-4 of which the experts 4-7 are held, two
@@ -28,25 +29,17 @@ for _p in (os.path.join(ROOT, "benchmarks", "perf"),
 
 import family_mla_moe as fam                                    # noqa: E402
 import reference_mla_moe as ref                                 # noqa: E402
+from decoder_conformance import (                               # noqa: E402
+    TOL, DecoderConformance, family_fixtures, interpret, kernel_flags, randn)
 from tiny_mla import TINY_CONFIG, wide_scores                   # noqa: E402
 from paddle_tpu.framework.flags import set_flags                # noqa: E402
-from paddle_tpu.inference.serving import cache as cache_mod     # noqa: E402
-from paddle_tpu.inference.serving import engine as engine_mod   # noqa: E402
 from paddle_tpu.inference.serving.cache import (                # noqa: E402
     LayerCacheView, PagedKVCache, StackedKV)
-from paddle_tpu.inference.serving.engine import GenerationEngine  # noqa: E402
 from paddle_tpu.models import decoder as dec                    # noqa: E402
 from paddle_tpu.ops import pallas_kernels as pk                 # noqa: E402
 
 CFG = dict(TINY_CONFIG, vocab_size=300)
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# float32 on both sides and the same function of the weights: the program
-# and the reference differ in the order of summation, and the absorbed path
-# from the expanded one in where the up-projections are multiplied in.
-# Logits have a standard deviation of 0.16: 2e-5 is a hundred times the 2e-7
-# read on the sound program, a thousandth of what bfloat16 gives and far
-# under int8 (0.02) or a dropped `kv_norm` (0.01: the tests below).
-TOL = 2e-5
 
 
 def program(cfg, weights):
@@ -55,31 +48,7 @@ def program(cfg, weights):
     return net
 
 
-@pytest.fixture(scope="module")
-def weights():
-    return ref.make_weights(CFG, 5, "float32")
-
-
-@pytest.fixture(scope="module")
-def net(weights):
-    return program(CFG, weights)
-
-
-@pytest.fixture
-def kernels():
-    set_flags({"FLAGS_paged_flash_interpret": True})
-    yield
-    set_flags({"FLAGS_paged_flash_interpret": False})
-
-
-def _flags(with_kernels):
-    set_flags({"FLAGS_paged_flash_interpret": with_kernels,
-               "FLAGS_use_flash_attention": with_kernels})
-
-
-def _flags_back():
-    set_flags({"FLAGS_paged_flash_interpret": False,
-               "FLAGS_use_flash_attention": True})
+weights, net = family_fixtures(ref, CFG, program)
 
 
 # -- (a) the configuration's way into the block -----------------------------
@@ -203,15 +172,6 @@ def test_latent_layers_stand_beside_no_other_kind():
 # -- (b) the whole forward pass ---------------------------------------------
 
 
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_logits_match_the_reference(net, weights, seed):
-    ids = ref.tokens(seed, 2, 40, CFG["vocab_size"])
-    got = np.asarray(net.run(jnp.asarray(ids, jnp.int32))[0])
-    for row in range(2):
-        want = np.asarray(ref.logits(CFG, weights, ids[row]))
-        assert np.abs(got[row] - want).max() < TOL
-
-
 @pytest.mark.parametrize("fault", [f for f in ref.FAULTS
                                    if f not in (None, "one_token")])
 def test_each_planted_fault_moves_the_reference_logits(monkeypatch, fault):
@@ -270,157 +230,36 @@ def test_the_absorbed_path_gives_the_expanded_paths_attention(
     T, n = 37, 20
     h = jax.random.normal(jax.random.PRNGKey(layer), (1, T, 64), jnp.float32)
     pos = jnp.arange(T, dtype=jnp.int32)[None]
-    _flags(with_kernels)
-    try:
+    with kernel_flags(with_kernels):
         want, rows, none = dec._attention(cfg, layer, p, h, pos)
         assert none is None and rows.shape == (1, T, 32 + 8)
         kv = PagedKVCache(3, 2, None, 64, None, layer_kinds=cfg.layer_kinds,
                           kv_geometry={"latent": (32, 8)})
         carrier = kv.carrier(kv.state())
         carrier.insert([rows[:, :n]] * 3, None, jnp.int32(n), jnp.int32(1))
+
+        @jax.jit            # one token's step: the cache state in and out
+        def absorbed(state, hh, pos):
+            carrier = kv.carrier(state)
+            got, _, _ = dec._attention(cfg, layer, p, hh, pos,
+                                       kv.views(carrier)[layer])
+            return got, carrier.state()
+
+        state = carrier.state()
         for t in range(n, T):
-            view = kv.views(carrier)[layer]
             hh = jnp.stack([h[0, t] * 0.0, h[0, t]])[:, None]   # slot 0 empty
-            got, _, _ = dec._attention(
-                cfg, layer, p, hh, jnp.asarray([[0], [t]], jnp.int32), view)
+            got, state = absorbed(state, hh,
+                                  jnp.asarray([[0], [t]], jnp.int32))
             assert np.abs(np.asarray(got[1, 0]) - np.asarray(
                 want[0, t])).max() < 1e-5, t
-            carrier.lens = jnp.asarray([0, t + 1], jnp.int32)
+            state = kv.carrier(state).state(jnp.asarray([0, t + 1],
+                                                        jnp.int32))
+        carrier = kv.carrier(state)
         # the cache holds the rows the expanded pass returned, nothing else
         assert np.allclose(carrier.c[layer, 1, :T], rows[0, :, :32],
                            atol=1e-6)
         assert np.allclose(carrier.kr[layer, 1, :, :T], rows[0, :, 32:].T,
                            atol=1e-6)
-    finally:
-        _flags_back()
-
-
-# -- (d) prefill, then decoding through the cache ---------------------------
-
-
-def _engine(net, **kw):
-    kw = dict(dict(max_batch=3, max_seq_len=64, prefill_buckets=(8, 16, 32),
-                   kv_dtype="float32"), **kw)
-    return GenerationEngine(net, **kw)
-
-
-@pytest.mark.parametrize("with_kernels", [False, True])
-def test_prefill_then_decode_gives_the_reference_logits_at_every_position(
-        net, weights, with_kernels):
-    """Three slots of different lengths in one batch, teacher-forced along
-    fixed sequences: the logits of the prompt (expanded prefill) and of
-    every decoded position (absorbed, through the latent cache) are the
-    reference's full, always-expanded forward pass over the same sequence —
-    through the einsum, and through the latent decode kernel and the band
-    kernel in interpret mode."""
-    _flags(with_kernels)
-    try:
-        e = _engine(net)
-        seqs = ref.tokens(11, 3, 40, CFG["vocab_size"])
-        n_prompt = [27, 6, 17]
-        want = [np.asarray(ref.logits(CFG, weights, s)) for s in seqs]
-        cache = e.kv.state()
-        for slot, n in enumerate(n_prompt):
-            b = e.bucket_for(n)
-            ids = np.zeros((1, b), np.int32)
-            ids[0, :n] = seqs[slot, :n]
-            logits, ks, vs, _ = net.run(jnp.asarray(ids))
-            assert vs == [None] * 3 and ks[0].shape == (1, b, 40)
-            assert np.abs(np.asarray(logits)[0, :n]
-                          - want[slot][:n]).max() < TOL
-            kv = e.kv.carrier(cache)
-            kv.insert(ks, vs, jnp.int32(n), jnp.int32(slot))
-            cache = kv.state()
-        for step in range(40 - max(n_prompt)):
-            last = jnp.asarray([[seqs[s, n + step]] for s, n in
-                                enumerate(n_prompt)], jnp.int32)
-            kv = e.kv.carrier(cache)
-            logits, stats = net.step(last, e.kv.views(kv))
-            assert stats.shape == (3,)      # a share: what fell on it too
-            for s, n in enumerate(n_prompt):
-                assert np.abs(np.asarray(logits)[s, 0]
-                              - want[s][n + step]).max() < TOL, (s, step)
-            cache = kv.state(kv.lens + 1)
-    finally:
-        _flags_back()
-
-
-@pytest.mark.parametrize("with_kernels", [False, True])
-def test_the_server_path_decodes_the_reference_greedy_tokens(
-        net, weights, with_kernels):
-    """Through `GenerationEngine.prefill` / `.decode` (jitted, donated):
-    greedy tokens equal the reference's argmax along the served sequence;
-    ONE decode executable, one prefill executable a bucket."""
-    _flags(with_kernels)
-    paths0 = dict(pk.attention_path_counts())
-    try:
-        e = _engine(net, max_seq_len=64, prefill_buckets=(16, 32))
-        prompts = ref.tokens(7, 3, 30, CFG["vocab_size"])
-        n_prompt = [30, 5, 19]
-        seqs = [list(prompts[s, :n]) + [int(e.prefill(s, prompts[s, :n]))]
-                for s, n in enumerate(n_prompt)]
-        for _ in range(14):
-            toks = e.decode()
-            for s in range(3):
-                seqs[s].append(int(toks[s]))
-    finally:
-        _flags_back()
-    assert e.decode_compiles == 1 and e.prefill_compiles == 2
-    paths = {k: v - paths0.get(k, 0)
-             for k, v in pk.attention_path_counts().items()}
-    # three layers a trace: two prefill buckets expanded, one decode absorbed
-    assert paths["latent_expanded"] == 6
-    if with_kernels:     # no layer of the model took the einsum
-        assert paths["xla_latent"] == 0 and paths["latent_absorbed"] == 3
-        assert paths["band_flash"] == 6
-    else:
-        assert paths["xla_latent"] == 3 and paths["latent_absorbed"] == 0
-    assert paths["xla_paged"] == 0 and paths.get("paged_gqa", 0) == 0
-    gaps = ref.served_gaps(CFG, weights, [np.asarray(s) for s in seqs],
-                           n_prompt)
-    assert max(gaps) == 0.0
-    assert max(ref.served_gaps(
-        CFG, weights, [np.asarray(s) for s in seqs], n_prompt,
-        fault="one_token")) > 0.0
-
-
-@pytest.mark.parametrize("with_kernels", [False, True])
-def test_the_run_ahead_loop_gives_the_depth_0_tokens_through_the_latents(
-        net, with_kernels):
-    from test_serving import run_ahead_matches_depth0
-    _flags(with_kernels)
-    try:
-        b, refills = run_ahead_matches_depth0(_engine(net),
-                                              CFG["vocab_size"])
-    finally:
-        _flags_back()
-    assert refills >= 3 and b.steps > 0
-
-
-def test_counters_of_a_served_latent_model(net):
-    e = _engine(net)
-    n0, h0 = engine_mod.MOE_ASSIGNMENTS.value, engine_mod.MOE_HERE.value
-    c0 = engine_mod.MOE_HERE_PCT.count
-    int(e.prefill(0, np.arange(1, 12)))    # observed where it is read
-    e.decode()
-    # a bucket of 16 rows, then 3 slots: 4 experts a token, 2 layers
-    routed = (16 + 3) * 4 * 2
-    assert engine_mod.MOE_ASSIGNMENTS.value - n0 == routed
-    assert 0 < engine_mod.MOE_HERE.value - h0 < routed  # 4 of 16 are held
-    assert engine_mod.MOE_HERE_PCT.count - c0 == 2
-    # 40 numbers a token a layer and nothing else of the token
-    by_kind = e.kv.nbytes_by_kind()
-    assert by_kind == {"latent": 3 * 3 * 64 * (32 + 8) * 4}
-    assert cache_mod.KV_BYTES.labels("latent").value == by_kind["latent"]
-    assert e.kv.nbytes == by_kind["latent"] + 3 * 4
-    l0 = cache_mod.KV_ROWS_LIVE.labels("latent").sum
-    f0 = cache_mod.KV_ROWS_LIVE.labels("full").sum
-    e.kv.observe_live_rows([3, 40, 100])
-    assert cache_mod.KV_ROWS_LIVE.labels("latent").sum - l0 == 3 + 40 + 64
-    assert cache_mod.KV_ROWS_LIVE.labels("full").sum == f0
-
-
-# -- (e) the share of a deployment ------------------------------------------
 
 
 def _moe_inputs(n=37, d=64, E=16, f=32, k=4, fs=64, seed=3):
@@ -440,62 +279,132 @@ def _moe_inputs(n=37, d=64, E=16, f=32, k=4, fs=64, seed=3):
     return m, lw, x
 
 
-@pytest.mark.parametrize("E,held,k,d,f", [
-    (64, 8, 6, 32, 16),         # eight shares of a 64-wide router, top-6
-    (16, 2, 4, 64, 32), (16, 4, 4, 64, 32)])
-def test_the_shares_with_the_shared_expert_once_add_up_to_the_whole_layer(
-        E, held, k, d, f):
-    """What the holders of `held` experts each compute of one layer — every
-    one of them the shared expert too, which a deployment's combine counts
-    ONCE — adds up to the uncut reference's whole layer: the routed parts
-    of all the shares, and the shared expert's output one time."""
-    m, lw, x = _moe_inputs(E=E, k=k, d=d, f=f)
-    whole = np.asarray(ref.moe(m, lw, x))
-    shared = np.asarray(ref._swiglu(x, lw["s_gate"], lw["s_up"],
-                                    lw["s_down"], False))
-    assert np.abs(shared).max() > 1e-2
-    total, assigned = np.zeros_like(whole), 0
-    for first in range(0, E, held):
-        mc = dec.MoEConfig(E, k, f, 64, True, 2.446,
-                           experts_held=(first, held))
-        part = dict(lw, **{n: lw[n][first:first + held]
-                           for n in ("e_gate", "e_up", "e_down")})
-        got, sizes = dec.moe_layer(mc, part, x)       # routed part + shared
-        assert sizes.shape == (held,)
-        assigned += int(np.asarray(sizes).sum())
-        want = ref.moe(dict(m, route_scale=2.446), lw, x,
-                       experts_held=(first, held))
-        assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
-        total += np.asarray(got) - shared             # its routed part
-    assert assigned == x.shape[0] * k            # no assignment dropped
-    whole = np.asarray(ref.moe(dict(m, route_scale=2.446), lw, x))
-    assert np.abs(total + shared - whole).max() < 2e-5
-    routed = np.asarray(ref.moe(dict(m, route_scale=2.446), lw, x,
-                                shared=False))
-    assert np.abs(total - routed).max() < 2e-5 and np.abs(routed).max() > .01
+class TestConformance(DecoderConformance):
+    """The prompt's rows come from the expanded prefill, every decoded
+    position's from the absorbed path through the latent cache."""
+    ref, CFG, build = ref, CFG, staticmethod(program)
+    STEP_STATS = (3,)                   # a share: what fell on it too
+    SERVED_FAULTS = ("one_token",)
+    # a bucket of 16 rows, then 3 slots: 4 experts a token, 2 layers, 4 of
+    # the 16 experts held
+    ASSIGNMENTS = (16 + 3) * 4 * 2
+    HELD = True
+    # 40 numbers a token a layer and nothing else of the token
+    KV_BYTES = {"latent": 3 * 3 * 64 * (32 + 8) * 4}
+    LIVE_ROWS = {"latent": 3 + 40 + 64, "full": 0}
+    # 48 slots x 16 384 positions x 9 layers in bfloat16: 512 + 64 numbers
+    # a token a layer, the latents by row and the rotary parts by column,
+    # and nothing else
+    CELL = ((9, 48, None, 16384, None),
+            dict(kv_dtype="bfloat16", layer_kinds=("latent",) * 9,
+                 kv_geometry={"latent": (512, 64)}),
+            [(9, 48, 16384, 512), (9, 48, 64, 16384), (48,)], 8153726976)
+    SHARES = [(64, 8, 6, 32, 16),       # eight shares of a 64-wide router
+              (16, 2, 4, 64, 32), (16, 4, 4, 64, 32)]
+    SHARE_TOL = (1e-5, 2e-5)
+
+    def check_rows(self, ks, vs, b):
+        assert vs == [None] * 3
+        assert all(k.shape == (1, b, 40) for k in ks)
+
+    def check_prefill_rows(self, kv, ks, vs, n, b):
+        for layer in range(3):
+            assert np.allclose(kv.c[layer, 1, :n], ks[layer][0, :n, :32],
+                               atol=2e-5)
+            assert np.allclose(kv.kr[layer, 1, :, :n],
+                               ks[layer][0, :n, 32:].T, atol=2e-5)
+        assert not np.asarray(kv.c[:, 0]).any()    # other slots untouched
+        assert not np.asarray(kv.kr[:, 2]).any()
+
+    def check_paths(self, paths, with_kernels):
+        # three layers a trace: two prefill buckets expanded, one decode
+        # absorbed
+        assert paths["latent_expanded"] == 6
+        if with_kernels:     # no layer of the model took the einsum
+            assert paths["xla_latent"] == 0 and paths["latent_absorbed"] == 3
+            assert paths["band_flash"] == 6
+        else:
+            assert paths["xla_latent"] == 3 and paths["latent_absorbed"] == 0
+        assert paths["xla_paged"] == 0 and paths.get("paged_gqa", 0) == 0
+
+    def check_small_caches(self):
+        # per-head keys and values of the same heads (16 x 320) would take
+        # 72.5 GB
+        assert 8153726976 == 9 * 48 * 16384 * 576 * 2
+        assert 9 * 48 * 16384 * 16 * 320 * 2 == 72477573120
+        small = PagedKVCache(2, 2, None, 32, None,
+                             layer_kinds=("latent",) * 2,
+                             kv_geometry={"latent": (12, 4)})
+        assert small.geometry == {"latent": (12, 4)}
+        assert small._fields == ("c", "kr", "lens")
+        assert small.c.shape == (2, 2, 32, 12)
+        assert small.kr.shape == (2, 2, 4, 32)
+        assert small.k is None and small.wk is None and small.k_cols == ()
+        assert small.layer_index(1) == ("latent", 1)
+        assert [v.kind for v in small.views(small.carrier(
+            small.state()))] == ["latent"] * 2
+        assert small.nbytes_by_kind() == {"latent": 2 * 2 * 32 * 16 * 4}
+        with pytest.raises(ValueError, match="no prompt head"):
+            small.head(0, 4)
+        with pytest.raises(ValueError, match="3 arrays"):
+            small.set_state((small.c, small.kr))
+        with pytest.raises(ValueError, match="dtype"):
+            small.set_state(small.c.astype(jnp.bfloat16), small.kr,
+                            small.lens)
+        with pytest.raises(ValueError, match="latent layers alone"):
+            PagedKVCache(2, 2, 2, 32, 8, layer_kinds=("latent", "full"),
+                         kv_geometry={"latent": (12, 4)})
+        with pytest.raises(ValueError, match="int8"):
+            PagedKVCache(1, 2, None, 32, None, kv_dtype="int8",
+                         layer_kinds=("latent",),
+                         kv_geometry={"latent": (12, 4)})
+        with pytest.raises(ValueError, match="no geometry"):
+            PagedKVCache(1, 2, None, 32, None, layer_kinds=("latent",))
+        # a cache of K and V is what it was, stated by sizes or by geometry
+        flat = PagedKVCache(2, 2, 2, 32, 8)
+        assert flat._fields == ("k", "v", "lens") and flat.c is None
+        same = PagedKVCache(2, 2, None, 32, None,
+                            kv_geometry={"full": (2, 8, 8)})
+        assert same.k.shape == flat.k.shape == (2, 2, 2, 32, 8)
+        assert same.geometry == flat.geometry
+
+    def expert_layer(self, weights, E, k, d, f):
+        """An UNCUT layer with a shared expert, which every share computes
+        too and a deployment's combine counts ONCE."""
+        m, lw, x = _moe_inputs(E=E, k=k, d=d, f=f)
+        shared = np.asarray(ref._swiglu(x, lw["s_gate"], lw["s_up"],
+                                        lw["s_down"], False))
+        assert np.abs(shared).max() > 1e-2
+        return m, lw, x, (E, k, f, 64, True, 2.446), shared
+
+    def check_routed_part(self, m, lw, x, routed, whole):
+        want = np.asarray(ref.moe(m, lw, x, shared=False))
+        assert np.abs(routed - want).max() < 2e-5
+        assert np.abs(want).max() > .01
 
 
 # -- (f) the kernel, in interpret mode, against the einsum -------------------
 
 
-def _randn(*shape):
-    return jnp.asarray(np.random.RandomState(sum(shape)).randn(*shape),
-                       jnp.float32)
+# one compile a block size, where the kernel's grid steps would otherwise
+# dispatch op by op
+_latent = jax.jit(pk._paged_latent_decode, static_argnames=(
+    "layer", "block_k", "scale", "interpret"))
 
 
 @pytest.mark.parametrize("block", [16, 32, 64])
 @pytest.mark.parametrize("lens", [[0, 17, 200], [63, 64, 31], [15, 16, 47],
                                   [0, 0, 0], [1, 32, 48]])
-def test_latent_decode_kernel_against_the_einsum(kernels, lens, block):
+def test_latent_decode_kernel_against_the_einsum(interpret, lens, block):
     """Ragged lengths over two layers: an empty slot (no grid step, output
     0, rows untouched), append rows that open a block, close one and sit at
     the wall; every row the call did not append comes back unchanged."""
     L, B, H, r, dr, T = 2, 3, 4, 32, 8, 64
-    q, new = _randn(B, H, r + dr), _randn(B, r + dr)
-    c, kr = _randn(L, B, T, r) * 2.0, _randn(L, B, dr, T)
+    q, new = randn(B, H, r + dr), randn(B, r + dr)
+    c, kr = randn(L, B, T, r) * 2.0, randn(L, B, dr, T)
     lens = jnp.asarray(lens, jnp.int32)
     scale = 24.0 ** -0.5
-    out, co, kro = pk._paged_latent_decode(
+    out, co, kro = _latent(
         q[..., :r], q[..., r:], c, kr, lens, new[:, :r], new[:, r:], layer=1,
         block_k=block, scale=scale, interpret=True)
     live = np.asarray(lens) > 0
@@ -519,10 +428,10 @@ def test_latent_decode_kernel_against_the_einsum(kernels, lens, block):
     assert np.array_equal(np.asarray(view.kv.c)[:, live], cb[:, live])
 
 
-def test_the_scale_is_the_callers_and_a_wrong_one_shows(kernels):
+def test_the_scale_is_the_callers_and_a_wrong_one_shows(interpret):
     B, H, r, dr, T = 2, 4, 32, 8, 32
-    q, new = _randn(B, 1, H, r + dr), _randn(B, 1, 1, r + dr)
-    c, kr = _randn(1, B, T, r), _randn(1, B, dr, T)
+    q, new = randn(B, 1, H, r + dr), randn(B, 1, 1, r + dr)
+    c, kr = randn(1, B, T, r), randn(1, B, dr, T)
     lens = jnp.asarray([20, 31], jnp.int32)
 
     def attend(scale):
@@ -536,16 +445,16 @@ def test_the_scale_is_the_callers_and_a_wrong_one_shows(kernels):
     assert np.abs(attend(24.0 ** -0.5) - head).max() < 1e-5
     # the other kinds' kernels scale by the key size: no other is taken
     full = LayerCacheView(StackedKV(
-        _randn(1, B, 1, T, 8), _randn(1, B, 1, T, 8), lens), 0)
-    qf, kf = _randn(B, 1, 2, 8), _randn(B, 1, 1, 8)
+        randn(1, B, 1, T, 8), randn(1, B, 1, T, 8), lens), 0)
+    qf, kf = randn(B, 1, 2, 8), randn(B, 1, 1, 8)
     with pytest.raises(ValueError, match="scale"):
         full.attend(qf, kf, kf, scale=0.5)
     assert full.attend(qf, kf, kf).shape == (B, 1, 2, 8)
 
 
-def test_the_gate_takes_the_latent_cache_or_leaves_it_to_the_einsum(kernels):
-    q, new = _randn(2, 4, 40), _randn(2, 40)
-    c, kr = _randn(1, 2, 64, 32), _randn(1, 2, 8, 64)
+def test_the_gate_takes_the_latent_cache_or_leaves_it_to_the_einsum(interpret):
+    q, new = randn(2, 4, 40), randn(2, 40)
+    c, kr = randn(1, 2, 64, 32), randn(1, 2, 8, 64)
     lens = jnp.asarray([3, 40], jnp.int32)
     kw = dict(layer=0, scale=0.2)
     assert pk.paged_latent_decode_or_none(q, c, kr, lens, new, **kw) \
@@ -563,73 +472,3 @@ def test_the_gate_takes_the_latent_cache_or_leaves_it_to_the_einsum(kernels):
     assert pk._band_blocks(15360, False, 0, 1) == (512, 512)
     assert pk._band_blocks(3072, False, 128, 8) == (128, 128)
     assert pk._band_blocks(3072, False, 0, 16) == (64, 512)
-
-
-# -- (g) the latent cache ----------------------------------------------------
-
-
-def test_cache_bytes_of_the_cell():
-    """48 slots x 16 384 positions x 9 layers in bfloat16: 512 + 64 numbers
-    a token a layer, the latents by row and the rotary parts by column, and
-    nothing else: 8 153 726 976 bytes where per-head keys and values of the
-    same heads (16 x 320) would be 72.5 GB."""
-    shape = jax.eval_shape(lambda: PagedKVCache(
-        9, 48, None, 16384, None, kv_dtype="bfloat16",
-        layer_kinds=("latent",) * 9,
-        kv_geometry={"latent": (512, 64)}).state())
-    assert [a.shape for a in shape] == [
-        (9, 48, 16384, 512), (9, 48, 64, 16384), (48,)]
-    nbytes = [int(np.prod(a.shape)) * a.dtype.itemsize for a in shape[:2]]
-    assert sum(nbytes) == 8153726976 == 9 * 48 * 16384 * 576 * 2
-    assert 9 * 48 * 16384 * 16 * 320 * 2 == 72477573120
-    small = PagedKVCache(2, 2, None, 32, None, layer_kinds=("latent",) * 2,
-                         kv_geometry={"latent": (12, 4)})
-    assert small.geometry == {"latent": (12, 4)}
-    assert small._fields == ("c", "kr", "lens")
-    assert small.c.shape == (2, 2, 32, 12) and small.kr.shape == (2, 2, 4, 32)
-    assert small.k is None and small.wk is None and small.k_cols == ()
-    assert small.layer_index(1) == ("latent", 1)
-    assert [v.kind for v in small.views(small.carrier(small.state()))] == [
-        "latent"] * 2
-    assert small.nbytes_by_kind() == {"latent": 2 * 2 * 32 * 16 * 4}
-    with pytest.raises(ValueError, match="no prompt head"):
-        small.head(0, 4)
-    with pytest.raises(ValueError, match="3 arrays"):
-        small.set_state((small.c, small.kr))
-    with pytest.raises(ValueError, match="dtype"):
-        small.set_state(small.c.astype(jnp.bfloat16), small.kr, small.lens)
-    with pytest.raises(ValueError, match="latent layers alone"):
-        PagedKVCache(2, 2, 2, 32, 8, layer_kinds=("latent", "full"),
-                     kv_geometry={"latent": (12, 4)})
-    with pytest.raises(ValueError, match="int8"):
-        PagedKVCache(1, 2, None, 32, None, kv_dtype="int8",
-                     layer_kinds=("latent",), kv_geometry={"latent": (12, 4)})
-    with pytest.raises(ValueError, match="no geometry"):
-        PagedKVCache(1, 2, None, 32, None, layer_kinds=("latent",))
-    # a cache of K and V is what it was, stated by sizes or by geometry
-    flat = PagedKVCache(2, 2, 2, 32, 8)
-    assert flat._fields == ("k", "v", "lens") and flat.c is None
-    same = PagedKVCache(2, 2, None, 32, None,
-                        kv_geometry={"full": (2, 8, 8)})
-    assert same.k.shape == flat.k.shape == (2, 2, 2, 32, 8)
-    assert same.geometry == flat.geometry
-
-
-@pytest.mark.parametrize("n", [5, 8, 19, 27, 32])
-def test_a_prefill_leaves_its_latents_where_the_stack_keeps_them(net, n):
-    e = _engine(net)
-    ids = ref.tokens(n, 1, n, CFG["vocab_size"])[0]
-    e.prefill(1, ids)
-    b = e.bucket_for(n)
-    padded = np.zeros((1, b), np.int32)
-    padded[0, :n] = ids
-    _, ks, vs, _ = net.run(jnp.asarray(padded))
-    assert int(e.kv.lens[1]) == n and list(np.asarray(e.kv.lens)) == [0, n, 0]
-    for layer in range(3):
-        assert ks[layer].shape == (1, b, 40) and vs[layer] is None
-        assert np.allclose(e.kv.c[layer, 1, :n], ks[layer][0, :n, :32],
-                           atol=2e-5)
-        assert np.allclose(e.kv.kr[layer, 1, :, :n], ks[layer][0, :n, 32:].T,
-                           atol=2e-5)
-    assert not np.asarray(e.kv.c[:, 0]).any()        # other slots untouched
-    assert not np.asarray(e.kv.kr[:, 2]).any()

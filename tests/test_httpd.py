@@ -56,9 +56,12 @@ def _get(url, timeout=5.0):
 def plane(monkeypatch):
     """Fresh plane on both sides: no singleton server, no leftover
     probes/providers, no ambient enablement or stale watchdog fires
-    (test_resilience trips the process-global counter)."""
+    (test_resilience trips the process-global counter), and no rank
+    from the environment: a server's rank names its endpoint file, and
+    `health.configure(rank=)` exports one for the rest of the process."""
     for var in (httpd.ENV_PORT, httpd.ENV_HOST, httpd.ENV_STALE,
-                health.ENV_DIR, "PADDLE_TPU_TELEMETRY_DIR"):
+                health.ENV_DIR, "PADDLE_TPU_TELEMETRY_DIR",
+                "PADDLE_TRAINER_ID"):
         monkeypatch.delenv(var, raising=False)
     metrics.REGISTRY.unregister("pt_watchdog_fires_total")
     httpd.shutdown()

@@ -513,6 +513,9 @@ class TestHeartbeat:
         health.reset()
         assert not health.tick(1)           # unset env: cheap no-op
         monkeypatch.setenv(health.ENV_INTERVAL, "0")
+        # configure() exports the directory and the rank: undone after
+        for var in (health.ENV_DIR, "PADDLE_TRAINER_ID"):
+            monkeypatch.delenv(var, raising=False)
         health.configure(str(tmp_path), rank=5)
         assert health.tick(9)
         rec = health.read_heartbeat(health.heartbeat_path(str(tmp_path), 5))
