@@ -1629,12 +1629,24 @@ def _paged_work(lens, T, block_k):
     no block of an EMPTY slot (lens == 0: it holds no request, the serving
     engine's one encoding of that); the entries from n on are never
     visited, and n is 0 where every slot is empty."""
-    nblk = jnp.where(lens > 0,
-                     jnp.minimum(lens, T - 1) // block_k + 1, 0)    # [B]
+    return _work_list(jnp.where(lens > 0,
+                                jnp.minimum(lens, T - 1) // block_k + 1, 0),
+                      T // block_k)
+
+
+def _live_work(live, R, block_k):
+    """`_paged_work` of a cache whose slots hold `live` rows each, int32
+    [B] and at least 1 (the new token's row is one): a slot's blocks 0 ..
+    (live - 1) // block_k, n the sum of their counts."""
+    return _work_list((live - 1) // block_k + 1, R // block_k)
+
+
+def _work_list(nblk, per_slot):
+    """(slot, block, n) of `nblk` [B] blocks a slot, at most `per_slot`."""
     end = jnp.cumsum(nblk)
-    i = jnp.arange(lens.shape[0] * (T // block_k), dtype=jnp.int32)
+    i = jnp.arange(nblk.shape[0] * per_slot, dtype=jnp.int32)
     before = end[None, :] <= i[:, None]       # slots wholly before entry i
-    slot = jnp.minimum(jnp.sum(before, axis=1), lens.shape[0] - 1)
+    slot = jnp.minimum(jnp.sum(before, axis=1), nblk.shape[0] - 1)
     blk = i - jnp.sum(jnp.where(before, nblk[None, :], 0), axis=1)
     return slot.astype(jnp.int32), blk.astype(jnp.int32), end[-1]
 
@@ -1988,11 +2000,13 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
 # starts from (-inf, 0, 0); nothing else in a kernel knows of it. The band
 # kernel takes both as it is. For decode such a cache keeps K BY COLUMN
 # ([.., dk, rows]: why, at `_paged_kv_decode`) and `_paged_kv_kernel` is
-# `_paged_gqa_kernel` over that layout, with as many key-value heads of a
-# slot a grid step as keep its K and V blocks within `_GQA_STEP_BYTES`
-# (`_gqa_heads`): all 4 heads of a 1024-row block, all 8 of a 128-row ring
-# — few fat steps — and with the new token folded into the softmax's start
-# instead of substituted into a block. A call with dk != dv or
+# the grouped-query decode over that layout: its grid walks a work list of
+# the live (slot, block) pairs, as `_paged_core`'s does, with as many
+# key-value heads of a slot a step as keep its K and V blocks within
+# `_GQA_STEP_BYTES` (`_gqa_heads`): all 4 heads of a 512-row block
+# (`_gqa_cols_block`), all 8 of a 128-row ring, one entry a slot — and
+# with the new token folded into the softmax's start instead of
+# substituted into a block. A call with dk != dv or
 # with a sink bears a name of its own (`paged_kv_ring_decode`,
 # `paged_kv_rows_decode`, `prefill_kv_band_flash`), so a trace tells it
 # from the calls of a model that has neither, and a ring's call from a
@@ -2000,9 +2014,9 @@ def paged_decode_attention_or_none(q, k_cache, v_cache, lens, new_k,
 # ---------------------------------------------------------------------------
 
 _APPEND_ROWS = 16     # a bf16 tile's sublanes: the group written back
-# K and V blocks of one grid step of the by-column decode kernel (twice that
-# is in flight): four heads of 1024 rows of a 192-wide key and a 128-wide
-# value
+# the most K and V blocks of one grid step of the by-column decode kernel
+# hold (twice that is in flight): four heads of 1024 rows of a 192-wide key
+# and a 128-wide value
 _GQA_STEP_BYTES = 2560 << 10
 
 
@@ -2017,6 +2031,28 @@ def _gqa_block(rows, interpret):
         if rows % b == 0:
             return b
     return None
+
+
+# the least K and V a grid step of the by-column kernel reads: a smaller
+# block reads less past a slot's live rows (half a block a slot), but below
+# this a step's fixed cost and its short K runs outweigh that (longgen's
+# full layer on the v5e: 1.10 ms a call at 512 rows = 1.3 MB a step, 1.16
+# at 256, 1.26 at 1024)
+_GQA_COLS_MIN_BYTES = 1 << 20
+
+
+def _gqa_cols_block(rows, H, dk, dv, itemsize, interpret):
+    """Key rows a grid step of the by-column decode kernel reads: the
+    fewest, of the multiples of 128 that divide `rows`, whose K and V
+    blocks for the step's heads hold _GQA_COLS_MIN_BYTES, else the most."""
+    if interpret:
+        return _gqa_block(rows, interpret)
+    blocks = [b for b in (128, 256, 512, 1024) if rows % b == 0]
+    for b in blocks:
+        step = _gqa_heads(H, b, dk, dv, itemsize) * b * (dk + dv) * itemsize
+        if step >= _GQA_COLS_MIN_BYTES:
+            return b
+    return blocks[-1] if blocks else None
 
 
 def _gqa_heads(H, block_k, dk, dv, itemsize):
@@ -2146,32 +2182,39 @@ def _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
     return out, ko, vo
 
 
-def _paged_kv_kernel(row_ref, live_ref, layer_ref, q_ref, nk_ref, nkc_ref,
-                     nv_ref, *refs, block_k, sm_scale, sink):
-    """`_paged_gqa_kernel` for a cache whose K is kept BY COLUMN (a key
-    row dk wide that is no whole number of 128-lane tiles: see
-    `_paged_kv_decode`), `heads` key-value heads of a slot a grid step as
-    the refs' leading axis, and a sink where the layer has one. q [hb, G,
-    dk]; K [hb, dk, block_k]: position along the lanes; V [hb, block_k,
-    dv]; the new token's K as rows [hb, 1, dk] and, for the write-back
-    into the by-column cache, as the columns of ALL the slot's heads side
-    by side [dk, H] (a [.., dk, 1] operand a head would be padded to 128
-    lanes a column: 75 MB a ring layer in HBM), its V [hb, 1, dv].
+def _paged_kv_kernel(row_ref, live_ref, layer_ref, slot_ref, blk_ref, q_ref,
+                     nk_ref, nkc_ref, nv_ref, *refs, block_k, sm_scale, sink):
+    """The grouped-query decode for a cache whose K is kept BY COLUMN (a
+    key row dk wide that is no whole number of 128-lane tiles: see
+    `_paged_kv_decode`), and a sink where the layer has one. Grid (H //
+    heads, n): this body runs once for entry i of the work list, block j
+    of slot b, `heads` key-value heads of the slot as the refs' leading
+    axis. q [hb, G, dk]; K [hb, dk, block_k]: position along the lanes; V
+    [hb, block_k, dv]; the new token's K as rows [hb, 1, dk] and, for the
+    write-back into the by-column cache, as the columns of ALL the slot's
+    heads side by side [dk, H] (a [.., dk, 1] operand a head would be
+    padded to 128 lanes a column: 75 MB a ring layer in HBM), its V [hb,
+    1, dv].
 
-    The new token starts the running softmax (behind the sink, where there
-    is one) and the row it overwrites is masked out of the blocks, as in
-    `_paged_core`: no block has the row substituted, so a step's only work
-    on a whole block is its two products."""
+    As in `_paged_core`, state lives in VMEM scratch across a slot's
+    blocks: the new token starts the running softmax (behind the sink,
+    where there is one) at j == 0, the block that holds the append row
+    writes the row's tile back (the cache outputs hold that tile for every
+    entry of the slot), and the slot's last live block writes the output.
+    The row the new token overwrites is masked out of the blocks: no block
+    has the row substituted, so a step's only work on a whole block is its
+    two products."""
     if sink:
         sink_ref, *refs = refs
     k_ref, v_ref, o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref = refs
     del layer_ref                    # read by the index maps only
-    b, j = pl.program_id(0), pl.program_id(2)
+    i = pl.program_id(1)
+    b, j = slot_ref[i], blk_ref[i]
     ar, nl = row_ref[b], live_ref[b]         # append row; live rows
     jm, ja = (nl - 1) // block_k, ar // block_k
     off = ar - j * block_k           # the append row within this block
     cols = ko_ref.shape[2]           # K positions written back: a lane tile
-    first = pl.program_id(1) * ko_ref.shape[0]      # this step's first head
+    first = pl.program_id(0) * ko_ref.shape[0]      # this step's first head
     cd = k_ref.dtype
 
     @pl.when(j == 0)
@@ -2243,10 +2286,10 @@ def _paged_kv_kernel(row_ref, live_ref, layer_ref, q_ref, nk_ref, nkc_ref,
         l_ref[...] = jax.lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1, 2))
 
     pl.when(j < jm)(lambda: step(False))
-    pl.when(j == jm)(lambda: step(True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == jm)
     def _finish():
+        step(True)
         ell = jnp.max(l_ref[...], axis=2, keepdims=True)
         # l > 0 always: the new token is in it
         o_ref[...] = (acc_ref[...] / ell).astype(o_ref.dtype)
@@ -2264,8 +2307,14 @@ def _paged_kv_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
                      name="paged_kv_rows_decode"):
     """q [B, H, G, dk]; new_k [B, H, 1, dk], new_v [B, H, 1, dv]; K cache
     BY COLUMN [L, B, H, dk, R], V cache [L, B, H, R, dv]; row/live int32
-    [B]; sink float32 [H * G] or None. Returns (out [B, H, G, dv],
-    k_cache', v_cache'), the caches being the operands updated in place.
+    [B], live >= 1 (the append row is live); sink float32 [H * G] or None.
+    Returns (out [B, H, G, dv], k_cache', v_cache'), the caches being the
+    operands updated in place.
+
+    The grid is (H // heads, n) over `_live_work`'s list of the slots'
+    live blocks, n its length as the dynamic bound, as in `_paged_decode`:
+    no step for a block past a slot's live rows. A ring of one block is
+    one entry a slot.
 
     Why by column: a bfloat16 array whose minor dimension is 192 is padded
     to 256 lanes in a row-major tiled layout, so XLA keeps [.., R, 192]
@@ -2278,33 +2327,32 @@ def _paged_kv_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
     R, dv = k_cache.shape[4], v_cache.shape[4]
     hb = heads or _gqa_heads(H, block_k, dk, dv, k_cache.dtype.itemsize)
     cols = min(_LANES, block_k)      # K positions written back
+    row, live = row.astype(jnp.int32), live.astype(jnp.int32)
+    slot, blk, n_work = _live_work(live, R, block_k)
 
-    def _last(b, live):
-        return (live[b] - 1) // block_k
+    def k_map(h, i, row, live, layer, slot, blk):
+        return (layer[0], slot[i], h, _I0, blk[i])
 
-    def k_map(b, h, j, row, live, layer):
-        return (layer[0], b, h, _I0, jnp.minimum(j, _last(b, live)))
+    def v_map(h, i, row, live, layer, slot, blk):
+        return (layer[0], slot[i], h, blk[i], _I0)
 
-    def v_map(b, h, j, row, live, layer):
-        return (layer[0], b, h, jnp.minimum(j, _last(b, live)), _I0)
+    def k_out_map(h, i, row, live, layer, slot, blk):
+        return (layer[0], slot[i], h, _I0, row[slot[i]] // cols)
 
-    def k_out_map(b, h, j, row, live, layer):
-        return (layer[0], b, h, _I0, row[b] // cols)
+    def v_out_map(h, i, row, live, layer, slot, blk):
+        return (layer[0], slot[i], h, row[slot[i]] // _APPEND_ROWS, _I0)
 
-    def v_out_map(b, h, j, row, live, layer):
-        return (layer[0], b, h, row[b] // _APPEND_ROWS, _I0)
+    def tok_map(h, i, row, live, layer, slot, blk):
+        return (slot[i], h, _I0, _I0)
 
-    def tok_map(b, h, j, row, live, layer):
-        return (b, h, _I0, _I0)
-
-    def sink_map(b, h, j, row, live, layer):
+    def sink_map(h, i, row, live, layer, slot, blk):
         return (h, _I0, _I0)
 
     def tok_spec(rows, d):
         return pl.BlockSpec((None, hb, rows, d), tok_map)
 
-    def cols_map(b, h, j, row, live, layer):
-        return (b, _I0, _I0)
+    def cols_map(h, i, row, live, layer, slot, blk):
+        return (slot[i], _I0, _I0)
 
     tokens = [q, new_k, jnp.swapaxes(new_k[:, :, 0], 1, 2), new_v]
     tok_specs = [tok_spec(G, dk), tok_spec(1, dk),
@@ -2312,10 +2360,11 @@ def _paged_kv_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
     if sink is not None:
         tokens.append(_sink_lanes(sink, H, G))
         tok_specs.append(pl.BlockSpec((hb, G, _LANES), sink_map))
-    n_prefetch = 3                     # row, live, layer
+    prefetch = [row, live, jnp.asarray(layer, jnp.int32).reshape(1), slot,
+                blk]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, H // hb, R // block_k),
+        num_scalar_prefetch=len(prefetch),
+        grid=(H // hb, n_work),
         in_specs=tok_specs + [
             pl.BlockSpec((None, None, hb, dk, block_k), k_map),
             pl.BlockSpec((None, None, hb, block_k, dv), v_map)],
@@ -2331,17 +2380,15 @@ def _paged_kv_decode(q, k_cache, v_cache, row, live, new_k, new_v, *,
                              sink=sink is not None)
     # cache operands (after the prefetch and the token operands)
     # -> outputs 1 and 2
-    n_tok = len(tokens)
-    aliases = {n_prefetch + n_tok: 1, n_prefetch + n_tok + 1: 2}
+    n_in = len(prefetch) + len(tokens)
+    aliases = {n_in: 1, n_in + 1: 2}
     out, ko, vo = _pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H, G, dv), q.dtype),
                    jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                    jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
         input_output_aliases=aliases, interpret=interpret, name=name)(
-        row.astype(jnp.int32), live.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), *tokens,
-        k_cache, v_cache)
+        *prefetch, *tokens, k_cache, v_cache)
     return out, ko, vo
 
 
@@ -2358,7 +2405,8 @@ def paged_gqa_decode_or_none(q, k_cache, v_cache, row, live, new_k, new_v,
     B, H, G, dk = q.shape
     R, dv = v_cache.shape[3], v_cache.shape[4]
     interpret = jax.default_backend() != "tpu"
-    blk = _gqa_block(R, interpret)
+    blk = _gqa_cols_block(R, H, dk, dv, q.dtype.itemsize, interpret) \
+        if k_cols else _gqa_block(R, interpret)
     if blk is None or k_cache.dtype != q.dtype:
         return None
     if not k_cols and (sink is not None or dk != dv):
@@ -2369,12 +2417,13 @@ def paged_gqa_decode_or_none(q, k_cache, v_cache, row, live, new_k, new_v,
             return None
     elif dv % 128 != 0 or dk % (16 if k_cols else 128) != 0:
         return None
-    _note_attn_path("paged_gqa")
     if k_cols:
+        _note_attn_path("paged_kv_work")
         return _paged_kv_decode(
             q, k_cache, v_cache, row, live, new_k, new_v, layer=layer,
             block_k=blk, interpret=interpret, sink=sink,
             name="paged_kv_ring_decode" if ring else "paged_kv_rows_decode")
+    _note_attn_path("paged_gqa")
     return _paged_gqa_decode(q, k_cache, v_cache, row, live, new_k, new_v,
                              layer=layer, block_k=blk, interpret=interpret)
 
@@ -2434,10 +2483,10 @@ def _check_paged_gqa():
 
 def _check_paged_gqa_sink():
     """The by-column kernel at a 192-wide key and a 128-wide value with a
-    sink, against the einsum: over 1024-row blocks one head a step (ragged
-    lengths, one slot at the wall), and over a wrapped 128-row ring every
-    head of a slot in one step; every row the call did not append must
-    come back unchanged."""
+    sink, against the einsum: over the work list of a slot's 1024-row
+    blocks (ragged lengths, an empty slot, one at the wall), and over a
+    wrapped 128-row ring, one entry a slot; every row the call did not
+    append must come back unchanged."""
     L, B, H, G, dk, dv = 2, 3, 2, 8, 192, 128
     rs = np.random.RandomState(0)
     arr = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
@@ -2451,7 +2500,8 @@ def _check_paged_gqa_sink():
         row = lens % R if ring else jnp.minimum(lens, R - 1)
         live = jnp.minimum(lens + 1, R)
         out, ko, vo = run(q, k, v, row, live, nk, nv, sink=sink, layer=1,
-                          block_k=_gqa_block(R, False), interpret=False)
+                          block_k=_gqa_cols_block(R, H, dk, dv, 2, False),
+                          interpret=False)
         slots = jnp.arange(B)
         kb = k.at[1, slots, :, :, row].set(nk[:, :, 0])
         vb = v.at[1, slots, :, row].set(nv[:, :, 0])

@@ -179,7 +179,7 @@ class TestConformance(DecoderConformance):
 
     def check_paths(self, paths, with_kernels):
         if with_kernels:     # no layer of the model took the einsum
-            assert paths["xla_paged"] == 0 and paths["paged_gqa"] == 7
+            assert paths["xla_paged"] == 0 and paths["paged_kv_work"] == 7
         else:
             assert paths["xla_paged"] == 7
 
@@ -256,7 +256,11 @@ _by_column = jax.jit(pk._paged_kv_decode, static_argnames=(
 @pytest.mark.parametrize("sink", [False, True])
 @pytest.mark.parametrize("heads", [1, 2])
 @pytest.mark.parametrize("ring", [False, True])
-@pytest.mark.parametrize("lens", [[0, 17, 200], [63, 64, 31], [15, 16, 47]])
+@pytest.mark.parametrize("lens", [
+    [0, 17, 200], [63, 64, 31], [15, 16, 47],
+    [2, 62, 5],          # one slot far longer than the others
+    [31, 1, 0],          # live rows ending on a block; one row; an empty slot
+])
 def test_by_column_decode_kernel_on_rows_and_on_a_ring(interpret, ring, lens,
                                                         heads, sink):
     L, B, H, G, dk, dv, R = 2, 3, 2, 4, 24, 16, 64
@@ -281,6 +285,47 @@ def test_by_column_decode_kernel_on_rows_and_on_a_ring(interpret, ring, lens,
     assert np.array_equal(np.asarray(vo), np.asarray(vb))
 
 
+@pytest.mark.parametrize("live,R,block_k", [
+    ([1, 64, 17], 64, 16),      # one row; a full slot; a block and a row
+    ([3, 64, 1, 2], 64, 16),    # one slot far longer than the others
+    ([16, 32, 33], 64, 16),     # live rows ending on a block, and one past
+    ([128, 5, 128], 128, 128),  # a ring of one block: one entry a slot
+])
+def test_the_by_column_work_list_holds_each_slots_live_blocks(live, R,
+                                                              block_k):
+    # no entry for a block past a slot's live rows: the static grid's dead
+    # steps cannot come back unnoticed
+    slot, blk, n = pk._live_work(jnp.asarray(live, jnp.int32), R, block_k)
+    assert slot.shape == blk.shape == (len(live) * R // block_k,)
+    assert int(n) == sum((nl - 1) // block_k + 1 for nl in live)
+    want = [(b, j) for b, nl in enumerate(live)
+            for j in range((nl - 1) // block_k + 1)]
+    assert list(zip(np.asarray(slot)[:int(n)].tolist(),
+                    np.asarray(blk)[:int(n)].tolist())) == want
+
+
+def test_the_by_column_grid_is_the_work_list():
+    # longgen's full layer as the benchmark serves it: the lowered call's
+    # grid is (head groups, the work list's length), not (slots, head
+    # groups, blocks)
+    B, H, G, dk, dv, R = 192, 4, 16, 192, 128, 4096
+    blk = pk._gqa_cols_block(R, H, dk, dv, 2, interpret=False)
+    bf = jnp.bfloat16
+    i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
+    closed = jax.make_jaxpr(lambda *a: pk._paged_kv_decode(
+        *a, layer=1, block_k=blk, interpret=False))(
+        jax.ShapeDtypeStruct((B, H, G, dk), bf),
+        jax.ShapeDtypeStruct((2, B, H, dk, R), bf),
+        jax.ShapeDtypeStruct((2, B, H, R, dv), bf), i32, i32,
+        jax.ShapeDtypeStruct((B, H, 1, dk), bf),
+        jax.ShapeDtypeStruct((B, H, 1, dv), bf))
+    (call,) = [e for e in closed.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    assert gm.num_dynamic_grid_bounds == 1 and len(gm.grid) == 2
+    assert gm.grid[0] == H // pk._gqa_heads(H, blk, dk, dv, 2)
+
+
 def test_the_gate_sends_a_sink_or_unequal_sizes_to_the_by_column_kernel(
         interpret):
     q, nk, nv = randn(1, 2, 2, 24), randn(1, 2, 1, 24), randn(1, 2, 1, 16)
@@ -294,7 +339,11 @@ def test_the_gate_sends_a_sink_or_unequal_sizes_to_the_by_column_kernel(
                                        k_cols=True, sink=randn(4)) \
         is not None
     assert pk._gqa_heads(8, 128, 192, 128, 2) == 8       # a 128-row ring
-    assert pk._gqa_heads(4, 1024, 192, 128, 2) == 4      # 1024 full rows
+    assert pk._gqa_heads(4, 512, 192, 128, 2) == 4       # 512 full rows
+    # the block: the fewest rows whose step holds 1 MiB, a ring whole
+    assert pk._gqa_cols_block(4096, 4, 192, 128, 2, False) == 512
+    assert pk._gqa_cols_block(128, 8, 192, 128, 2, False) == 128
+    assert pk._gqa_cols_block(100, 8, 192, 128, 2, False) is None
     assert pk._band_blocks(3072, False, 128, 8) == (128, 128)
     assert pk._band_blocks(3072, False, 0, 16) == (64, 512)
     assert pk._band_blocks(4096, False, 2048, 8) == (128, 512)

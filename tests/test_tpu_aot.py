@@ -203,6 +203,17 @@ def test_decode_step_at_unequal_stacks_copies_neither_cache_nor_weight(
         jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert "paged_kv_ring_decode" in text and "paged_kv_rows_decode" in text
+    # the full layer's call walks a work list: its operands open with the
+    # grid's dynamic bound, then row, live, layer and the list's (slot,
+    # block) entries, one a block of every slot
+    blk = pk._gqa_cols_block(depth, 4, 192, 128, 2, interpret=False)
+    entries = slots * depth // blk
+    (operands,) = re.findall(              # the one full layer's call
+        r"%paged_kv_rows_decode\S* = .* custom-call\(.*"
+        r"operand_layout_constraints=\{(.*?)\}, output_to", text)
+    assert re.sub(r"\{[\d,]*\}", "", operands).split(", ")[:6] == [
+        "s32[]", "s32[%d]" % slots, "s32[%d]" % slots, "s32[1]",
+        "s32[%d]" % entries, "s32[%d]" % entries]
     # a held share: three products a layer on the held experts' rows alone
     assert len(re.findall(r"held_grouped_dot\S* = ", text)) == 9
     assert "ragged-dot" not in text
